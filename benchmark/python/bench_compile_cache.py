@@ -4,7 +4,7 @@ Measures the three levers of `mxtpu/compile_cache.py` on a gluon
 model-zoo net:
 
   * **cold vs warm start** — a subprocess binds + warms up resnet18_v1
-    through Module/Executor with `MXTPU_COMPILE_CACHE` pointed at a
+    through Module/Executor with `JAX_COMPILATION_CACHE_DIR` pointed at a
     fresh directory (cold: full XLA compile) and then again with the
     now-populated cache (warm: disk deserialization).  The headline
     metric is the warm-start speedup of the bind+warmup phase.
@@ -41,8 +41,7 @@ MAXB = int(os.environ.get("MXTPU_BENCH_CC_MAXB", "8"))
 
 _BIND_SCRIPT = r"""
 import os, sys, time
-cache_dir = sys.argv[1]
-os.environ["MXTPU_COMPILE_CACHE"] = cache_dir
+os.environ["JAX_COMPILATION_CACHE_DIR"] = sys.argv[1]
 import numpy as np
 t0 = time.perf_counter()
 import mxtpu as mx

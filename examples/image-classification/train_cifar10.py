@@ -12,10 +12,8 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 "..", ".."))
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-from common import data, fit, util
+from common import data, fit
 from symbols import zoo
-
-util.apply_platform_env()
 
 if __name__ == "__main__":
     parser = argparse.ArgumentParser(

@@ -35,25 +35,17 @@ def main():
     args = p.parse_args()
     logging.basicConfig(level=logging.INFO)
 
-    # virtual-CPU-mesh fallback (same guard as the test conftest):
-    # jax_num_cpu_devices only exists on newer JAX; older builds take
-    # the count from XLA_FLAGS, which must land before backend init
-    n_dev = max(args.tp, 8)
+    # on a host with no chips a virtual CPU mesh stands in; the flag
+    # only concerns the CPU platform and must land before its backend
+    # starts
     xla_flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in xla_flags:
         os.environ["XLA_FLAGS"] = (
-            xla_flags +
-            " --xla_force_host_platform_device_count=%d" % n_dev).strip()
+            xla_flags + " --xla_force_host_platform_device_count=%d"
+            % max(args.tp, 8)).strip()
 
     import jax
 
-    if hasattr(jax.config, "jax_num_cpu_devices"):
-        try:
-            # a no-op error if backends are already initialized or a
-            # real TPU mesh is present
-            jax.config.update("jax_num_cpu_devices", n_dev)
-        except RuntimeError:
-            pass
     if len(jax.devices()) < args.tp:
         raise SystemExit("need >= %d devices for tp=%d (got %d); run "
                          "with more chips or a larger CPU mesh"

@@ -7,11 +7,12 @@ the XLA substrate both costs are explicit and much larger — a ResNet
 bind is seconds of HLO compilation — so this module owns the three
 levers that make "compile once, serve many" real:
 
-  * **Persistent compile cache** — wires JAX's on-disk compilation
-    cache (``jax_compilation_cache_dir``) behind one env knob
-    (``MXTPU_COMPILE_CACHE``) / API (:func:`enable_persistent_cache`),
-    with the thresholds dropped to zero so every program is eligible.
-    The second process start of the same model skips XLA entirely.
+  * **Persistent compile cache** — JAX's on-disk compilation cache,
+    on by default: at ``JAX_COMPILATION_CACHE_DIR`` when whoever runs
+    the program sets it, else at ``<checkout>/.jax_cache``
+    (:func:`configure_persistent_cache`), with the thresholds dropped
+    to zero so every program is eligible.  The second process start of
+    the same model skips XLA entirely.
 
   * **Shape-bucketed dispatch** — serving traffic with ragged leading
     batch dims is padded up to a bounded bucket set (power-of-two by
@@ -33,6 +34,7 @@ Retrace/hit accounting for all three levers flows through
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import os
 import threading
@@ -41,9 +43,9 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 from .base import MXNetError, getenv
 
 __all__ = [
-    "enable_persistent_cache",
-    "disable_persistent_cache",
+    "configure_persistent_cache",
     "persistent_cache_dir",
+    "persistent_cache_bypassed",
     "graph_fingerprint",
     "set_bucket_policy",
     "get_bucket_policy",
@@ -56,11 +58,14 @@ __all__ = [
     "aot_compile",
 ]
 
+#: default cache location, ``<checkout>/.jax_cache``: derived from the
+#: package's own path, so every process started from one checkout shares
+#: it whatever its cwd (a directory that moves between runs never hits)
 _DEFAULT_CACHE_DIR = os.path.join(
-    os.path.expanduser("~"), ".cache", "mxtpu", "xla_cache")
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
 
 _lock = threading.Lock()
-_cache_dir: Optional[str] = None
 _policy_override: Optional[str] = None
 
 
@@ -68,151 +73,134 @@ _policy_override: Optional[str] = None
 # Persistent on-disk compilation cache
 # ---------------------------------------------------------------------------
 
-def enable_persistent_cache(path: Optional[str] = None) -> str:
-    """Enable JAX's persistent compilation cache at ``path``.
+def configure_persistent_cache() -> Optional[str]:
+    """Place JAX's persistent compilation cache; ``import mxtpu`` calls
+    this once, before anything can compile.  Returns the directory, or
+    None when the cache is off.
 
-    ``path`` defaults to ``MXTPU_COMPILE_CACHE`` (a value of ``1`` means
-    the default ``~/.cache/mxtpu/xla_cache``).  Safe to call at any
-    point: JAX latches its cache-enabled decision at the first
-    compilation, so this resets that latch when needed.  Returns the
-    active cache directory.
+    * ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself and this
+      code sets no directory — whoever runs the program places the
+      cache (a CI machine, the chip tool) and finds it again.
+    * unset: ``<checkout>/.jax_cache`` (:data:`_DEFAULT_CACHE_DIR`).
+    * ``MXTPU_COMPILE_CACHE=0``: no cache for this process (chaos
+      harnesses that SIGKILL their children use it).
+
+    The entry-size and compile-time thresholds are dropped so every
+    executor/CachedOp program is eligible, not just the large ones — a
+    serving fleet cold-starts hundreds of small bucket programs — and
+    cache writes are made atomic (see :func:`_patch_atomic_cache_writes`).
     """
-    global _cache_dir
-    if path is None:
-        env = getenv("MXTPU_COMPILE_CACHE")
-        path = _DEFAULT_CACHE_DIR if env in (None, "", "1", "true") else env
-    path = os.path.abspath(os.path.expanduser(path))
-    os.makedirs(path, exist_ok=True)
     import jax
 
-    with _lock:
-        jax.config.update("jax_compilation_cache_dir", path)
-        # every executor/CachedOp program should be cache-eligible, not
-        # just the ones above JAX's default size/time thresholds — a
-        # serving fleet cold-starts hundreds of small bucket programs
-        for name, val in (("jax_persistent_cache_min_entry_size_bytes", -1),
-                          ("jax_persistent_cache_min_compile_time_secs", 0.0)):
-            if hasattr(jax.config, name):
-                jax.config.update(name, val)
-        # zero thresholds mean MANY small writes, often from several
-        # processes sharing one dir — they must be atomic (torn reads
-        # heap-corrupt jaxlib 0.4.x at deserialize)
-        _patch_atomic_cache_writes()
-        _reset_jax_cache_latch()
-        _cache_dir = path
-    from . import profiler as _prof
-
-    _prof.inc_stat("persistent_cache_enabled", 0)  # ensure key exists
-    return path
-
-
-def disable_persistent_cache() -> None:
-    global _cache_dir
-    import jax
-
-    with _lock:
-        jax.config.update("jax_compilation_cache_dir", None)
-        _reset_jax_cache_latch()
-        _cache_dir = None
+    if getenv("MXTPU_COMPILE_CACHE", "1") in ("0", "false", "False", "off"):
+        jax.config.update("jax_enable_compilation_cache", False)
+        return None
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        os.makedirs(_DEFAULT_CACHE_DIR, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", _DEFAULT_CACHE_DIR)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    _patch_atomic_cache_writes()
+    return persistent_cache_dir()
 
 
 def persistent_cache_dir() -> Optional[str]:
     """The active on-disk cache directory, or None when disabled."""
-    return _cache_dir
+    import jax
+
+    if not jax.config.jax_enable_compilation_cache:
+        return None
+    return jax.config.jax_compilation_cache_dir
+
+
+@contextlib.contextmanager
+def persistent_cache_bypassed():
+    """Scope in which compiles neither read nor write the persistent
+    cache (diagnostic compiles whose HLO text must come from THIS
+    lowering, see ``inspect._compile_uncached``).  Flips JAX's enable
+    flag, never the directory, and resets the per-process latch JAX
+    keeps on its cache decision both ways.  Process-global, so scopes
+    are serialized; a normal compile on another thread during the
+    scope just compiles uncached."""
+    import jax
+    from jax._src import compilation_cache as _jax_cc
+
+    with _lock:
+        prev = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        _jax_cc.reset_cache()
+        try:
+            yield
+        finally:
+            jax.config.update("jax_enable_compilation_cache", prev)
+            _jax_cc.reset_cache()
 
 
 def _patch_atomic_cache_writes() -> None:
     """Make JAX's on-disk cache writes ATOMIC (temp + ``os.replace``).
 
-    jaxlib 0.4.x ``LRUCache.put`` writes entries with a bare
-    ``path.write_bytes`` — no temp file, and no lock unless eviction
-    is on.  A concurrent reader (this suite runs many processes
-    against ONE shared cache dir) or a SIGKILL landing mid-write
-    leaves/observes a TORN entry, and deserializing one is not a
-    graceful miss: jaxlib heap-corrupts (rc -11 / "corrupted
-    double-linked list").  With the entry-size thresholds dropped to
-    zero (see :func:`enable_persistent_cache`) every tiny program is
-    written, so the window is hit in practice.  ``os.replace`` is
-    atomic on POSIX: readers see the old state or the full entry,
-    never a partial one; an interrupted writer leaves only a ``.tmp``
-    sibling the reader never looks at.  Version-guarded: if the
-    internals moved, the patch silently does not install."""
-    try:
-        from jax._src import lru_cache as _lru
+    jax 0.9.0's ``LRUCache.put`` still writes an entry with a bare
+    ``path.write_bytes`` — no temp file, and no lock unless eviction is
+    on.  Every process of a checkout shares one cache directory and the
+    thresholds are zero, so a concurrent reader, or a SIGKILL landing
+    mid-write, meets a TORN entry; since ``put`` never overwrites an
+    existing key, a torn entry would stay a miss (plus a warning) for
+    good.  ``os.replace`` is atomic on POSIX: readers see no entry or
+    the whole entry, and an interrupted writer leaves only a ``.tmp``
+    sibling no reader looks at.  Written against the 0.9.0 internals;
+    if they moved, this raises instead of leaving writes unprotected."""
+    import tempfile
+    import time as _time
+    import warnings
 
-        cls = _lru.LRUCache
-        if getattr(cls.put, "_mxtpu_atomic", False):
+    from jax._src import lru_cache as _lru
+
+    cls = _lru.LRUCache
+    if getattr(cls.put, "_mxtpu_atomic", False):
+        return
+    cache_suffix = _lru._CACHE_SUFFIX
+    atime_suffix = _lru._ATIME_SUFFIX
+
+    def put(self, key, val):
+        if not key:
+            raise ValueError("key cannot be empty")
+        if self.eviction_enabled and len(val) > self.max_size:
+            warnings.warn(  # keep the stock diagnostic
+                f"Cache value for key {key!r} of size {len(val)} "
+                f"bytes exceeds the maximum cache size of "
+                f"{self.max_size} bytes")
             return
-        cache_suffix = _lru._CACHE_SUFFIX
-        atime_suffix = _lru._ATIME_SUFFIX
-
-        def put(self, key, val):
-            if not key:
-                raise ValueError("key cannot be empty")
-            if self.eviction_enabled and len(val) > self.max_size:
-                import warnings
-
-                warnings.warn(  # keep the stock diagnostic
-                    f"Cache value for key {key!r} of size {len(val)} "
-                    f"bytes exceeds the maximum cache size of "
-                    f"{self.max_size} bytes")
+        cache_path = self.path / f"{key}{cache_suffix}"
+        if self.eviction_enabled:
+            self.lock.acquire(timeout=self.lock_timeout_secs)
+        try:
+            if cache_path.exists():
                 return
-            cache_path = self.path / f"{key}{cache_suffix}"
-            atime_path = self.path / f"{key}{atime_suffix}"
-            if self.eviction_enabled:
-                self.lock.acquire(timeout=self.lock_timeout_secs)
+            self._evict_if_needed(additional_size=len(val))
+            # mkstemp, not a fixed pid-derived name: two THREADS
+            # putting the same key must not share one temp file (a
+            # reopen+truncate race would atomically install a torn
+            # entry — the exact corruption this patch kills)
+            fd, tmp = tempfile.mkstemp(dir=str(self.path), suffix=".tmp")
             try:
-                if cache_path.exists():
-                    return
-                self._evict_if_needed(additional_size=len(val))
-                import tempfile
-
-                # mkstemp, not a fixed pid-derived name: two THREADS
-                # putting the same key must not share one temp file
-                # (a reopen+truncate race would atomically install a
-                # torn entry — the exact corruption this patch kills)
-                fd, tmp = tempfile.mkstemp(dir=str(self.path),
-                                           suffix=".tmp")
+                with os.fdopen(fd, "wb") as f:
+                    f.write(val)
+                os.replace(tmp, cache_path)
+            except BaseException:
                 try:
-                    with os.fdopen(fd, "wb") as f:
-                        f.write(val)
-                    os.replace(tmp, cache_path)
-                except BaseException:
-                    try:
-                        os.unlink(tmp)
-                    except OSError:
-                        pass
-                    raise
-                import time as _time
-
-                atime_path.write_bytes(
+                    os.unlink(tmp)
+                except OSError:
+                    pass
+                raise
+            if self.eviction_enabled:
+                (self.path / f"{key}{atime_suffix}").write_bytes(
                     _time.time_ns().to_bytes(8, "little"))
-            finally:
-                if self.eviction_enabled:
-                    self.lock.release()
+        finally:
+            if self.eviction_enabled:
+                self.lock.release()
 
-        put._mxtpu_atomic = True
-        cls.put = put
-    except Exception:  # pragma: no cover - jax internals moved
-        pass
-
-
-def _reset_jax_cache_latch() -> None:
-    """JAX decides once per process whether the cache is used; flipping
-    the config after the first compile is a silent no-op without this."""
-    try:
-        from jax._src import compilation_cache as _cc
-
-        _cc.reset_cache()
-    except Exception:  # pragma: no cover - internal API moved
-        pass
-
-
-def _maybe_enable_from_env() -> None:
-    """Import-time hook: honor MXTPU_COMPILE_CACHE before any compile."""
-    env = getenv("MXTPU_COMPILE_CACHE")
-    if env not in (None, "", "0", "false", "False"):
-        enable_persistent_cache()
+    put._mxtpu_atomic = True
+    cls.put = put
 
 
 # ---------------------------------------------------------------------------

@@ -87,8 +87,6 @@ class Context(object):
     @property
     def jax_device(self):
         """The concrete jax.Device backing this context."""
-        import jax
-
         if self.device_typeid == 2:
             devs = _accelerator_devices()
             if not devs:
@@ -96,14 +94,13 @@ class Context(object):
                     "no TPU/accelerator devices visible to JAX; "
                     "use mxtpu.cpu() or set JAX_PLATFORMS"
                 )
-            if self.device_id >= len(devs):
-                raise MXNetError(
-                    "tpu(%d) requested but only %d device(s) present"
-                    % (self.device_id, len(devs))
-                )
-            return devs[self.device_id]
-        devs = _cpu_devices()
-        return devs[min(self.device_id, len(devs) - 1)]
+        else:
+            devs = _cpu_devices()
+        if self.device_id >= len(devs):
+            raise MXNetError(
+                "%s requested but only %d %s device(s) present"
+                % (self, len(devs), self.device_type))
+        return devs[self.device_id]
 
     def empty_cache(self):  # parity no-op: PJRT owns the HBM pool
         pass
@@ -112,12 +109,7 @@ class Context(object):
 def _accelerator_devices():
     import jax
 
-    devs = [d for d in jax.devices() if d.platform != "cpu"]
-    if devs:
-        return devs
-    # CPU-only environment (tests force JAX_PLATFORMS=cpu): treat the virtual
-    # CPU devices as "chips" so multi-device codepaths still run.
-    return jax.devices()
+    return [d for d in jax.devices() if d.platform != "cpu"]
 
 
 def _cpu_devices():
@@ -157,11 +149,7 @@ def num_tpus() -> int:
 
 def num_gpus() -> int:
     """Parity alias (reference `mxnet.context.num_gpus`)."""
-    import jax
-
-    if any(d.platform != "cpu" for d in jax.devices()):
-        return num_tpus()
-    return 0
+    return num_tpus()
 
 
 def default_ctx() -> Context:
@@ -172,10 +160,8 @@ def default_ctx() -> Context:
             Context._default_ctx.value = Context(name, int(idx or 0))
         else:
             # TPU if one is attached, else CPU.
-            import jax
-
-            has_acc = any(d.platform != "cpu" for d in jax.devices())
-            Context._default_ctx.value = Context("tpu" if has_acc else "cpu", 0)
+            Context._default_ctx.value = Context(
+                "tpu" if _accelerator_devices() else "cpu", 0)
     return Context._default_ctx.value
 
 

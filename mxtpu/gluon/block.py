@@ -606,8 +606,9 @@ class HybridBlock(Block):
         input, e.g. ``[(8, 3, 224, 224)]`` — or a list of signatures,
         e.g. one per serving bucket.  Parameters must be initialized;
         the cache is traced from dummy zeros of the first signature if
-        absent.  With `MXTPU_COMPILE_CACHE` enabled, warmup on a warm
-        process start deserializes from disk instead of compiling."""
+        absent.  With the persistent compile cache warm
+        (`docs/compile_cache.md`), warmup deserializes from disk
+        instead of compiling."""
         if not self._active:
             raise MXNetError("warmup requires hybridize()")
         sigs = list(input_shapes)

@@ -53,16 +53,25 @@ def default_batchify_fn(data):
     return nd_array(arr)
 
 
+def _holds_ndarray(sample):
+    if isinstance(sample, (tuple, list)):
+        return any(_holds_ndarray(s) for s in sample)
+    return isinstance(sample, NDArray)
+
+
+_NDARRAY_SAMPLE_MSG = (
+    "process workers (thread_pool=False) need datasets that return "
+    "numpy/python samples — NDArray samples would pull the device "
+    "runtime into the forked worker, and the device belongs to the "
+    "parent (one process per chip); use thread_pool=True (default) or "
+    "return numpy from __getitem__")
+
+
 def _np_batchify(data):
     """Worker-side batchify: pure numpy (workers must never initialize
     jax — the device belongs to the parent)."""
     if isinstance(data[0], NDArray):
-        raise MXNetError(
-            "process workers (thread_pool=False) need datasets that "
-            "return numpy/python samples — NDArray samples would pull "
-            "the device runtime into the forked worker; use "
-            "thread_pool=True (default) or return numpy from "
-            "__getitem__")
+        raise MXNetError(_NDARRAY_SAMPLE_MSG)
     if isinstance(data[0], tuple):
         return tuple(_np_batchify(list(i)) for i in zip(*data))
     arr = np.asarray(data)
@@ -355,6 +364,13 @@ class DataLoader(object):
             batchify = _np_batchify
         ctx = multiprocessing.get_context("fork")
         batches = list(self._batch_sampler)[skip:]
+        # the forked children inherit a parent that may hold the chip,
+        # and must never touch JAX: read one sample HERE, where that is
+        # safe, and refuse a dataset that hands out device arrays
+        # before any worker exists (whatever batchify_fn is in use)
+        if batches and batches[0] and \
+                _holds_ndarray(self._dataset[batches[0][0]]):
+            raise MXNetError(_NDARRAY_SAMPLE_MSG)
         pool = ctx.Pool(min(self._num_workers, max(1, len(batches))),
                         initializer=_worker_init,
                         initargs=(self._dataset,))

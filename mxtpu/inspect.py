@@ -88,9 +88,6 @@ _lock = threading.RLock()
 # path (track_compile): serving threads sharing one CachedOp must
 # resolve a brand-new signature to exactly ONE compile token
 _sig_lock = threading.Lock()
-# serializes the global compile-cache config flip in _compile_uncached
-# (never held together with _lock; analysis runs outside _lock)
-_cfg_lock = threading.Lock()
 _REGISTRY: "collections.OrderedDict[str, ProgramRecord]" = \
     collections.OrderedDict()
 _BLAME: "collections.Counter" = collections.Counter()
@@ -227,28 +224,10 @@ def _compile_uncached(lowered):
     ``hlo_text()`` would then show the twin's layer names, defeating
     attribution.  Cost/memory figures are name-independent, but the
     text must come from THIS program's lowering."""
-    import jax
-
     from . import compile_cache as _cc
 
-    # The flip is process-global, so two concurrent diagnostic
-    # compiles must not interleave their save/restore (the second
-    # would snapshot None and "restore" the cache to disabled).
-    with _cfg_lock:
-        try:
-            # jax_enable_compilation_cache alone is a no-op on 0.4.x
-            # once the per-process cache decision has latched; clearing
-            # the dir and resetting the latch is the lever that works.
-            prev = jax.config.jax_compilation_cache_dir
-            jax.config.update("jax_compilation_cache_dir", None)
-            _cc._reset_jax_cache_latch()
-        except Exception:
-            return lowered.compile()
-        try:
-            return lowered.compile()
-        finally:
-            jax.config.update("jax_compilation_cache_dir", prev)
-            _cc._reset_jax_cache_latch()
+    with _cc.persistent_cache_bypassed():
+        return lowered.compile()
 
 
 class _SigInfo(object):

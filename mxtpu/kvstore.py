@@ -13,10 +13,10 @@ Backends:
     CommDevice GPU P2P merge `comm.h:451` and the NCCL ring
     `kvstore_nccl.h:62`): device-to-device transfers ride ICI, the sum is
     one jitted executable on the merge device.
-  * ``tpu`` — the north-star backend (SURVEY.md): when a
-    `mxtpu.parallel` mesh is active, push/pull is an XLA all-reduce over
-    the mesh's data axis (`jax.lax.psum` under shard_map); otherwise it
-    degrades to the on-device merge.
+  * ``tpu`` — the north-star backend (SURVEY.md): push is an XLA
+    all-reduce (`jax.lax.psum` under shard_map) over the data axis of
+    the active `mxtpu.parallel` mesh or, with none, over the devices
+    the pushed values live on.
   * ``dist_sync`` / ``dist_device_sync`` / ``dist_async`` — multi-process
     parameter server over TCP (`mxtpu/_ps.py`), the analog of the ps-lite
     path (`kvstore_dist.h:44`, `kvstore_dist_server.h:155`).  Roles are
@@ -355,12 +355,17 @@ class KVStoreDevice(KVStore):
 
 
 class KVStoreTPU(KVStoreDevice):
-    """`tpu` backend: XLA all-reduce over the active mesh's data axis.
+    """`tpu` backend: XLA all-reduce over the replicas' devices.
 
-    With a live mesh whose data axis matches the number of pushed
-    per-device values, the merge is `jax.lax.psum` under shard_map (one
-    compiled collective over ICI); otherwise falls back to the on-device
-    fused merge.  This is the BASELINE.json ``kvstore=tpu`` north star.
+    The merge of n pushed per-device values is `jax.lax.psum` under
+    shard_map (one compiled collective over ICI) along a line of n
+    devices: the data axis of the live mesh when its size is n, else
+    the n distinct devices the values already live on — which is what
+    `Module(context=[tpu(0), ...])` pushes, with no mesh in sight.
+    With neither, values that all sit on ONE device are summed there
+    (nothing to reduce across) and anything in between raises.
+    `last_reduce_path` names the path taken.  This is the BASELINE.json
+    ``kvstore=tpu`` north star.
 
     Mesh and axis resolve through the sharding backbone: an explicit
     ctor arg wins, then the `MeshContext` stack, then the active
@@ -372,8 +377,8 @@ class KVStoreTPU(KVStoreDevice):
         super().__init__()
         self._mesh = mesh
         self._axis = axis  # None = the active plan's data axis
-        self.last_reduce_path = None  # "psum" | "fallback" (introspection)
-        self._warned_fallback = False
+        # "psum" | "local" (one value, or all on one device)
+        self.last_reduce_path = None
 
     @property
     def type(self):
@@ -410,44 +415,68 @@ class KVStoreTPU(KVStoreDevice):
         line = np.moveaxis(mesh.devices, ai, 0).reshape(n, -1)[:, 0]
         return Mesh(line, (axis,))
 
+    def _own_line_mesh(self, vals: List[NDArray], axis):
+        """A 1-D mesh over the devices the pushed values live on, in
+        push order, when those are len(vals) distinct devices."""
+        devs = []
+        for v in vals:
+            ds = v._data.devices()
+            if len(ds) != 1:
+                return None
+            devs.append(next(iter(ds)))
+        if len(set(devs)) != len(devs):
+            return None
+        from jax.sharding import Mesh
+
+        return Mesh(np.array(devs), (axis,))   # jax interns equal meshes
+
     def _reduce(self, k, vals: List[NDArray]) -> NDArray:
-        mesh, axis = self._resolve()
         n = len(vals)
+        mesh, axis = self._resolve()
         line = self._dp_line_mesh(mesh, n, axis) \
             if mesh is not None and n > 1 else None
-        if line is not None:
-            import jax
-            from jax.sharding import NamedSharding, PartitionSpec
+        if line is None and n > 1:
+            line = self._own_line_mesh(vals, axis)
+        if line is None:
+            devices = set().union(*(v._data.devices() for v in vals))
+            if len(devices) == 1:
+                # one value, or every replica on one device and no
+                # mesh to spread them over: nothing to reduce across
+                self.last_reduce_path = "local"
+                return super()._reduce(k, vals)
+            raise MXNetError(
+                "kvstore=tpu: %d pushed values span %d device(s) and no "
+                "active mesh has a %r axis of size %d, so there is no "
+                "line of devices to all-reduce over; push one value per "
+                "device, or use kvstore='device' for an on-device merge"
+                % (n, len(devices), axis, n))
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec
 
-            from .parallel import collectives
+        from .parallel import collectives
 
-            # one shard per pushed value, placed on the reduce-line
-            # devices in order — no host round-trip, replica i's gradient
-            # stays on (or moves device-to-device to) line device i
-            sharding = NamedSharding(line, PartitionSpec(axis))
-            shape0 = vals[0].shape
-            line_devs = list(line.devices.flat)
-            shards = [jax.device_put(v._data.reshape((1,) + shape0), d)
-                      for v, d in zip(vals, line_devs)]
-            stacked = jax.make_array_from_single_device_arrays(
-                (n,) + shape0, sharding, shards)
-            merged = collectives.all_reduce(stacked, axis=axis,
-                                            mesh=line)[0]
-            if self._compression is not None:
-                merged = self._compression.compress(k, merged)
-            self.last_reduce_path = "psum"
-            return NDArray(merged, ctx=vals[0].ctx, _committed=True)
-        if mesh is not None and n > 1 and not self._warned_fallback:
-            import logging
-
-            logging.getLogger(__name__).warning(
-                "kvstore=tpu: %d pushed values do not line up with the "
-                "mesh's %r axis (shape %s) — falling back to the fused "
-                "device merge (no XLA collective)", n, axis,
-                dict(mesh.shape))
-            self._warned_fallback = True
-        self.last_reduce_path = "fallback"
-        return super()._reduce(k, vals)
+        # one shard per pushed value, placed on the reduce-line devices
+        # in order — no host round-trip, replica i's gradient stays on
+        # (or moves device-to-device to) line device i
+        sharding = NamedSharding(line, PartitionSpec(axis))
+        shape0 = vals[0].shape
+        line_devs = list(line.devices.flat)
+        shards = [jax.device_put(v._data.reshape((1,) + shape0), d)
+                  for v, d in zip(vals, line_devs)]
+        stacked = jax.make_array_from_single_device_arrays(
+            (n,) + shape0, sharding, shards)
+        summed = collectives.all_reduce(stacked, axis=axis, mesh=line)
+        # every line device now holds the sum; hand back the copy on
+        # the first value's own device (a single-device array, like the
+        # values pushed — the updater mixes it with the stored weight)
+        home = vals[0]._data.devices()
+        shards = summed.addressable_shards
+        merged = next((s for s in shards if s.device in home),
+                      shards[0]).data[0]
+        if self._compression is not None:
+            merged = self._compression.compress(k, merged)
+        self.last_reduce_path = "psum"
+        return NDArray(merged, ctx=vals[0].ctx, _committed=True)
 
 
 # ---------------------------------------------------------------------------

@@ -79,8 +79,8 @@ def _count_bytes(counter: str, x, factor: float,
 def _compiled_collective(kind, mesh, axis, perm_key):
     import jax
     from jax.sharding import PartitionSpec as P
-    from .mesh import get_shard_map
-    shard_map = get_shard_map()
+
+    shard_map = jax.shard_map
 
     spec_in = P(axis)       # sharded along leading dim over `axis`
     spec_rep = P()          # fully replicated

@@ -34,43 +34,6 @@ _CANONICAL_ORDER = (AXIS_DP, AXIS_PP, AXIS_TP, AXIS_SP, AXIS_EP)
 _state = threading.local()
 
 
-def get_shard_map():
-    """The shard_map entry point, wherever this JAX version keeps it
-    (top-level `jax.shard_map` on new releases,
-    `jax.experimental.shard_map.shard_map` on 0.4.x).  Every
-    shard_map user in the tree resolves through here so one JAX bump
-    can't strand half the call sites."""
-    import jax
-
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm
-    import functools
-
-    from jax.experimental.shard_map import shard_map
-
-    @functools.wraps(shard_map)
-    def compat(f, *args, **kwargs):
-        # 0.4.x's static replication checker predates the vma tracking
-        # these programs are written against and rejects out_specs the
-        # newer checker proves fine — run unchecked there
-        kwargs.setdefault("check_rep", False)
-        return shard_map(f, *args, **kwargs)
-
-    return compat
-
-
-def axis_size(axis_name) -> int:
-    """Static size of a named mesh axis from inside a shard_map'ped
-    function.  `jax.lax.axis_size` only exists on newer JAX; on 0.4.x
-    `lax.psum(1, axis)` constant-folds to the same static int."""
-    import jax
-
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
 def default_mesh_shape(n_devices: int,
                        tp: int = 1, pp: int = 1, sp: int = 1,
                        ep: int = 1) -> Dict[str, int]:
@@ -125,29 +88,22 @@ def current_mesh():
 
 class MeshContext(object):
     """`with MeshContext(mesh):` — like the reference's Context scope but
-    for a whole device mesh.  Also enters `jax.sharding.use_mesh` (when
-    this jax provides it) so jit-traced code can use bare PartitionSpecs
-    and collectives with the axis names resolved."""
+    for a whole device mesh.  It is this package's own stack, read back
+    through :func:`current_mesh`; it enters no ambient JAX mesh
+    (`jax.set_mesh`), because every consumer (`collectives`, the `tpu`
+    kvstore, the sharding plan) hands the mesh explicitly to
+    `jax.shard_map` / `NamedSharding` and none uses bare
+    PartitionSpecs."""
 
     def __init__(self, mesh):
         self._mesh = mesh
-        self._inner = None
 
     def __enter__(self):
-        import jax
-
         if not hasattr(_state, "stack"):
             _state.stack = []
         _state.stack.append(self._mesh)
-        use_mesh = getattr(jax.sharding, "use_mesh", None)
-        if use_mesh is not None:
-            self._inner = use_mesh(self._mesh)
-            self._inner.__enter__()
         return self._mesh
 
     def __exit__(self, *exc):
         _state.stack.pop()
-        if self._inner is not None:
-            inner, self._inner = self._inner, None
-            return inner.__exit__(*exc)
         return False

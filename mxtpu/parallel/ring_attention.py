@@ -24,7 +24,6 @@ from __future__ import annotations
 
 import functools
 from typing import Optional
-from .mesh import axis_size as _axis_size
 
 __all__ = ["ring_attention", "blockwise_attention", "ring_self_attention"]
 
@@ -44,14 +43,11 @@ def _pallas_enabled() -> bool:
 
 def _match_vma(x, like):
     """Mark `x` as varying over the manual mesh axes `like` varies over
-    (required for lax loop carries under jax>=0.8 shard_map vma
-    tracking); no-op outside shard_map."""
+    (lax loop carries need it under shard_map's vma tracking); no-op
+    outside shard_map."""
     import jax
 
-    try:
-        want = set(jax.typeof(like).vma) - set(jax.typeof(x).vma)
-    except (AttributeError, TypeError):
-        return x
+    want = set(jax.typeof(like).vma) - set(jax.typeof(x).vma)
     if want:
         x = jax.lax.pcast(x, tuple(want), to="varying")
     return x
@@ -175,7 +171,7 @@ def _ring_forward(q, k, v, axis_name, causal, scale):
     import jax
     import jax.numpy as jnp
 
-    sp_size = _axis_size(axis_name)
+    sp_size = jax.lax.axis_size(axis_name)
     my_idx = jax.lax.axis_index(axis_name)
     B, H, T, D = q.shape
     perm = [(i, (i + 1) % sp_size) for i in range(sp_size)]
@@ -218,7 +214,7 @@ def _ring_backward(q, k, v, out, lse, g, axis_name, causal, scale):
     import jax
     import jax.numpy as jnp
 
-    sp_size = _axis_size(axis_name)
+    sp_size = jax.lax.axis_size(axis_name)
     my_idx = jax.lax.axis_index(axis_name)
     B, H, T, D = q.shape
     perm = [(i, (i + 1) % sp_size) for i in range(sp_size)]
@@ -318,7 +314,7 @@ def ring_attention(q, k, v, axis_name: str = "sp", causal: bool = False,
     # differentiated by JAX AD through its block loop, which stashes
     # O(T^2/block) probability residuals — exactly the memory blowup
     # this module's recompute backward exists to avoid.
-    if _axis_size(axis_name) == 1 and _pallas_enabled() \
+    if jax.lax.axis_size(axis_name) == 1 and _pallas_enabled() \
             and q.shape[2] == k.shape[2]:
         return blockwise_attention(q, k, v, causal=causal, scale=scale,
                                    use_pallas=True)

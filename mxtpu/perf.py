@@ -34,9 +34,9 @@ consume; arXiv 1802.04799's premise that optimization is search over
 
   * **Live MFU + roofline** — measured per-call wall (the sampled
     call→ready span) joined against the `mx.inspect` registry's
-    ``cost_analysis`` FLOPs/bytes and a per-backend peak table
-    (``MXTPU_PEAK_FLOPS`` / ``MXTPU_PEAK_BYTES`` override the coarse
-    CPU/TPU defaults) gives per-program MFU and a compute- vs
+    ``cost_analysis`` FLOPs/bytes and the peak table keyed by
+    ``device_kind`` (:data:`DEVICE_PEAKS`; ``MXTPU_PEAK_FLOPS`` /
+    ``MXTPU_PEAK_BYTES`` override) gives per-program MFU and a compute- vs
     memory-bound roofline classification: operational intensity
     (flops/byte) above the machine's ridge point (peak_flops /
     peak_bytes) means the program is compute-bound — more FLOPs/s
@@ -66,12 +66,13 @@ See `docs/observability.md` §Performance.
 """
 from __future__ import annotations
 
+import functools
 import os
 import threading
 import time
 from typing import Any, Dict, List, Optional
 
-from .base import getenv, getenv_bool, getenv_int
+from .base import MXNetError, getenv, getenv_bool, getenv_int
 
 __all__ = [
     "PHASES",
@@ -81,6 +82,9 @@ __all__ = [
     "begin",
     "end",
     "note_phase",
+    "DEVICE_PEAKS",
+    "peaks_for",
+    "device_peaks",
     "peak_flops",
     "peak_bytes",
     "roofline",
@@ -99,18 +103,20 @@ PHASES = ("input_wait", "host_dispatch", "device_compute", "optimizer",
 
 _ENABLED = getenv_bool("MXTPU_PERF", True)
 
-#: coarse per-backend peaks (flops/s, HBM bytes/s) — deliberately
-#: round numbers for a *relative* utilization signal; override with
-#: MXTPU_PEAK_FLOPS / MXTPU_PEAK_BYTES for calibrated absolute MFU.
-#: cpu is computed from the core count (see _default_peaks).
-_BACKEND_PEAKS = {
-    # TPU v4-ish: 275 TFLOP/s bf16 MXU, 1.2 TB/s HBM
-    "tpu": (275e12, 1.2e12),
-    # A100-class: 312 TFLOP/s bf16, 2 TB/s
-    "gpu": (312e12, 2.0e12),
+#: THE peak table: published per-chip peaks keyed by the
+#: ``device_kind`` JAX reports.  Every MFU / roofline figure in the
+#: tree (`mx.perf`, `mx.xprof`, `tools/hlo_report.py`, `bench.py`)
+#: reads this one table; an accelerator that is not in it is an error
+#: (:func:`device_peaks`), never a default.
+DEVICE_PEAKS = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16,
+    # 393 TOP/s int8, 16 GB HBM2e at 819 GB/s per chip
+    "TPU v5 lite": {"flops": 197e12, "int8_ops": 393e12,
+                    "bytes_per_s": 819e9, "hbm_bytes": 16e9},
 }
-# per-core CPU guess: ~2.5 GHz x 8 f32 lanes x 2 (FMA) = 40 GFLOP/s,
-# and ~40 GB/s of shared memory bandwidth for the whole socket
+# a CPU has no published figure to key on: an ESTIMATE from the core
+# count (~2.5 GHz x 8 f32 lanes x 2 (FMA) = 40 GFLOP/s per core, ~40
+# GB/s for the socket), good for a relative signal in CPU tests only
 _CPU_FLOPS_PER_CORE = 4e10
 _CPU_BYTES = 4e10
 
@@ -309,50 +315,57 @@ def note_phase_since(phase: str, t0: Optional[float]) -> None:
 # Peak table + roofline
 # ---------------------------------------------------------------------------
 
-_backend_cache: List[Optional[str]] = [None]
+def peaks_for(platform: str, device_kind: str) -> Dict[str, Any]:
+    """The :data:`DEVICE_PEAKS` row for one device, plus its
+    ``device_kind`` and an ``estimate`` flag (true only for the CPU's
+    core-count estimate).  An accelerator kind that is not in the table
+    raises: a utilization against a guessed peak is worse than none."""
+    if platform == "cpu":
+        cores = os.cpu_count() or 1
+        return {"device_kind": device_kind, "estimate": True,
+                "flops": _CPU_FLOPS_PER_CORE * cores,
+                "bytes_per_s": _CPU_BYTES}
+    row = DEVICE_PEAKS.get(device_kind)
+    if row is None:
+        raise MXNetError(
+            "no peak figures for %s device_kind %r: add its published "
+            "peaks, with their source, to mxtpu.perf.DEVICE_PEAKS "
+            "(known: %s)" % (platform, device_kind,
+                             ", ".join(sorted(DEVICE_PEAKS))))
+    return dict(row, device_kind=device_kind, estimate=False)
 
 
-def _backend() -> str:
-    if _backend_cache[0] is None:
-        try:
-            import jax
+@functools.lru_cache(maxsize=1)
+def device_peaks() -> Dict[str, Any]:
+    """:func:`peaks_for` the process's first JAX device."""
+    import jax
 
-            _backend_cache[0] = jax.default_backend()
-        except Exception:
-            _backend_cache[0] = "cpu"
-    return _backend_cache[0]
-
-
-def _default_peaks() -> tuple:
-    b = _backend()
-    if b in _BACKEND_PEAKS:
-        return _BACKEND_PEAKS[b]
-    cores = os.cpu_count() or 1
-    return (_CPU_FLOPS_PER_CORE * cores, _CPU_BYTES)
+    d = jax.devices()[0]
+    return peaks_for(d.platform, d.device_kind)
 
 
 def peak_flops() -> float:
-    """Peak device flops/s: ``MXTPU_PEAK_FLOPS`` override, else the
-    per-backend table (coarse — calibrate for absolute MFU)."""
+    """Peak device flops/s (bf16 on a TPU): ``MXTPU_PEAK_FLOPS``
+    override, else the :data:`DEVICE_PEAKS` table."""
     env = getenv("MXTPU_PEAK_FLOPS")
     if env:
         return float(env)
-    return _default_peaks()[0]
+    return device_peaks()["flops"]
 
 
 def peak_bytes() -> float:
     """Peak memory bandwidth bytes/s: ``MXTPU_PEAK_BYTES`` override,
-    else the per-backend table."""
+    else the :data:`DEVICE_PEAKS` table."""
     env = getenv("MXTPU_PEAK_BYTES")
     if env:
         return float(env)
-    return _default_peaks()[1]
+    return device_peaks()["bytes_per_s"]
 
 
 def mfu(flops: float, wall_s: float) -> Optional[float]:
     """Model-flops utilization of one program call: achieved flops/s
-    over :func:`peak_flops`, clamped into (0, 1] (a coarse default
-    peak table must not report a nonsense >1)."""
+    over :func:`peak_flops`, clamped into (0, 1] (the CPU's estimated
+    peak must not report a nonsense >1)."""
     if not flops or not wall_s or wall_s <= 0:
         return None
     return min(1.0, flops / (wall_s * peak_flops()))

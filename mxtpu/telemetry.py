@@ -304,8 +304,7 @@ def record_input_wait(dur_s: float) -> None:
     batch (DataLoader / DataIter ``__next__``).  Always-on gauge
     (``input_wait_us_last`` in `profiler.stats()`) + running totals in
     :func:`metrics` — this is what attributes an input-bound step-time
-    gap (the 911us/step dispatch gap in BENCH_r05) to the pipeline
-    instead of the device.  Producers that can NEST (a DataLoader
+    gap to the pipeline instead of the device.  Producers that can NEST (a DataLoader
     whose fetch drives an inner DataIter — both used to stamp the same
     wait, double-counting it) should wrap the fetch in
     :func:`input_wait` instead, which records only at the outermost

@@ -135,6 +135,25 @@ def _backend() -> str:
         return "unknown"
 
 
+def _require_parent_off_chip() -> None:
+    """Trials are subprocesses and each needs the chip, which belongs
+    to one process at a time: a parent that has already initialized an
+    accelerator backend would make every trial fail or hang."""
+    import jax
+    from jax._src import xla_bridge as _xb
+
+    if _xb.backends_are_initialized() and jax.default_backend() != "cpu":
+        from ..base import MXNetError
+
+        raise MXNetError(
+            "mx.tune.tune: this process already holds the %s device, "
+            "and a chip belongs to one process at a time, so the trial "
+            "subprocesses could not reach it; start the tuning session "
+            "from a process that has not run anything on the device "
+            "(build Symbols only), or under JAX_PLATFORMS=cpu"
+            % jax.default_backend())
+
+
 def maybe_apply(symbol=None, name: Optional[str] = None,
                 profile: str = "", site: str = "bind") -> Optional[str]:
     """Auto-apply hook: when ``MXTPU_TUNE=apply`` and the tuning DB
@@ -207,6 +226,7 @@ def tune(bench_argv: Sequence[str],
     """
     from .. import telemetry as _tel
 
+    _require_parent_off_chip()
     analysis = None
     if symbol is not None:
         try:

@@ -28,11 +28,11 @@ __all__ = ["SCHEMA", "db_dir", "entry_key", "store", "lookup",
 
 def db_dir(path: Optional[str] = None) -> str:
     """Resolve the DB directory: explicit arg > ``MXTPU_TUNE_DB`` env
-    > ``~/.cache/mxtpu/tune_db`` (mirrors the compile-cache default)."""
-    d = path or os.environ.get("MXTPU_TUNE_DB") \
-        or os.path.join(os.path.expanduser("~"), ".cache", "mxtpu",
-                        "tune_db")
-    return d
+    > ``<checkout>/.tune_db`` (derived from the package path like the
+    compile-cache default, so it travels with the checkout)."""
+    return path or os.environ.get("MXTPU_TUNE_DB") \
+        or os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__)))), ".tune_db")
 
 
 def entry_key(graph: str, backend: str, profile: str) -> str:

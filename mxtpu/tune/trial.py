@@ -33,9 +33,10 @@ __all__ = ["Trial", "TrialRunner", "objective", "default_trial_timeout"]
 
 def default_trial_timeout() -> float:
     """Per-trial wall budget in seconds: ``MXTPU_TUNE_TRIAL_TIMEOUT``
-    (default 300).  A wedged bench — deadlocked collective, hung
-    accelerator tunnel — is killed as a whole process group when the
-    budget expires and the trial scores ``inf``."""
+    (default 300).  A wedged bench — a deadlocked collective, a child
+    waiting for a chip another process holds — is killed as a whole
+    process group when the budget expires and the trial scores
+    ``inf``."""
     try:
         return float(os.environ.get("MXTPU_TUNE_TRIAL_TIMEOUT", "300"))
     except ValueError:

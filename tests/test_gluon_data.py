@@ -141,3 +141,21 @@ def test_device_prefetch_error_sentinel_survives_full_queue(monkeypatch):
         "consumer hung: error sentinel was dropped on the full queue"
     assert outcome.get("result") == "raised"
     assert outcome["batches"] == (0.0, 4.0)
+
+
+def test_process_workers_refuse_device_array_datasets():
+    """The forked children must never touch JAX (the device belongs to
+    the parent, one process per chip): a dataset handing out NDArrays
+    is refused in the PARENT, before any worker is forked, whatever
+    batchify_fn is in use."""
+    import pytest
+
+    import mxtpu as mx
+    from mxtpu.gluon.data import ArrayDataset
+    from mxtpu.gluon.data.dataloader import DataLoader
+
+    ds = ArrayDataset(mx.nd.ones((8, 2)), np.arange(8, dtype=np.float32))
+    dl = DataLoader(ds, batch_size=4, num_workers=2, thread_pool=False,
+                    batchify_fn=lambda samples: samples)
+    with pytest.raises(mx.MXNetError, match="one process per chip"):
+        next(iter(dl))

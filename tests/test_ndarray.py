@@ -124,6 +124,24 @@ def test_context_movement():
     assert b is a
 
 
+def test_contexts_name_real_devices():
+    """On a host with no accelerator there are no TPUs to count, and
+    `mx.tpu()` raises instead of standing for a CPU device; an index
+    past the last device raises too instead of clamping."""
+    import jax
+
+    assert mx.num_tpus() == 0 and mx.num_gpus() == 0
+    assert mx.current_context() == mx.cpu(0)
+    with pytest.raises(mx.MXNetError, match="no TPU"):
+        mx.tpu().jax_device
+    with pytest.raises(mx.MXNetError, match="no TPU"):
+        nd.ones((2, 3), ctx=mx.gpu(0))
+    n = len(jax.devices("cpu"))
+    assert mx.cpu(n - 1).jax_device is jax.devices("cpu")[n - 1]
+    with pytest.raises(mx.MXNetError, match="only %d cpu" % n):
+        mx.cpu(n).jax_device
+
+
 def test_comparisons():
     a = nd.array([1.0, 2.0, 3.0])
     b = nd.array([2.0, 2.0, 2.0])

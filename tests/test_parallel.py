@@ -15,53 +15,6 @@ import mxtpu.parallel as par
 from mxtpu.parallel import transformer as tfm
 from mxtpu.parallel.mesh import (AXIS_DP, AXIS_PP, AXIS_TP, AXIS_SP,
                                  AXIS_EP)
-from mxtpu.parallel.mesh import get_shard_map
-
-
-def _spmd_partition_id_unsupported() -> bool:
-    """Probe whether this jaxlib's SPMD partitioner supports the
-    PartitionId instruction that `lax.while_loop`s over
-    `axis_index`-dependent bounds lower to.  CPU jaxlib 0.4.x raises
-    UNIMPLEMENTED ("PartitionId instruction is not supported for SPMD
-    partitioning"); the transformer train step and non-causal ring
-    attention hit that path (crash) or its silent fallback (numeric
-    divergence).  One tiny 2-device probe, run once per process."""
-    if jax.device_count() < 2:
-        return False  # conftest skips the whole file anyway
-    try:
-        import jax.numpy as jnp
-
-        mesh = par.create_mesh({AXIS_SP: 2}, devices=jax.devices()[:2])
-
-        def probe(x):
-            i = jax.lax.axis_index(AXIS_SP)
-
-            def body(c):
-                return c[0] + 1, c[1] + jnp.float32(1.0)
-
-            _, v = jax.lax.while_loop(lambda c: c[0] < i + 1, body,
-                                      (jnp.int32(0), x))
-            return v
-
-        from jax.sharding import PartitionSpec as P
-
-        sm = jax.jit(get_shard_map()(probe, mesh=mesh,
-                                     in_specs=(P(AXIS_SP, None),),
-                                     out_specs=P(AXIS_SP, None)))
-        jax.block_until_ready(
-            sm(np.arange(8, dtype=np.float32).reshape(2, 4)))
-        return False
-    except Exception as e:  # XlaRuntimeError on the unsupported builds
-        return "PartitionId" in str(e)
-
-
-_NO_SPMD_PARTITION_ID = _spmd_partition_id_unsupported()
-_SPMD_SKIP = pytest.mark.skipif(
-    _NO_SPMD_PARTITION_ID,
-    reason="CPU jaxlib SPMD partitioner lacks PartitionId "
-           "(while_loop over axis_index): sharded transformer train "
-           "steps silently diverge on this build; green on TPU and on "
-           "jaxlibs that pass the module-level probe")
 
 
 def _mesh(dp=1, pp=1, tp=1, sp=1, ep=1):
@@ -129,20 +82,17 @@ class TestShardedTrainConsistency:
             losses.append(float(jax.device_get(loss)))
         return losses
 
-    @_SPMD_SKIP
     def test_train_matches_single_device(self):
         ref = self._loss(CFG, _mesh())
         got = self._loss(CFG, _mesh(dp=2, pp=2, tp=2))
         np.testing.assert_allclose(got, ref, rtol=5e-4, atol=5e-4)
         assert ref[-1] < ref[0]  # it actually learns
 
-    @_SPMD_SKIP
     def test_train_sp_ring(self):
         ref = self._loss(CFG, _mesh())
         got = self._loss(CFG, _mesh(sp=4))
         np.testing.assert_allclose(got, ref, rtol=5e-4, atol=5e-4)
 
-    @_SPMD_SKIP
     def test_train_moe_ep(self):
         cfg = tfm.TransformerConfig(vocab=32, d_model=16, n_heads=4,
                                     n_layers=2, d_ff=32, n_experts=4,
@@ -175,14 +125,7 @@ class TestRingAttention:
         np.testing.assert_allclose(out, self._naive(q, k, v, causal),
                                    rtol=1e-5, atol=1e-5)
 
-    @pytest.mark.parametrize("causal", [
-        pytest.param(False, marks=pytest.mark.skipif(
-            _NO_SPMD_PARTITION_ID,
-            reason="non-causal ring attention crashes on CPU jaxlibs "
-                   "whose SPMD partitioner lacks PartitionId "
-                   "(UNIMPLEMENTED at dispatch); causal path and TPU "
-                   "builds are unaffected")),
-        True])
+    @pytest.mark.parametrize("causal", [False, True])
     def test_ring_matches_naive(self, causal):
         from jax.sharding import PartitionSpec as P
 
@@ -197,7 +140,7 @@ class TestRingAttention:
                                       causal=causal)
 
         spec = P(None, None, AXIS_SP, None)
-        sm = jax.jit(get_shard_map()(
+        sm = jax.jit(jax.shard_map(
             f, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec))
         out = np.asarray(jax.device_get(sm(q, k, v)))
         np.testing.assert_allclose(out, self._naive(q, k, v, causal),
@@ -221,7 +164,7 @@ class TestRingAttention:
             return (jnp.sin(o) * o).sum()  # non-uniform cotangent
 
         spec = P(None, None, AXIS_SP, None)
-        grads_ring = jax.jit(get_shard_map()(
+        grads_ring = jax.jit(jax.shard_map(
             lambda q, k, v: jax.grad(ring_loss, argnums=(0, 1, 2))(
                 q, k, v),
             mesh=mesh, in_specs=(spec, spec, spec),
@@ -299,7 +242,6 @@ class TestMesh:
         assert par.current_mesh() is None
 
 
-@_SPMD_SKIP
 def test_zero1_adam_matches_unsharded_and_shards_memory():
     """ZeRO-1 sharded Adam (arxiv 2004.13336): dp=2 chunked update must
     match the dp=1 (unsharded) trajectory exactly — Adam is

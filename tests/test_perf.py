@@ -242,6 +242,21 @@ def test_peak_table_env_overrides(monkeypatch):
     assert perf.mfu(0.0, 1.0) is None
 
 
+def test_peak_table_is_keyed_by_device_kind():
+    """ONE table, keyed by the device_kind JAX reports: the v5e row
+    carries the published figures, a CPU gets a flagged estimate, and
+    an accelerator that is not in the table raises instead of
+    borrowing another chip's peak."""
+    v5e = perf.peaks_for("tpu", "TPU v5 lite")
+    assert (v5e["flops"], v5e["bytes_per_s"]) == (197e12, 819e9)
+    assert (v5e["int8_ops"], v5e["hbm_bytes"]) == (393e12, 16e9)
+    assert v5e["estimate"] is False
+    cpu = perf.device_peaks()           # this suite runs on a CPU
+    assert cpu["estimate"] is True and cpu["flops"] > 0
+    with pytest.raises(mx.MXNetError, match="TPU v9000"):
+        perf.peaks_for("tpu", "TPU v9000")
+
+
 # ---------------------------------------------------------------------------
 # Disabled mode / metrics surface
 # ---------------------------------------------------------------------------

@@ -421,9 +421,13 @@ class TestFusedCarry:
         mesh = parallel.create_mesh({"dp": 4},
                                     devices=jax.devices()[:4])
         ps, info = self._run(ShardingPlan(mesh=mesh, min_shard_elems=64))
+        # float tolerance, not bitwise: GSPMD's sharded program sums
+        # in another order than the unsharded one on XLA:CPU (jax
+        # 0.9.0), and Adam's m/sqrt(v) turns a last-bit gradient
+        # difference into ~1e-6 of a weight within these four steps
         for k in pr:
-            np.testing.assert_allclose(pr[k], ps[k], rtol=1e-6,
-                                       atol=1e-6, err_msg=k)
+            np.testing.assert_allclose(pr[k], ps[k], rtol=1e-5,
+                                       atol=1e-5, err_msg=k)
         assert info is not None and "zero1:n=4" in info["plan"]
         per_dev = list(info["state_bytes_per_device"].values())
         assert len(per_dev) == 4

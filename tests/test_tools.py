@@ -115,8 +115,8 @@ def test_check_sharding_guard():
     state bytes, carry the plan as `mx.passes` shard-pass provenance on
     the inspect record + telemetry compile events, tick the
     allgather/reduce_scatter byte counters, and the FusedTrainLoop
-    sharded scanned carry must match the plain loop (see
-    mxtpu/sharding/, docs/sharding.md)."""
+    sharded scanned carry must match the plain loop to float tolerance
+    (see mxtpu/sharding/, docs/sharding.md)."""
     out = _run(["tools/check_sharding.py", "--fused", "--steps", "20"],
                timeout=420)
     assert "check_sharding OK" in out

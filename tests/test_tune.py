@@ -387,3 +387,16 @@ def test_trial_timeout_kills_wedged_bench(tmp_path, monkeypatch):
     assert t.score == float("inf")
     assert "timed out" in (t.error or "")
     assert profiler.get_stat("tune_trial_timeouts") == pre + 1
+
+
+def test_tune_refuses_a_parent_that_holds_the_chip(monkeypatch):
+    """Trials are subprocesses and a chip belongs to one process at a
+    time: a tuning session started from a process that already runs on
+    the accelerator must fail loudly, before the first trial hangs."""
+    import jax
+
+    jax.devices()                       # this process has a backend up
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(MXNetError, match="one process at a time"):
+        tune.tune([sys.executable, "-c", "pass"], name="m", max_trials=1,
+                  store_db=False)

@@ -33,18 +33,12 @@ def main():
     if args.cpu_mesh:
         import os
 
-        xla_flags = os.environ.get("XLA_FLAGS", "")
-        if "xla_force_host_platform_device_count" not in xla_flags:
-            # only effective if the backend is not initialized yet;
-            # jax_num_cpu_devices below (newer JAX) covers the rest
-            os.environ["XLA_FLAGS"] = (
-                xla_flags + " --xla_force_host_platform_device_count=%d"
-                % args.cpu_mesh).strip()
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
-        if hasattr(jax.config, "jax_num_cpu_devices"):
-            jax.config.update("jax_num_cpu_devices", args.cpu_mesh)
+        # jax is not imported yet, so the environment alone decides
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        os.environ["XLA_FLAGS"] = (
+            os.environ.get("XLA_FLAGS", "")
+            + " --xla_force_host_platform_device_count=%d"
+            % args.cpu_mesh).strip()
     import jax
 
     import mxtpu as mx

@@ -228,7 +228,7 @@ def main():
                 print("check_serving: failover replay kept trace %s "
                       "(1 client root span)" % tid)
 
-        # oracle: every output must match the local model bit-for-bit
+        # oracle: every output must match the local model's
         oracle = build_model()
         bad = 0
         for x, out in results:

@@ -21,7 +21,11 @@ on a >=4-device CPU mesh:
      ring-payload magnitude the model predicts.
   5. (``--fused``) the FusedTrainLoop sharded scanned carry: GSPMD
      K-step program with state sharded over the mesh matches the
-     unsharded loop within tol and places ~1/N state bytes per device.
+     unsharded loop and places ~1/N state bytes per device.  This one
+     is to float tolerance (10x ``--tol``), not bitwise: GSPMD's
+     sharded program sums in another order than the unsharded one on
+     XLA:CPU (jax 0.9.0), and Adam's m/sqrt(v) turns a last-bit
+     gradient difference into ~1e-6 of a weight within a few steps.
 
 Usage: python tools/check_sharding.py [--steps N] [--replicas N]
                                       [--tol T] [--fused]
@@ -219,6 +223,7 @@ def check_fused(mx, np, n, tol, failures):
     mesh = parallel.create_mesh({"dp": n}, devices=jax.devices()[:n])
     p_s, info = run(ShardingPlan(mesh=mesh, min_shard_elems=256))
     d = max(float(np.abs(p_r[k] - p_s[k]).max()) for k in p_r)
+    tol = 10 * tol      # float tolerance, see the module docstring
     if d <= tol:
         print("OK: fused sharded-carry params match plain loop "
               "(max |delta| %.3g)" % d)

@@ -61,7 +61,7 @@ def _build_net(args):
 
         net = vision.get_model(args.model, classes=args.classes)
         data_shape = (args.batch, 3, args.image, args.image)
-    ctx = mx.tpu() if mx.num_tpus() else mx.cpu()
+    ctx = mx.current_context()
     net.initialize(ctx=ctx)
     x_trace = mx.nd.zeros(data_shape, ctx=ctx)
     out_sym, _, _ = net._trace_symbol(x_trace)

@@ -315,6 +315,9 @@ def _run_fleet(args, ns, base):
         env["MXTPU_ROLE"] = role
         env.update(extra or {})
         if role in ("scheduler", "server"):
+            # a chip belongs to one process at a time and these roles
+            # only move host bytes: keep them off it, the workers'
+            env["JAX_PLATFORMS"] = "cpu"
             cmd = [sys.executable, "-c",
                    "import mxtpu.kvstore_server as s; s.init_module()"]
         else:

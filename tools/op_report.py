@@ -22,8 +22,8 @@ Acquisition paths (see docs/observability.md §Op profiling):
 
 Usage::
 
-    JAX_PLATFORMS=cpu python tools/op_report.py
-    python tools/op_report.py --trace --image 64 --batch 16
+    python tools/op_report.py --trace --image 64 --batch 16   # the chip
+    JAX_PLATFORMS=cpu python tools/op_report.py            # no chip
     python tools/op_report.py --json            # full OpProfile JSON
 """
 import argparse
@@ -32,7 +32,6 @@ import os
 import sys
 import time
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 # the report needs the program wall: force the perf observatory on and
 # sample every chunk so even a short run measures it
 os.environ.setdefault("MXTPU_PERF", "1")

@@ -1,7 +1,6 @@
 #!/usr/bin/env python
 """Profile the production train loop on the current backend and
-attribute the step time PER OP via `mx.xprof` (VERDICT r4 next #2:
-"close the MFU gap with a profile-driven loop").
+attribute the step time PER OP via `mx.xprof`.
 
 For each configuration (dtype x conv layout x steps-per-program):
 
@@ -14,7 +13,7 @@ For each configuration (dtype x conv layout x steps-per-program):
    joined through the HLO op_name metadata);
 3. prints the top-sink report plus one JSON line per config (the
    ``mxtpu-bench-v1``-style record now carries the ``op_profile``
-   breakdown) for BENCH_NOTES.
+   breakdown).
 
 The old ad-hoc staging/execute stopwatch split is gone: staging shows
 up as the `mx.perf` ``input_wait``/``host_dispatch`` phases and the
@@ -47,7 +46,7 @@ def build_loop(batch, image, dtype, spp):
     from mxtpu.fused_train import FusedTrainLoop
     from mxtpu.gluon.model_zoo import vision
 
-    ctx = mx.tpu() if mx.num_tpus() else mx.cpu()
+    ctx = mx.current_context()
     with mx.amp.scope(dtype if dtype != "float32" else None):
         net = vision.resnet50_v1(classes=1000)
         net.initialize(ctx=ctx)
