@@ -1,0 +1,2 @@
+"""Per-layer metric ``mfu_pct.fused``: see readers.mfu_pct."""
+from readers import mfu_pct as read  # noqa: F401
