@@ -41,6 +41,7 @@ from .ndarray.ndarray import NDArray
 from . import checkpoint as _ckpt
 from . import health as _health
 from . import perf as _perf
+from . import profiler as _prof
 from . import resilience as _res
 from . import xprof as _xprof
 
@@ -447,7 +448,20 @@ class FusedTrainLoop(object):
     def run_stacked(self, data_stack: List[Any]):
         """Run K fused steps over pre-staged (K, ...) slot arrays.
         Returns stacked outputs (list of (K, ...) NDArrays) when
-        collect_outputs, else None."""
+        collect_outputs, else None.
+
+        While `mx.profiler.armed()` the call is one ``mx:step`` span
+        whose children say what the host did (``mx:host_args``,
+        ``mx:host_dispatch``, ``mx:publish``, ``mx:observe.<module>``)
+        and where it waited for the device (``mx:device_wait``): see
+        docs/observability.md."""
+        _prof.inc_stat("fused_programs")
+        _prof.inc_stat("fused_steps", self._K)
+        with _prof.span("mx:step", "loop", step=self._t, k=self._K,
+                        site="fused_train"):
+            return self._run_stacked(data_stack)
+
+    def _run_stacked(self, data_stack: List[Any]):
         import time as _time
 
         import jax
@@ -460,22 +474,26 @@ class FusedTrainLoop(object):
 
         K = self._K
         t_base = self._t
-        base_key = _rnd._next_key() if self._exec._has_rng \
-            else jax.random.PRNGKey(0)
-        tok = _insp_mod.track_compile(
-            self._insp, self._seen_sigs, "fused_train", "fused_train",
-            "train", _cc.sig_of(data_stack),
-            arg_names=[self._arg_names[i] for i in self._data_idx])
-        prog_args = self._program_args(data_stack, base_key)
+        with _prof.span("mx:observe.inspect", "loop"):
+            tok = _insp_mod.track_compile(
+                self._insp, self._seen_sigs, "fused_train", "fused_train",
+                "train", _cc.sig_of(data_stack),
+                arg_names=[self._arg_names[i] for i in self._data_idx])
+        with _prof.span("mx:host_args", "loop"):
+            base_key = _rnd._next_key() if self._exec._has_rng \
+                else jax.random.PRNGKey(0)
+            prog_args = self._program_args(data_stack, base_key)
         t0 = _time.monotonic()
         pt0 = _perf.begin()
-        with _OOM_RUN:
+        with _prof.span("mx:host_dispatch", "loop"), _OOM_RUN:
             p, s, aux, outs = self._jit_program(*prog_args)
         if tok is not None:
-            tok.done(self._jit_program, prog_args)
+            with _prof.span("mx:observe.inspect", "loop"):
+                tok.done(self._jit_program, prog_args)
         # block target = the new params: produced LAST in the scanned
         # program, so call->ready spans the full K-step chunk
-        _perf.end(self._insp.name, "fused_train", pt0, outputs=p, n=K)
+        with _prof.span("mx:observe.perf", "loop"):
+            _perf.end(self._insp.name, "fused_train", pt0, outputs=p, n=K)
         bad_flags = gnorms = lnorms = prev_health = None
         if self._track_health:
             bad_dev, gn_dev = outs["bad"], outs["gnorm"]
@@ -484,8 +502,9 @@ class FusedTrainLoop(object):
             if self._guard is not None:
                 # guard armed: the skip/abort contract needs the flags
                 # NOW (synchronous read — the PR 2 behavior)
-                bad_flags = np.asarray(bad_dev)
-                gnorms = np.asarray(gn_dev)
+                with _prof.span("mx:device_wait", "loop", why="guard"):
+                    bad_flags = np.asarray(bad_dev)
+                    gnorms = np.asarray(gn_dev)
             else:
                 # guard off: defer the host read one chunk — by the
                 # next run these scalars are long since materialized,
@@ -497,31 +516,34 @@ class FusedTrainLoop(object):
                     t_base, base_key,
                     data_stack if _health.want_context() else None,
                     bad_dev, gn_dev)
-        self._p_vals, self._s_tree, self._aux_vals = p, s, aux
-        self._t += K
-        self._optimizer.commit_scan_steps(self._opt_indices, K)
-        if self._shard_plan is not None \
-                and self._collective_bytes_per_step:
-            # the ring-payload estimate of what GSPMD moved for the K
-            # sharded updates (reduce-scatter grads in, allgather
-            # params out) — same counters the eager ZeRO-1 engine ticks
-            from . import profiler as _prof
-
-            _prof.inc_stat("reduce_scatter_bytes",
-                           self._collective_bytes_per_step * K)
-            _prof.inc_stat("allgather_bytes",
-                           self._collective_bytes_per_step * K)
-        self._publish()
-        # one record for the whole K-step program: per-step batch size
-        # is the second dim of the staged (K, batch, ...) stacks
-        batch = int(data_stack[0].shape[1]) \
-            if data_stack and getattr(data_stack[0], "ndim", 0) > 1 else 0
-        skipped_n = int(bad_flags.sum()) if bad_flags is not None else None
-        _tel.record_step(batch_size=batch, n=K,
-                         duration=_time.monotonic() - t0,
-                         site="fused_train", skipped_n=skipped_n,
-                         grad_norm=float(gnorms[-1])
-                         if gnorms is not None else None)
+        with _prof.span("mx:publish", "loop"):
+            self._p_vals, self._s_tree, self._aux_vals = p, s, aux
+            self._t += K
+            self._optimizer.commit_scan_steps(self._opt_indices, K)
+            if self._shard_plan is not None \
+                    and self._collective_bytes_per_step:
+                # the ring-payload estimate of what GSPMD moved for the
+                # K sharded updates (reduce-scatter grads in, allgather
+                # params out) — same counters the eager ZeRO-1 engine
+                # ticks
+                _prof.inc_stat("reduce_scatter_bytes",
+                               self._collective_bytes_per_step * K)
+                _prof.inc_stat("allgather_bytes",
+                               self._collective_bytes_per_step * K)
+            self._publish()
+        with _prof.span("mx:observe.telemetry", "loop"):
+            # one record for the whole K-step program: per-step batch
+            # size is the second dim of the staged (K, batch, ...) stacks
+            batch = int(data_stack[0].shape[1]) \
+                if data_stack and getattr(data_stack[0], "ndim", 0) > 1 \
+                else 0
+            skipped_n = int(bad_flags.sum()) if bad_flags is not None \
+                else None
+            _tel.record_step(batch_size=batch, n=K,
+                             duration=_time.monotonic() - t0,
+                             site="fused_train", skipped_n=skipped_n,
+                             grad_norm=float(gnorms[-1])
+                             if gnorms is not None else None)
         if self._stats_on and lnorms is not None:
             self._maybe_emit_stats(lnorms)
         if bad_flags is not None:
@@ -529,26 +551,29 @@ class FusedTrainLoop(object):
             # buffers in-program); blame the FIRST bad step, then
             # account per-step health and abort on too many
             # CONSECUTIVE skips
-            if bad_flags.any():
-                k = int(np.argmax(bad_flags))
-                _health.on_nonfinite(
-                    "fused_train", gnorm=float(gnorms[k]),
-                    ctx=self._diag_ctx(data_stack, base_key, t_base, k))
-            for gn, bad in zip(gnorms, bad_flags):
-                if not bad:
-                    _health.observe_grad_norm(float(gn))
-            for bad in bad_flags:
-                self._guard.record(not bool(bad))
+            with _prof.span("mx:observe.health", "loop"):
+                if bad_flags.any():
+                    k = int(np.argmax(bad_flags))
+                    _health.on_nonfinite(
+                        "fused_train", gnorm=float(gnorms[k]),
+                        ctx=self._diag_ctx(data_stack, base_key, t_base, k))
+                for gn, bad in zip(gnorms, bad_flags):
+                    if not bad:
+                        _health.observe_grad_norm(float(gn))
+                for bad in bad_flags:
+                    self._guard.record(not bool(bad))
         elif prev_health is not None:
             self._check_pending(prev_health)
         # mx.checkpoint boundary: the end of a K-step chunk is the only
         # point where host copies of params/opt-state are coherent, so
         # periodic snapshots and SIGTERM flushes both anchor here
-        if _ckpt.active():
-            _ckpt.on_boundary(self._t)
+        with _prof.span("mx:observe.checkpoint", "loop"):
+            if _ckpt.active():
+                _ckpt.on_boundary(self._t)
         # mx.xprof auto-profile cadence (MXTPU_XPROF_EVERY, default
         # off): when disarmed this is two int/bool checks per chunk
-        _xprof.maybe_autoprofile(self, data_stack)
+        with _prof.span("mx:observe.xprof", "loop"):
+            _xprof.maybe_autoprofile(self, data_stack)
         if self._collect:
             ctx = self._exec._ctx
             return [NDArray(o, ctx=ctx, _committed=True) for o in outs]
@@ -580,17 +605,19 @@ class FusedTrainLoop(object):
         """Read the PREVIOUS chunk's deferred health scalars (ready by
         now — their program finished before this chunk dispatched)."""
         t_base, base_key, stack, bad_dev, gn_dev = pending
-        bad = np.asarray(bad_dev)
-        gn = np.asarray(gn_dev)
-        if bad.any():
-            k = int(np.argmax(bad))
-            ctx = self._diag_ctx(stack, base_key, t_base, k) \
-                if stack is not None else None
-            _health.on_nonfinite("fused_train", gnorm=float(gn[k]),
-                                 ctx=ctx)
-        else:
-            for v in gn:
-                _health.observe_grad_norm(float(v))
+        with _prof.span("mx:device_wait", "loop", why="health"):
+            bad = np.asarray(bad_dev)
+            gn = np.asarray(gn_dev)
+        with _prof.span("mx:observe.health", "loop"):
+            if bad.any():
+                k = int(np.argmax(bad))
+                ctx = self._diag_ctx(stack, base_key, t_base, k) \
+                    if stack is not None else None
+                _health.on_nonfinite("fused_train", gnorm=float(gn[k]),
+                                     ctx=ctx)
+            else:
+                for v in gn:
+                    _health.observe_grad_norm(float(v))
 
     def _maybe_emit_stats(self, lnorms) -> None:
         """Opt-in per-layer stat streaming on the
@@ -598,6 +625,8 @@ class FusedTrainLoop(object):
         run is K wall steps): grad norms come from the scanned program
         (last step of the chunk), param norms from one fused reduction
         over the published params."""
+        import jax
+
         n = _health.stats_every()
         if n <= 0:
             return
@@ -606,15 +635,18 @@ class FusedTrainLoop(object):
             return
         names = [self._arg_names[i] for i in self._diff_idx]
         pn = _health.layer_norms(self._p_vals)
-        try:
-            opt = self._optimizer
-            lr = opt.lr if opt.lr_scheduler is None \
-                else opt.lr_scheduler(opt.num_update)
-            scale = abs(float(lr) * float(opt.rescale_grad))
-        except Exception:
-            scale = 1.0
-        _health.emit_stats(names, pn, [l[-1] for l in lnorms],
-                           scale=scale, site="fused_train")
+        with _prof.span("mx:device_wait", "loop", why="stats"):
+            pn, gn = jax.device_get((pn, [l[-1] for l in lnorms]))
+        with _prof.span("mx:observe.health", "loop"):
+            try:
+                opt = self._optimizer
+                lr = opt.lr if opt.lr_scheduler is None \
+                    else opt.lr_scheduler(opt.num_update)
+                scale = abs(float(lr) * float(opt.rescale_grad))
+            except Exception:
+                scale = 1.0
+            _health.emit_stats(names, pn, gn, scale=scale,
+                               site="fused_train")
 
     def run(self, batches: Sequence[Any]):
         """Stage K DataBatches and run them as one program."""

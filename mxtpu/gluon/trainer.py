@@ -16,6 +16,7 @@ from .. import checkpoint as _ckpt
 from .. import health as _health
 from .. import optimizer as opt_mod
 from .. import perf as _perf
+from .. import profiler as _prof
 from .. import resilience as _res
 from .. import telemetry as _tel
 from .. import tracing as _tracing
@@ -223,7 +224,9 @@ class Trainer(object):
                 scale=abs(self.learning_rate
                           * self._optimizer.rescale_grad))
             pt0 = _perf.begin()
-            self._update(ignore_stale_grad)
+            with _prof.span("mx:optimizer", "loop", step=self._num_steps,
+                            site="trainer"):
+                self._update(ignore_stale_grad)
             _perf.note_phase_since("optimizer", pt0)
         finally:
             if trc is not None:
@@ -272,12 +275,14 @@ class Trainer(object):
     def _allreduce_grads(self):
         if self._kvstore is None:
             return
-        for i, param in enumerate(self._params):
-            if param.grad_req != "null":
-                self._kvstore.push(i, param.list_grad(), priority=-i)
-                if not self._update_on_kvstore:
-                    self._kvstore.pull(i, param.list_grad(), priority=-i,
-                                       ignore_sparse=False)
+        with _prof.span("mx:collective", "loop", step=self._num_steps,
+                        site="trainer"):
+            for i, param in enumerate(self._params):
+                if param.grad_req != "null":
+                    self._kvstore.push(i, param.list_grad(), priority=-i)
+                    if not self._update_on_kvstore:
+                        self._kvstore.pull(i, param.list_grad(),
+                                           priority=-i, ignore_sparse=False)
 
     def update(self, batch_size, ignore_stale_grad=False):
         if not self._kv_initialized:

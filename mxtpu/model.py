@@ -16,6 +16,7 @@ from .context import Context, cpu, current_context
 from .ndarray import ndarray as nd_mod
 from .ndarray.ndarray import NDArray
 from . import kvstore as kvs
+from . import profiler as _prof
 from . import resilience as _res
 from . import symbol as sym_mod
 
@@ -66,13 +67,14 @@ def _update_params_on_kvstore(param_arrays, grad_arrays, kvstore,
                               param_names):
     """Push grads / pull weights (reference `model.py:145`); priority
     -index so earlier-needed keys schedule first."""
-    for index, pair in enumerate(zip(param_arrays, grad_arrays)):
-        arg_list, grad_list = pair
-        if grad_list[0] is None:
-            continue
-        name = param_names[index]
-        kvstore.push(name, grad_list, priority=-index)
-        kvstore.pull(name, arg_list, priority=-index)
+    with _prof.span("mx:collective", "loop", site="module"):
+        for index, pair in enumerate(zip(param_arrays, grad_arrays)):
+            arg_list, grad_list = pair
+            if grad_list[0] is None:
+                continue
+            name = param_names[index]
+            kvstore.push(name, grad_list, priority=-index)
+            kvstore.pull(name, arg_list, priority=-index)
 
 
 def _update_params(param_arrays, grad_arrays, updater, num_device,
@@ -88,29 +90,28 @@ def _update_params(param_arrays, grad_arrays, updater, num_device,
     state redundancy (`docs/sharding.md`)."""
     from .sharding.zero1 import ZeRO1Updater
 
+    if kvstore:
+        # every gradient's push + pull, as one span of the step
+        with _prof.span("mx:collective", "loop", site="module"):
+            for i, grad_list in enumerate(grad_arrays):
+                if grad_list[0] is None:
+                    continue
+                kvstore.push(param_names[i], grad_list, priority=-i)
+                kvstore.pull(param_names[i], grad_list, priority=-i)
     if isinstance(updater, ZeRO1Updater):
         triples = []
         for i, (arg_list, grad_list) in enumerate(zip(param_arrays,
                                                       grad_arrays)):
             if grad_list[0] is None:
                 continue
-            if kvstore:
-                name = param_names[i]
-                kvstore.push(name, grad_list, priority=-i)
-                kvstore.pull(name, grad_list, priority=-i)
             triples.append((i, grad_list, arg_list))
         updater.update_replicas(triples, pre_reduced=kvstore is not None)
         return
     updates: List[List[Tuple]] = [[] for _ in range(num_device)]
-    for i, pair in enumerate(zip(param_arrays, grad_arrays)):
+    for index, pair in enumerate(zip(param_arrays, grad_arrays)):
         arg_list, grad_list = pair
         if grad_list[0] is None:
             continue
-        index = i
-        if kvstore:
-            name = param_names[index]
-            kvstore.push(name, grad_list, priority=-index)
-            kvstore.pull(name, grad_list, priority=-index)
         for k, (w, g) in enumerate(zip(arg_list, grad_list)):
             updates[k].append((index * num_device + k, g, w))
     for dev_updates in updates:

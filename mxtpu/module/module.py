@@ -22,6 +22,7 @@ from ..model import (_create_kvstore, _initialize_kvstore, _update_params,
                      save_checkpoint)
 from .. import health as _health
 from .. import perf as _perf
+from .. import profiler as _prof
 from .. import resilience as _res
 from ..ndarray.ndarray import NDArray, zeros
 from .. import optimizer as opt_mod
@@ -398,7 +399,18 @@ class Module(BaseModule):
         self.optimizer_initialized = True
 
     # -- execution ----------------------------------------------------------
+    def _step_num(self):
+        """The update count: the ``step`` of this iteration's
+        ``mx:forward`` / ``mx:backward`` / ``mx:optimizer`` spans."""
+        return self._optimizer.num_update if self.optimizer_initialized \
+            else None
+
     def forward(self, data_batch, is_train=None):
+        with _prof.span("mx:forward", "loop", step=self._step_num(),
+                        site="module"):
+            self._forward(data_batch, is_train)
+
+    def _forward(self, data_batch, is_train):
         if not (self.binded and self.params_initialized):
             raise MXNetError("bind() and init_params() first")
         # re-bind on shape change (bucketing / last partial batch)
@@ -479,7 +491,9 @@ class Module(BaseModule):
     def backward(self, out_grads=None):
         if not (self.binded and self.params_initialized):
             raise MXNetError("bind() and init_params() first")
-        self._exec_group.backward(out_grads=out_grads)
+        with _prof.span("mx:backward", "loop", step=self._step_num(),
+                        site="module"):
+            self._exec_group.backward(out_grads=out_grads)
 
     def update(self):
         """Apply optimizer using accumulated gradients (reference
@@ -487,6 +501,11 @@ class Module(BaseModule):
         if not (self.binded and self.params_initialized and
                 self.optimizer_initialized):
             raise MXNetError("init_optimizer() first")
+        with _prof.span("mx:optimizer", "loop", step=self._step_num(),
+                        site="module"):
+            self._update()
+
+    def _update(self):
         from .. import telemetry as _tel
 
         # deferred no-stall grad health on the Executor path; detection

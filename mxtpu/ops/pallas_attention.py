@@ -265,6 +265,7 @@ def _flash_forward_pallas(q, k, v, sm_scale, causal, block_q, block_k,
             pltpu.VMEM((block_q, 128), jnp.float32),   # running sum
         ],
         interpret=_interpret(),
+        name="mx_flash_fwd",
     )(q, k, v)
     if want_lse:
         return outs[0], outs[1][:, :, 0]
@@ -396,6 +397,7 @@ def _flash_backward_pallas(q, k, v, g, out, lse, sm_scale, causal,
         out_specs=qspec,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=_interpret(),
+        name="mx_flash_dq",
     )(q, k, v, g, lse3, delta3)
 
     # dkv grid: (bh, nk, nq) — q innermost; index maps swap (i, j)
@@ -415,6 +417,7 @@ def _flash_backward_pallas(q, k, v, g, out, lse, sm_scale, causal,
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
         interpret=_interpret(),
+        name="mx_flash_dkv",
     )(q, k, v, g, lse3, delta3)
     return dq, dk, dv
 

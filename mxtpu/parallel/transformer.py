@@ -377,17 +377,22 @@ def _stage_fn(cfg, params_stage, x, tp_size, ep_size):
 
     x = _pvary_all(x)
 
+    # the named scopes (here, and `embed` / `loss` / `adam` below) are
+    # metadata on the device program's instructions: a trace's device
+    # time can be summed by them (PERF.md)
     def layer(x, lw):
-        h = x + _attention(cfg, _rms_norm(x, lw["ln1"]),
-                           lw["wq"], lw["wk"], lw["wv"], lw["wo"],
-                           tp_size)
-        z = _rms_norm(h, lw["ln2"])
-        if cfg.n_experts:
-            f = _moe_ffn(cfg, z, lw["router"], lw["we1"], lw["we2"],
-                         ep_size)
-        else:
-            f = _dense_ffn(z, lw["w1"], lw["w2"])
-        return h + f, None
+        with jax.named_scope("attn"):
+            h = x + _attention(cfg, _rms_norm(x, lw["ln1"]),
+                               lw["wq"], lw["wk"], lw["wv"], lw["wo"],
+                               tp_size)
+        with jax.named_scope("ffn"):
+            z = _rms_norm(h, lw["ln2"])
+            if cfg.n_experts:
+                f = _moe_ffn(cfg, z, lw["router"], lw["we1"], lw["we2"],
+                             ep_size)
+            else:
+                f = _dense_ffn(z, lw["w1"], lw["w2"])
+            return h + f, None
 
     if cfg.remat != "none":
         from ..executor import apply_remat
@@ -451,18 +456,19 @@ def _build_loss_fn(cfg: TransformerConfig, mesh, n_micro: int):
         E = cfg.d_model
 
         # vocab-sharded embedding lookup: local rows + psum over tp
-        local_tok = tokens - tp_idx * V_loc
-        in_shard = (local_tok >= 0) & (local_tok < V_loc)
-        emb = jnp.where(
-            in_shard[..., None],
-            params["embed"][jnp.clip(local_tok, 0, V_loc - 1)], 0.0)
-        # exactly one tp shard contributes a non-zero row per token
-        # (vocab-sharded one-hot), so a native-dtype psum is exact
-        # and halves the ICI bytes vs upcasting to f32 first
-        emb = jax.lax.psum(emb, AXIS_TP)
-        pos_global = sp_idx * T + jnp.arange(T)
-        x = (emb + params["pos"][pos_global][None]).astype(
-            jnp.dtype(cfg.dtype))                         # [B, T, E]
+        with jax.named_scope("embed"):
+            local_tok = tokens - tp_idx * V_loc
+            in_shard = (local_tok >= 0) & (local_tok < V_loc)
+            emb = jnp.where(
+                in_shard[..., None],
+                params["embed"][jnp.clip(local_tok, 0, V_loc - 1)], 0.0)
+            # exactly one tp shard contributes a non-zero row per token
+            # (vocab-sharded one-hot), so a native-dtype psum is exact
+            # and halves the ICI bytes vs upcasting to f32 first
+            emb = jax.lax.psum(emb, AXIS_TP)
+            pos_global = sp_idx * T + jnp.arange(T)
+            x = (emb + params["pos"][pos_global][None]).astype(
+                jnp.dtype(cfg.dtype))                     # [B, T, E]
         x_mb = x.reshape(n_micro, mb, T, E)
 
         # my stage's layer stack: params["wq"][pp_idx] etc (leading pp
@@ -502,18 +508,20 @@ def _build_loss_fn(cfg: TransformerConfig, mesh, n_micro: int):
 
         # only the last stage's h is the real model output; psum the
         # masked loss over pp so every rank agrees (others contribute 0)
-        h = _rms_norm(h, params["ln_f"])
-        logits = h @ params["unembed"]                    # [B, T, V/tp]
-        nll = _sharded_xent(logits.reshape(B * T, V_loc),
-                            labels.reshape(B * T), V_loc)
-        local_loss = nll.mean() * jnp.where(is_last, 1.0, 0.0)
-        # mean over dp × sp shards; sum over pp picks the last stage;
-        # ep ranks hold identical copies, so psum/ep is exact (and makes
-        # the per-path gradient normalization come out right for both
-        # ep-sharded expert weights and replicated params)
-        loss = jax.lax.psum(local_loss,
-                            (AXIS_PP, AXIS_DP, AXIS_SP, AXIS_EP)) \
-            / (mesh.shape[AXIS_DP] * sp * ep)
+        with jax.named_scope("loss"):
+            h = _rms_norm(h, params["ln_f"])
+            logits = h @ params["unembed"]                # [B, T, V/tp]
+            nll = _sharded_xent(logits.reshape(B * T, V_loc),
+                                labels.reshape(B * T), V_loc)
+            local_loss = nll.mean() * jnp.where(is_last, 1.0, 0.0)
+            # mean over dp × sp shards; sum over pp picks the last
+            # stage; ep ranks hold identical copies, so psum/ep is exact
+            # (and makes the per-path gradient normalization come out
+            # right for both ep-sharded expert weights and replicated
+            # params)
+            loss = jax.lax.psum(local_loss,
+                                (AXIS_PP, AXIS_DP, AXIS_SP, AXIS_EP)) \
+                / (mesh.shape[AXIS_DP] * sp * ep)
         return loss
 
     return loss_fn
@@ -585,6 +593,10 @@ def _build_adam_zero1_step(cfg: TransformerConfig, mesh, n_micro: int,
 
     def device_step(params, opt_state, tokens, labels):
         loss, grads = jax.value_and_grad(loss_fn)(params, tokens, labels)
+        with jax.named_scope("adam"):
+            return _adam(params, opt_state, grads, loss)
+
+    def _adam(params, opt_state, grads, loss):
         dp_idx = lax.axis_index(AXIS_DP)
         t = opt_state["t"] + 1.0
         bc1 = 1.0 - b1 ** t

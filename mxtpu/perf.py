@@ -249,7 +249,9 @@ def end(name: str, site: str, t0: Optional[float], outputs: Any = None,
     try:
         import jax
 
-        jax.block_until_ready(outputs)
+        # bracketed as the loop's device wait (mx.profiler, while armed)
+        with _prof.span("mx:device_wait", "loop", why="perf_sync"):
+            jax.block_until_ready(outputs)
     except Exception:
         return
     t2 = time.perf_counter()

@@ -13,8 +13,17 @@ accurate per-op device times (the analog of the reference profiling
 `NaiveEngine` mode).  The flag is read PER SPAN, so it can be flipped
 mid-run; a span whose producer attached the op's results (``span.result``)
 blocks on exactly those via ``jax.block_until_ready`` instead of the
-global ``jax.effects_barrier``.  For kernel-level device timing use
-jax.profiler (XPlane) alongside — `start_xplane`/`stop_xplane` wrap it.
+global ``jax.effects_barrier``.
+
+One span primitive: `span()` records while `armed()`, i.e. while
+``set_state('run')`` is on OR a JAX profiler session is live
+(``jax.profiler.start_trace``).  An armed span is one row of a bounded
+in-memory buffer (`spans()`: raw ``time.perf_counter()`` seconds, the
+thread, the enclosing span, the step) and, inside a JAX session, a
+``TraceAnnotation`` / ``StepTraceAnnotation`` of the same name in the
+session's ``.xplane.pb``, on the clock of the device's events.  The
+loop-level sites (the ``mx:`` vocabulary, `docs/observability.md`) are
+gated by `armed()` alone; the per-op sites keep `is_recording`.
 
 Trace identity: every event is stamped with the REAL pid, `dump()`
 emits chrome ``process_name``/``thread_name`` metadata rows (role+rank
@@ -27,17 +36,20 @@ MXNET_PROFILER_AUTOSTART, `docs/faq/env_var.md:156`).
 """
 from __future__ import annotations
 
+import collections
 import json
 import os
 import threading
 import time
 from typing import Any, Dict, List, Optional
 
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
 from .base import MXNetError, getpid_cached
 
 __all__ = ["set_config", "set_state", "state", "pause", "resume", "dump",
            "dumps", "Domain", "Task", "Frame", "Counter", "Marker",
-           "start_xplane", "stop_xplane",
+           "armed", "span", "spans",
            "inc_stat", "get_stat", "set_stat", "max_stat", "stats",
            "reset_stats"]
 
@@ -56,8 +68,15 @@ _CONFIG = {
     "aggregate_stats": False,
     "continuous_dump": False,
 }
-_EVENTS: List[Dict[str, Any]] = []
+_EVENTS: List[Dict[str, Any]] = []     # counter and marker events
 _AGG: Dict[str, List[float]] = {}
+# THE span store: one row per finished span, oldest dropped when full
+# (drops counted in the `profiler_span_drops` stat); `dump()` writes its
+# chrome-trace "X" events from these rows
+_MAX_SPANS = 1 << 16
+_SPANS: "collections.deque[Dict[str, Any]]" = collections.deque(
+    maxlen=_MAX_SPANS)
+_tls = threading.local()               # .stack: the thread's open spans
 # the two origins are captured back-to-back: _START_TS anchors the
 # relative event timestamps, _START_EPOCH records what wall-clock
 # instant that zero corresponds to (the mergeable-trace contract)
@@ -122,16 +141,42 @@ def is_recording(kind: str = "imperative") -> bool:
         _CONFIG.get("profile_" + kind, True)
 
 
-def record_span(name: str, cat: str, ts_us: float, dur_us: float,
-                tid: int = 0, args: Optional[Dict] = None):
-    if not _RUNNING or _PAUSED:
-        return
+def armed() -> bool:
+    """True while a span would be kept: ``set_state('run')`` is on (and
+    not paused) or a JAX profiler session is live.  The one check an
+    un-armed loop-level site pays."""
+    return (_RUNNING and not _PAUSED) or TraceAnnotation.is_enabled()
+
+
+def record_span(name: str, cat: str, t0: float, t1: float,
+                parent: Optional[str] = None, step: Optional[int] = None,
+                args: Optional[Dict] = None):
+    """Append one finished span (``t0`` / ``t1`` raw perf_counter
+    seconds) to the span store."""
+    row = {"name": name, "cat": cat, "t0": t0, "t1": t1,
+           "tid": threading.get_ident(), "parent": parent, "step": step}
+    if args:
+        row["args"] = args
     with _lock:
-        _EVENTS.append({"name": name, "cat": cat, "ph": "X",
-                        "ts": ts_us, "dur": dur_us, "pid": getpid_cached(),
-                        "tid": tid,
-                        **({"args": args} if args else {})})
-        _AGG.setdefault(name, []).append(dur_us)
+        dropped = len(_SPANS) == _SPANS.maxlen
+        _SPANS.append(row)
+        _AGG.setdefault(name, []).append((t1 - t0) * 1e6)
+    if dropped:
+        inc_stat("profiler_span_drops")
+
+
+def spans(reset: bool = False) -> List[Dict[str, Any]]:
+    """The recorded span rows, oldest first: ``name``, ``cat``, ``t0``
+    and ``t1`` (raw ``time.perf_counter()`` seconds), ``tid``,
+    ``parent`` (the name of the span open on that thread when this one
+    started, or None), ``step`` (its own or the enclosing span's) and
+    ``args`` where it was given attributes.  ``reset`` empties the
+    store."""
+    with _lock:
+        rows = list(_SPANS)
+        if reset:
+            _SPANS.clear()
+    return rows
 
 
 # -- always-on stats -------------------------------------------------------
@@ -198,21 +243,48 @@ class _Span(object):
     ``span.result = <jax arrays>``; under MXTPU_PROFILER_SYNC the exit
     then blocks on exactly those (``jax.block_until_ready``) for a
     true synchronous device timing, falling back to the global
-    ``jax.effects_barrier`` when nothing was attached."""
+    ``jax.effects_barrier`` when nothing was attached.  Loop-level
+    spans (``cat='loop'``) never add a block of their own: their
+    ``mx:device_wait`` children say where the host waits."""
 
-    __slots__ = ("name", "cat", "t0", "result")
+    __slots__ = ("name", "cat", "step", "args", "t0", "result", "_parent",
+                 "_ann", "_own_step")
 
-    def __init__(self, name: str, cat: str):
+    def __init__(self, name: str, cat: str, step: Optional[int],
+                 args: Dict[str, Any]):
         self.name = name
         self.cat = cat
+        self.step = step
+        self._own_step = step is not None
+        self.args = args
         self.result = None
 
     def __enter__(self):
-        self.t0 = _now_us()
+        stack = getattr(_tls, "stack", None)
+        if stack is None:
+            stack = _tls.stack = []
+        if stack:
+            self._parent = stack[-1].name
+            if self.step is None:
+                self.step = stack[-1].step
+        else:
+            self._parent = None
+        stack.append(self)
+        self._ann = None
+        if TraceAnnotation.is_enabled():
+            # the same span in the JAX session's .xplane.pb, on the
+            # clock of the device's events
+            if self._own_step:
+                self._ann = StepTraceAnnotation(
+                    self.name, step_num=self.step, **self.args)
+            else:
+                self._ann = TraceAnnotation(self.name, **self.args)
+            self._ann.__enter__()
+        self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        if _sync_enabled():
+        if self.cat != "loop" and _sync_enabled():
             try:
                 import jax
 
@@ -222,11 +294,32 @@ class _Span(object):
                     jax.effects_barrier()
             except Exception:
                 pass
-        record_span(self.name, self.cat, self.t0, _now_us() - self.t0,
-                    tid=threading.get_ident() % 1000)
+        t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        _tls.stack.pop()
+        record_span(self.name, self.cat, self.t0, t1, self._parent,
+                    self.step, self.args)
         if _CONFIG["profile_memory"]:
             _sample_memory()
         return False
+
+
+class _NullSpan(object):
+    """What an un-armed `span()` returns: records nothing, enters no
+    annotation, takes (and drops) ``result``."""
+
+    __slots__ = ()
+    result = property(lambda self: None, lambda self, value: None)
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+_NULL_SPAN = _NullSpan()
 
 
 _mem_counter = [0]
@@ -245,8 +338,14 @@ def _sample_memory():
         pass
 
 
-def span(name: str, cat: str = "operator") -> _Span:
-    return _Span(name, cat)
+def span(name: str, cat: str = "operator", step: Optional[int] = None,
+         **args):
+    """A context manager around one span; while not `armed()` the shared
+    no-op.  ``step`` makes it (and, by inheritance, the spans inside it)
+    a step's span, entered as a ``StepTraceAnnotation`` with
+    ``step_num``.  Other keywords are the span's attributes (the
+    annotation's stats, the row's ``args``)."""
+    return _Span(name, cat, step, args) if armed() else _NULL_SPAN
 
 
 # -- user-facing objects (reference profiler.py Domain/Task/Frame/...) ----
@@ -262,13 +361,14 @@ class _Timed(object):
         self._t0 = None
 
     def start(self):
-        self._t0 = _now_us()
+        self._t0 = time.perf_counter()
 
     def stop(self):
         if self._t0 is None:
             raise MXNetError("stop() before start()")
-        record_span(self.name, type(self).__name__.lower(), self._t0,
-                    _now_us() - self._t0)
+        if armed():
+            record_span(self.name, type(self).__name__.lower(), self._t0,
+                        time.perf_counter())
         self._t0 = None
 
 
@@ -339,19 +439,28 @@ def dump(finished: bool = True, profile_process: str = "worker"):
     # the dumper, almost always the dispatch thread — as such)
     main_tid = threading.get_ident() % 1000
     with _lock:
-        seen_tids = {e.get("tid", 0) for e in _EVENTS}
+        span_events = [
+            {"name": r["name"], "cat": r["cat"], "ph": "X",
+             "ts": (r["t0"] - _START_TS) * 1e6,
+             "dur": (r["t1"] - r["t0"]) * 1e6, "pid": pid,
+             "tid": r["tid"] % 1000,
+             **({"args": r["args"]} if "args" in r else {})}
+            for r in _SPANS]
+        seen_tids = {e.get("tid", 0) for e in span_events}
+        seen_tids.update(e.get("tid", 0) for e in _EVENTS)
         for tid in sorted(seen_tids):
             meta.append({"name": "thread_name", "ph": "M", "pid": pid,
                          "tid": tid,
                          "args": {"name": "dispatch" if tid == main_tid
                                   else "thread-%d" % tid}})
-        payload = {"traceEvents": meta + list(_EVENTS),
+        payload = {"traceEvents": meta + span_events + list(_EVENTS),
                    "displayTimeUnit": "ms",
                    "otherData": {"epoch_origin_s": _START_EPOCH,
                                  "role": ident["role"],
                                  "rank": ident["rank"], "pid": pid}}
         if finished:
             _EVENTS.clear()
+            _SPANS.clear()
     with open(_CONFIG["filename"], "w") as f:
         json.dump(payload, f)
 
@@ -375,20 +484,6 @@ def dumps(reset: bool = False, format: str = "table") -> str:
     for r in rows:
         lines.append("%-48s %8d %12.1f %12.1f %12.1f %12.1f" % r)
     return "\n".join(lines)
-
-
-# -- XPlane bridge (device-level traces via jax.profiler) ------------------
-
-def start_xplane(logdir: str = "/tmp/mxtpu_xplane"):
-    import jax
-
-    jax.profiler.start_trace(logdir)
-
-
-def stop_xplane():
-    import jax
-
-    jax.profiler.stop_trace()
 
 
 if os.environ.get("MXTPU_PROFILER_AUTOSTART",
