@@ -434,6 +434,22 @@ def _sharded_xent(logits_loc, labels, vocab_shard_size):
 
 
 def _build_loss_fn(cfg: TransformerConfig, mesh, n_micro: int):
+    """The per-device loss (inside shard_map): embed, this rank's stage
+    of layers under the pipeline schedule, final norm, unembed, sharded
+    cross-entropy.
+
+    The schedule is GPipe's: n_micro + pp - 1 steps of a `fori_loop`,
+    each running the stage on one microbatch and handing its output to
+    the next rank by `ppermute`.  The loop exists only where it has more
+    than one step; one stage with one microbatch (every single-chip
+    run, any dp / tp / sp / ep mesh with pp = 1 and n_micro = 1) calls
+    the stage once.  A one-trip loop is still differentiated as a scan,
+    whose partial evaluation hoists what depends on loop constants only
+    and so splits the layer scan into a second n_layers-trip loop that
+    re-stacks the layer weights before every forward pass: gpt2-medium
+    at B=8, T=1024 on a v5e spent 43.9 ms of its 277 ms step there
+    (PERF.md, PR 27).  With more than one step that hoisted loop is
+    still there (PERF.md, open questions)."""
     import jax
     import jax.numpy as jnp
 
@@ -452,8 +468,6 @@ def _build_loss_fn(cfg: TransformerConfig, mesh, n_micro: int):
         B, T = tokens.shape
         if B % n_micro:
             raise MXNetError("local batch %d %% n_micro %d" % (B, n_micro))
-        mb = B // n_micro
-        E = cfg.d_model
 
         # vocab-sharded embedding lookup: local rows + psum over tp
         with jax.named_scope("embed"):
@@ -469,7 +483,6 @@ def _build_loss_fn(cfg: TransformerConfig, mesh, n_micro: int):
             pos_global = sp_idx * T + jnp.arange(T)
             x = (emb + params["pos"][pos_global][None]).astype(
                 jnp.dtype(cfg.dtype))                     # [B, T, E]
-        x_mb = x.reshape(n_micro, mb, T, E)
 
         # my stage's layer stack: params["wq"][pp_idx] etc (leading pp
         # axis is sharded, so inside shard_map it has extent 1)
@@ -479,32 +492,39 @@ def _build_loss_fn(cfg: TransformerConfig, mesh, n_micro: int):
             if name in params:
                 stage_params[name] = params[name][0]      # [lps, ...]
 
-        perm_fwd = [(i, (i + 1) % pp) for i in range(pp)]
-        is_first = (pp_idx == 0)
         is_last = (pp_idx == pp - 1)
 
         def run_stage(state):
             return _stage_fn(cfg, stage_params, state, tp, ep)
 
         n_steps = n_micro + pp - 1
-        out_buf = _pvary_all(jnp.zeros((n_micro, mb, T, E), x.dtype))
+        if n_steps == 1:
+            # one stage, one microbatch: one call, and never a loop of
+            # one trip (the docstring above says what that costs)
+            h = run_stage(x)
+        else:
+            mb, E = B // n_micro, cfg.d_model
+            x_mb = x.reshape(n_micro, mb, T, E)
+            perm_fwd = [(i, (i + 1) % pp) for i in range(pp)]
+            is_first = (pp_idx == 0)
+            out_buf = _pvary_all(jnp.zeros((n_micro, mb, T, E), x.dtype))
 
-        def step(s, carry):
-            state, out_buf = carry
-            feed = x_mb[jnp.clip(s, 0, n_micro - 1)]
-            inp = jnp.where(is_first, feed, state)
-            out = run_stage(inp)
-            slot = jnp.clip(s - (pp - 1), 0, n_micro - 1)
-            out_buf = out_buf.at[slot].set(
-                jnp.where(is_last, out, out_buf[slot]))
-            state = jax.lax.ppermute(out, AXIS_PP, perm_fwd) \
-                if pp > 1 else out
-            return state, out_buf
+            def step(s, carry):
+                state, out_buf = carry
+                feed = x_mb[jnp.clip(s, 0, n_micro - 1)]
+                inp = jnp.where(is_first, feed, state)
+                out = run_stage(inp)
+                slot = jnp.clip(s - (pp - 1), 0, n_micro - 1)
+                out_buf = out_buf.at[slot].set(
+                    jnp.where(is_last, out, out_buf[slot]))
+                state = jax.lax.ppermute(out, AXIS_PP, perm_fwd) \
+                    if pp > 1 else out
+                return state, out_buf
 
-        state0 = _pvary_all(jnp.zeros((mb, T, E), x.dtype))
-        _, out_buf = jax.lax.fori_loop(0, n_steps, step,
-                                       (state0, out_buf))
-        h = out_buf.reshape(B, T, E)
+            state0 = _pvary_all(jnp.zeros((mb, T, E), x.dtype))
+            _, out_buf = jax.lax.fori_loop(0, n_steps, step,
+                                           (state0, out_buf))
+            h = out_buf.reshape(B, T, E)
 
         # only the last stage's h is the real model output; psum the
         # masked loss over pp so every rank agrees (others contribute 0)

@@ -1,0 +1,161 @@
+"""Compiles for a DESCRIBED chip (TPU v5e, not attached), at real widths,
+at no chip time: the one file for such tests (ROADMAP C11; the
+`on-chip-measurement` guide, section 2).
+
+The topology is described inside a fixture, never while a module is
+imported: only one process may load the TPU's library, and every xdist
+worker imports every test file.  Nothing here runs a program, so nothing
+here is a device metric; what a compile can show is what the compiler
+built: which loops, which copies, whether the Mosaic kernels are in.
+"""
+import os
+import re
+
+import pytest
+
+# gpt2-medium as `gpt2m_fused_k8` runs it (benchmark/onchip/configs/
+# gpt2_medium.json, traffic/fused_k8_tokens.json)
+WIDTHS = dict(vocab=50257, d_model=1024, n_heads=16, n_layers=24,
+              d_ff=4096, max_len=1024, dtype="bfloat16")
+BATCH, SEQ, K_STEPS = 8, 1024, 8
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # The TPU compiler takes every core it sees (4-5 of 8 for ten
+    # seconds a program), and the suite's timing guards run beside this
+    # file on other workers: `test_tools.py::test_check_perf_guard`
+    # fails beside a compile left free.  Threads inherit the affinity
+    # of the thread that starts them, so load the library, and with it
+    # its pools, from a thread held to two cores.
+    cpus = os.sched_getaffinity(0)
+    os.sched_setaffinity(0, sorted(cpus)[-2:])
+    try:
+        try:
+            desc = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # no libtpu here, or another holds it
+            pytest.skip("no v5e:2x2 topology can be described here: %s"
+                        % e)
+        # a compile for a described chip is written to the persistent
+        # cache but cannot be read back without the chip: keep these
+        # out of it
+        was = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        yield desc
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    finally:
+        os.sched_setaffinity(0, cpus)
+
+
+@pytest.fixture(scope="module")
+def one_chip_mesh(topo):
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from mxtpu.parallel.mesh import (AXIS_DP, AXIS_PP, AXIS_TP, AXIS_SP,
+                                     AXIS_EP)
+
+    return Mesh(np.array(topo.devices[:1]).reshape(1, 1, 1, 1, 1),
+                (AXIS_DP, AXIS_PP, AXIS_TP, AXIS_SP, AXIS_EP))
+
+
+def _lm_program_text(mesh, remat, k_steps):
+    """The compiled text of the LM's Adam train program on `mesh`: K
+    steps fused (`k_steps`), or one step (None)."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxtpu.parallel import transformer as tf
+
+    cfg = tf.TransformerConfig(remat=remat, **WIDTHS)
+    if k_steps is None:
+        step, sh = tf.make_train_step(cfg, mesh, lr=3e-4, optimizer="adam")
+        data_shape = (BATCH, SEQ)
+    else:
+        step, sh = tf.make_fused_train_steps(cfg, mesh, k_steps, lr=3e-4,
+                                             optimizer="adam")
+        data_shape = (k_steps, BATCH, SEQ)
+    shapes = tf.param_shapes(cfg, 1)
+    params = {n: jax.ShapeDtypeStruct(s, jnp.bfloat16,
+                                      sharding=sh["params"][n])
+              for n, s in shapes.items()}
+    moments = {n: jax.ShapeDtypeStruct(s, jnp.float32,
+                                       sharding=sh["opt_state"]["m"][n])
+               for n, s in shapes.items()}
+    opt = {"m": moments, "v": dict(moments),
+           "t": jax.ShapeDtypeStruct((), jnp.float32,
+                                     sharding=sh["opt_state"]["t"])}
+    data = jax.ShapeDtypeStruct(data_shape, jnp.int32, sharding=sh["data"])
+    return step.lower(params, opt, data, data).compile().as_text()
+
+
+_COMPUTATION = re.compile(r"^(ENTRY )?%?([\w.\-]+) \(.*\{\s*$")
+_WHILE = re.compile(r"\bwhile\(.*\bbody=%?([\w.\-]+)")
+_COPY = re.compile(r"= \w+\[([\d,]*)\][^ ]* copy\(")
+
+
+def _whiles_and_stack_copies(text, n_layers):
+    """What the compiled text says of its loops: a list of (body's name,
+    whether the `while` sits in the ENTRY computation), one per `while`,
+    and by computation name the `copy` instructions in it whose result
+    has `n_layers` as its leading dimension (a leading 1 set aside)."""
+    whiles, copies, name, entry = [], {}, None, False
+    for line in text.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            entry, name = bool(m.group(1)), m.group(2)
+            continue
+        m = _WHILE.search(line)
+        if m:
+            whiles.append((m.group(1), entry))
+        m = _COPY.search(line)
+        if m:
+            dims = [int(d) for d in m.group(1).split(",") if d]
+            while dims[:1] == [1]:
+                dims = dims[1:]
+            if dims[:1] == [n_layers]:
+                copies.setdefault(name, []).append(line.strip()[:120])
+    return whiles, copies
+
+
+@pytest.mark.parametrize("k_steps,remat", [
+    (K_STEPS, "dots"), (K_STEPS, "full"), (None, "dots"), (None, "full")],
+    ids=["fused_k8-dots", "fused_k8-full", "per_step-dots",
+         "per_step-full"])
+def test_lm_step_keeps_layer_stacks_in_place(one_chip_mesh, monkeypatch,
+                                             k_steps, remat):
+    """On one chip with one microbatch the layer weights reach the
+    backward pass as the arrays the step was given: no loop over the
+    layers copies a whole [n_layers, ...] stack in its body, and the
+    only loops are the forward and the backward layer scan (and the K
+    loop around them).
+    A one-trip pipeline loop in the loss broke that (PERF.md, PR 27:
+    a fourth, hoisted 24-trip loop, six whole-stack copies a trip)."""
+    from mxtpu.ops import pallas_attention as pa
+
+    # the program asks jax.devices() whether it is on a TPU, and here
+    # that is the CPU: steer it in the test, not by an option of the
+    # program, so that the Pallas kernels go through Mosaic
+    monkeypatch.setattr(pa, "_on_tpu", lambda: True)
+    text = _lm_program_text(one_chip_mesh, remat, k_steps)
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+    whiles, copies = _whiles_and_stack_copies(text, WIDTHS["n_layers"])
+    # the loops that run once per layer: every `while` of the per-step
+    # program; in the fused one, those inside the K loop, which is the
+    # `while` of the ENTRY computation (a copy in ITS body runs once per
+    # step: remat="full" keeps one weight stack in a second layout so)
+    layer_loops = [body for body, in_entry in whiles
+                   if k_steps is None or not in_entry]
+    found = [c for body in layer_loops for c in copies.get(body, [])]
+    assert not found, ("whole-stack copies once per layer:\n  "
+                       + "\n  ".join(found))
+    assert len(layer_loops) == 2, whiles
+    assert len(whiles) == (2 if k_steps is None else 3), whiles
