@@ -74,9 +74,12 @@ class _Sizes(object):
             self.classes = 1000
             self.batch, self.image, self.fused_k = 128, 224, 4
             # (bh, T, d): the bench shape; bh=6, which once failed to
-            # lower; T=640 (blocks fall to 128) at d=64; a long sequence
+            # lower; T=640 (blocks fall to 128) at d=64; a long
+            # sequence; d=256 at T=4096 (latent attention's q.k and v
+            # width: two lane tiles a head)
             self.attn_shapes = [(64, 1024, 128), (6, 1024, 128),
-                                (2, 640, 64), (8, 4096, 128)]
+                                (2, 640, 64), (8, 4096, 128),
+                                (4, 4096, 256)]
             self.attn_ragged, self.attn_ragged_block = (2, 200, 64), 128
             self.lm = dict(vocab=8192, d_model=1024, n_heads=8,
                            n_layers=8, d_ff=4096, max_len=1024)
@@ -85,7 +88,7 @@ class _Sizes(object):
         else:
             self.classes = 10
             self.batch, self.image, self.fused_k = 8, 16, 2
-            self.attn_shapes = [(6, 128, 32), (2, 160, 16)]
+            self.attn_shapes = [(6, 128, 32), (2, 160, 16), (2, 128, 64)]
             self.attn_ragged, self.attn_ragged_block = (2, 50, 16), 32
             self.lm = dict(vocab=64, d_model=32, n_heads=2, n_layers=2,
                            d_ff=64, max_len=64)

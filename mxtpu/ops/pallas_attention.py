@@ -116,7 +116,8 @@ def _tiles(block_q, block_k, d):
     """Whether the kernel takes these blocks.  The interpreter takes
     any; compiled, only the tilings that have been on the chip: score
     tiles of whole 128-lane vregs, and a head width that is a lane
-    multiple or fits inside one (chip_smoke.py checks d=128 and d=64).
+    multiple or fits inside one (chip_smoke.py checks d=64, d=128 and,
+    for latent attention's 192 + 64, d=256 at T=4096).
     Anything else, a T < 128 included, takes the reference path."""
     if _interpret():
         return True

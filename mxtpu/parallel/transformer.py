@@ -32,7 +32,8 @@ from .ring_attention import ring_attention, _match_vma
 
 __all__ = ["TransformerConfig", "init_params", "param_specs",
            "make_train_step", "make_fused_train_steps", "make_forward",
-           "dryrun", "init_opt_state", "param_shapes"]
+           "dryrun", "init_opt_state", "param_shapes", "MOE_STATS",
+           "publish_moe_stats"]
 
 _NEG_INF = -1e30
 # params below this element count keep replicated optimizer state
@@ -62,6 +63,53 @@ class TransformerConfig:
     # repo's symbolic executor exposes as MXTPU_BACKWARD_DO_MIRROR;
     # same policy vocabulary (`executor.apply_remat`).
 
+    # ---- layer kinds beyond the square-attention GELU block.  A config
+    # that sets none of the fields below builds the program it built
+    # before they existed.
+    norm_eps: float = 1e-6
+    attention: str = "mha"     # "mha": q / k / v of width d_model //
+    # n_heads, a learned position table.  "mla": latent attention —
+    # low-rank q with a norm between (q_lora_rank), one compressed kv
+    # (kv_lora_rank, normed) plus one rotary key shared by the heads,
+    # decompressed to per-head k_nope (qk_nope_dim) and v (v_head_dim);
+    # rotary positions (rope_theta, rotate-half) on the qk_rope_dim
+    # parts, and no position table.  The attention kernels take one
+    # width for q.k and v: qk_nope_dim + qk_rope_dim == v_head_dim.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    rope_theta: float = 10000.0
+    ffn: str = "gelu"          # "gelu": a pair (w1, w2).  "swiglu":
+    # silu(x Wg) * (x Wu) -> Wd — dense layers, routed experts and the
+    # shared expert alike.  It also states the routed experts' dispatch:
+    # GELU experts go through the capacity-bucketed top-1 exchange
+    # (`_experts_bucketed`, any ep; DROPS over capacity_factor), gated
+    # ones through grouped products over the held experts
+    # (`_experts_grouped`: no token dropped, no capacity_factor; ep = 1)
+    n_dense_layers: int = 0    # leading dense layers (width d_ff) in
+    # front of the expert layers: a segment of the stack with its own
+    # scan and its own parameter names ("dense.<leaf>")
+    d_expert: int = 0          # a routed expert's width; 0 = d_ff
+    top_k: int = 1             # experts per token
+    moe_score: str = "softmax"  # or "sigmoid"
+    moe_select_bias: bool = False   # a per-expert bias ("router_bias")
+    # added to the scores for SELECTION only; no gradient reaches it
+    moe_norm_topk: bool = False     # weights / their sum over the top_k
+    moe_scale: float = 1.0          # ... times this
+    n_shared_experts: int = 0  # a shared expert of width n * d_expert,
+    # added for every token
+    expert_first: int = 0      # the range of experts this layer holds:
+    experts_held: int = 0      # [first, first + held); 0 = all.  The
+    # router still scores all n_experts; the layer computes its own
+    # experts' part of the result (one chip's share of an expert-
+    # parallel deployment, run without the exchange)
+    mtp_depth: int = 0         # 1: a multi-token-prediction block
+    # (DeepSeek-V3's form) after the stack, on the shared embedding and
+    # head: params "mtp.<leaf>"; its loss is added times mtp_weight
+    mtp_weight: float = 0.3
+
     def __post_init__(self):
         from ..executor import _REMAT_POLICIES
 
@@ -69,6 +117,147 @@ class TransformerConfig:
             raise MXNetError(
                 "TransformerConfig.remat must be 'none' or one of %s "
                 "(got %r)" % (sorted(_REMAT_POLICIES), self.remat))
+        bad = None
+        if self.attention not in ("mha", "mla"):
+            bad = "attention must be 'mha' or 'mla'"
+        elif self.attention == "mla" and (
+                min(self.q_lora_rank, self.kv_lora_rank, self.qk_nope_dim,
+                    self.v_head_dim) < 1 or self.qk_rope_dim < 2
+                or self.qk_rope_dim % 2
+                or self.qk_nope_dim + self.qk_rope_dim != self.v_head_dim):
+            bad = ("attention='mla' needs q_lora_rank, kv_lora_rank, "
+                   "qk_nope_dim, an even qk_rope_dim, and qk_nope_dim + "
+                   "qk_rope_dim == v_head_dim (the kernels' one width)")
+        elif self.ffn not in ("gelu", "swiglu"):
+            bad = "ffn must be 'gelu' or 'swiglu'"
+        elif self.moe_score not in ("softmax", "sigmoid"):
+            bad = "moe_score must be 'softmax' or 'sigmoid'"
+        elif self.mtp_depth not in (0, 1):
+            bad = "mtp_depth must be 0 or 1"
+        elif self.n_dense_layers and not (
+                self.n_experts and 0 < self.n_dense_layers < self.n_layers):
+            bad = ("n_dense_layers are the layers in front of the expert "
+                   "layers: it needs n_experts and fewer than n_layers")
+        elif self.n_experts:
+            held = self.experts_held or self.n_experts
+            if not 1 <= self.top_k <= self.n_experts:
+                bad = "top_k must be in 1..n_experts"
+            elif self.expert_first < 0 or \
+                    self.expert_first + held > self.n_experts:
+                bad = "the held experts lie outside 0..n_experts"
+            elif self.ffn == "gelu" and (
+                    self.top_k != 1 or self.experts_held
+                    or self.n_shared_experts):
+                bad = ("GELU experts run the capacity-bucketed exchange, "
+                       "which is top-1 over all experts; top_k > 1, a held "
+                       "range and a shared expert need ffn='swiglu' (the "
+                       "grouped, dropless dispatch)")
+        if bad:
+            raise MXNetError("TransformerConfig: " + bad)
+
+
+def _segments(cfg: TransformerConfig):
+    """The stack in segments, each a homogeneous run of layers with a
+    `lax.scan` of its own: [(parameter-name prefix, kind, layers)], kind
+    "dense" or "moe".  The model's repeated layer kind keeps the bare
+    leaf names; leading dense layers are "dense.<leaf>", the multi-
+    token-prediction block's layer "mtp.<leaf>"."""
+    kind = "moe" if cfg.n_experts else "dense"
+    segs = []
+    if cfg.n_dense_layers:
+        segs.append(("dense.", "dense", cfg.n_dense_layers))
+    segs.append(("", kind, cfg.n_layers - cfg.n_dense_layers))
+    if cfg.mtp_depth:
+        segs.append(("mtp.", kind, cfg.mtp_depth))
+    return segs
+
+
+def _layer_leaves(cfg: TransformerConfig, kind: str):
+    """{leaf: (shape, spec, fan_in)} of ONE layer of `kind`: Megatron
+    layout on tp (columns of the first product, rows of the last),
+    experts on ep.  fan_in None marks a norm scale (ones)."""
+    E, H = cfg.d_model, cfg.n_heads
+    col, row = (None, AXIS_TP), (AXIS_TP, None)
+    out = {"ln1": ((E,), (None,), None), "ln2": ((E,), (None,), None)}
+    if cfg.attention == "mla":
+        ql, kvl = cfg.q_lora_rank, cfg.kv_lora_rank
+        dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+        out.update({
+            "wq_a": ((E, ql), (None, None), E),
+            "q_norm": ((ql,), (None,), None),
+            "wq_b": ((ql, H * (dn + dr)), col, ql),
+            "wkv_a": ((E, kvl + dr), (None, None), E),
+            "kv_norm": ((kvl,), (None,), None),
+            "wkv_b": ((kvl, H * (dn + dv)), col, kvl),
+            "wo": ((H * dv, E), row, H * dv)})
+    else:
+        out.update({n: ((E, E), col, E) for n in ("wq", "wk", "wv")})
+        out["wo"] = ((E, E), row, E)
+
+    def ffn(names, width, lead=(), lead_spec=()):
+        first, last = (names[:-1], names[-1])
+        for n in first:
+            out[n] = (lead + (E, width), lead_spec + col, E)
+        out[last] = (lead + (width, E), lead_spec + row, width)
+
+    pair = cfg.ffn == "gelu"
+    if kind == "dense":
+        ffn(("w1", "w2") if pair else ("wg", "wu", "wd"), cfg.d_ff)
+        return out
+    NE, Fe = cfg.n_experts, cfg.d_expert or cfg.d_ff
+    out["router"] = ((E, NE), (None, None), E)
+    if cfg.moe_select_bias:
+        out["router_bias"] = ((NE,), (None,), 1e4)    # drawn small
+    ffn(("we1", "we2") if pair else ("we_g", "we_u", "we_d"), Fe,
+        (cfg.experts_held or NE,), (AXIS_EP,))
+    if cfg.n_shared_experts:
+        ffn(("ws_g", "ws_u", "ws_d"), cfg.n_shared_experts * Fe)
+    return out
+
+
+def _leaves(cfg: TransformerConfig, pp: int):
+    """{parameter: (global shape, PartitionSpec, fan_in)}: the single
+    source `param_shapes`, `param_specs` and `init_params` share.  A
+    layer leaf is stacked [pp, layers per stage, ...]; a stack in more
+    than one segment lives on one stage."""
+    from jax.sharding import PartitionSpec as P
+
+    if cfg.n_layers % pp:
+        raise MXNetError("n_layers=%d not divisible by pp=%d"
+                         % (cfg.n_layers, pp))
+    segs = _segments(cfg)
+    if len(segs) > 1 and pp > 1:
+        raise MXNetError("a stack in segments (leading dense layers, a "
+                         "multi-token-prediction block) needs pp = 1")
+    E, V = cfg.d_model, cfg.vocab
+    out = {"embed": ((V, E), P(AXIS_TP, None), E),   # vocab-sharded
+           "ln_f": ((E,), P(None), None),
+           "unembed": ((E, V), P(None, AXIS_TP), E)}
+    if cfg.attention == "mha":                     # rotary: no table
+        out["pos"] = ((cfg.max_len, E), P(None, None), E)
+    for prefix, kind, n in segs:
+        for leaf, (shape, spec, fan_in) in _layer_leaves(cfg,
+                                                         kind).items():
+            out[prefix + leaf] = ((pp, n // pp) + shape,
+                                  P(AXIS_PP, None, *spec), fan_in)
+    if cfg.mtp_depth:
+        out["mtp.eh"] = ((2 * E, E), P(None, None), 2 * E)
+        for leaf in ("mtp.ln_e", "mtp.ln_h", "mtp.ln_f"):
+            out[leaf] = ((E,), P(None), None)
+    return out
+
+
+def _has_stats(cfg: TransformerConfig) -> bool:
+    """Whether the expert layers run the grouped, dropless dispatch
+    (`_experts_grouped`: gated experts), whose step returns the routed
+    experts' per-step counters (`MOE_STATS`) beside its loss."""
+    return bool(cfg.n_experts and cfg.ffn == "swiglu")
+
+
+# per step, over every expert layer (the MTP block's too): token-expert
+# pairs the router put in the held range; tokens routed (tokens x expert
+# layers); the fullest held expert's pairs in any one layer
+MOE_STATS = ("moe_pairs", "moe_tokens", "moe_load_max")
 
 
 # ---------------------------------------------------------------------------
@@ -84,87 +273,34 @@ def init_params(cfg: TransformerConfig, mesh, seed: int = 0):
     import jax.numpy as jnp
     from jax.sharding import NamedSharding
 
-    pp = mesh.shape[AXIS_PP]
-    shapes = param_shapes(cfg, pp)  # single shape source (+div check)
-    E, F = cfg.d_model, cfg.d_ff
+    leaves = _leaves(cfg, mesh.shape[AXIS_PP])  # single source (+div check)
     key = jax.random.PRNGKey(seed)
     ks = jax.random.split(key, 16)
     dt = jnp.dtype(cfg.dtype)
-
-    def norm(k, shape, fan_in):
-        return (jax.random.normal(k, shape, jnp.float32)
-                * (1.0 / fan_in) ** 0.5).astype(dt)
-
-    # fan-in per param; ones-initialized norms have no fan-in entry
-    fan_in = {"embed": E, "pos": E, "unembed": E, "wq": E, "wk": E,
-              "wv": E, "wo": E, "router": E, "we1": E, "we2": F,
-              "w1": E, "w2": F}
-    p = {}
-    for i, (name, shape) in enumerate(sorted(shapes.items())):
-        if name in ("ln_f", "ln1", "ln2"):
-            p[name] = jnp.ones(shape, dt)
-        else:
-            p[name] = norm(ks[i], shape, fan_in[name])
-
-    specs = param_specs(cfg)
     out = {}
-    for name, arr in p.items():
-        out[name] = jax.device_put(
-            arr, NamedSharding(mesh, specs[name]))
+    for i, (name, (shape, spec, fan_in)) in enumerate(
+            sorted(leaves.items())):
+        if fan_in is None:                # norm scales start at one
+            arr = jnp.ones(shape, dt)
+        else:
+            k = ks[i] if i < 16 else jax.random.fold_in(key, i)
+            arr = (jax.random.normal(k, shape, jnp.float32)
+                   * (1.0 / fan_in) ** 0.5).astype(dt)
+        out[name] = jax.device_put(arr, NamedSharding(mesh, spec))
     return out
 
 
 def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
     """PartitionSpec per parameter (Megatron layout on tp, stage-stacked
     on pp, experts on ep)."""
-    from jax.sharding import PartitionSpec as P
-
-    specs = {
-        "embed": P(AXIS_TP, None),       # vocab-sharded embedding
-        "pos": P(None, None),
-        "ln_f": P(None),
-        "unembed": P(None, AXIS_TP),     # vocab-sharded unembedding
-        "wq": P(AXIS_PP, None, None, AXIS_TP),   # column parallel
-        "wk": P(AXIS_PP, None, None, AXIS_TP),
-        "wv": P(AXIS_PP, None, None, AXIS_TP),
-        "wo": P(AXIS_PP, None, AXIS_TP, None),   # row parallel
-        "ln1": P(AXIS_PP, None, None),
-        "ln2": P(AXIS_PP, None, None),
-    }
-    if cfg.n_experts:
-        specs["router"] = P(AXIS_PP, None, None, None)
-        specs["we1"] = P(AXIS_PP, None, AXIS_EP, None, AXIS_TP)
-        specs["we2"] = P(AXIS_PP, None, AXIS_EP, AXIS_TP, None)
-    else:
-        specs["w1"] = P(AXIS_PP, None, None, AXIS_TP)
-        specs["w2"] = P(AXIS_PP, None, AXIS_TP, None)
-    return specs
+    return {name: spec for name, (_, spec, _) in _leaves(cfg, 1).items()}
 
 
 def param_shapes(cfg: TransformerConfig, pp: int) -> Dict[str, Tuple]:
     """Global parameter shapes — the single source init_params and the
     optimizer-state builders share."""
-    if cfg.n_layers % pp:
-        raise MXNetError("n_layers=%d not divisible by pp=%d"
-                         % (cfg.n_layers, pp))
-    lps = cfg.n_layers // pp
-    E, F, V = cfg.d_model, cfg.d_ff, cfg.vocab
-    shapes = {
-        "embed": (V, E), "pos": (cfg.max_len, E), "ln_f": (E,),
-        "unembed": (E, V),
-        "wq": (pp, lps, E, E), "wk": (pp, lps, E, E),
-        "wv": (pp, lps, E, E), "wo": (pp, lps, E, E),
-        "ln1": (pp, lps, E), "ln2": (pp, lps, E),
-    }
-    if cfg.n_experts:
-        NE = cfg.n_experts
-        shapes["router"] = (pp, lps, E, NE)
-        shapes["we1"] = (pp, lps, NE, E, F)
-        shapes["we2"] = (pp, lps, NE, F, E)
-    else:
-        shapes["w1"] = (pp, lps, E, F)
-        shapes["w2"] = (pp, lps, F, E)
-    return shapes
+    return {name: shape for name, (shape, _, _) in _leaves(cfg,
+                                                           pp).items()}
 
 
 def _plan_for_mesh(cfg: TransformerConfig, mesh):
@@ -245,12 +381,12 @@ def _grad_psum_axes(cfg: TransformerConfig) -> Dict[str, Tuple[str, ...]]:
 # model (runs INSIDE shard_map: arrays are per-device shards)
 
 
-def _rms_norm(x, scale):
+def _rms_norm(x, scale, eps=1e-6):
     import jax.numpy as jnp
 
     x32 = x.astype(jnp.float32)
     var = (x32 * x32).mean(-1, keepdims=True)
-    return (x32 * jnp.reciprocal(jnp.sqrt(var + 1e-6))).astype(x.dtype) \
+    return (x32 * jnp.reciprocal(jnp.sqrt(var + eps))).astype(x.dtype) \
         * scale
 
 
@@ -275,6 +411,64 @@ def _attention(cfg, x, wq, wk, wv, wo, tp_size):
     return jax.lax.psum(out, AXIS_TP)
 
 
+def _rotary_table(cfg, positions):
+    """(cos, sin), float32 [T, qk_rope_dim / 2], of the global
+    `positions`: angle = position * rope_theta ** (-2i / qk_rope_dim)."""
+    import jax.numpy as jnp
+
+    half = cfg.qk_rope_dim // 2
+    inv = cfg.rope_theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    ang = positions.astype(jnp.float32)[:, None] * inv[None]
+    return jnp.cos(ang), jnp.sin(ang)
+
+
+def _rotate(x, rope):
+    """Rotary embedding of x [..., T, d], rotate-half pairing: dim i is
+    paired with dim i + d/2."""
+    import jax.numpy as jnp
+
+    cos, sin = rope
+    half = x.shape[-1] // 2
+    x1 = x[..., :half].astype(jnp.float32)
+    x2 = x[..., half:].astype(jnp.float32)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           -1).astype(x.dtype)
+
+
+def _mla(cfg, x, lw, tp_size, rope):
+    """Latent attention in its decompressed (training) form.  x: [B,
+    T_loc, E].  q goes through a rank-q_lora_rank bottleneck with a norm
+    in it; keys and values come from ONE compressed row per token
+    (kv_lora_rank wide, normed) plus one rotary key of qk_rope_dim that
+    all heads share.  Heads are column-sharded over tp in wq_b / wkv_b
+    and row-sharded in wo; the compressions are replicated.  q.k is
+    qk_nope_dim + qk_rope_dim wide, which the config holds equal to
+    v_head_dim, so the flash kernels serve as they are."""
+    import jax
+    import jax.numpy as jnp
+
+    B, T, _ = x.shape
+    h = cfg.n_heads // tp_size
+    dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+    kvl = cfg.kv_lora_rank
+
+    def heads(y, d):
+        return y.reshape(B, T, h, d).transpose(0, 2, 1, 3)
+
+    c_q = _rms_norm(x @ lw["wq_a"], lw["q_norm"], cfg.norm_eps)
+    q = heads(c_q @ lw["wq_b"], dn + dr)
+    q = jnp.concatenate([q[..., :dn], _rotate(q[..., dn:], rope)], -1)
+    ckv = x @ lw["wkv_a"]
+    c_kv = _rms_norm(ckv[..., :kvl], lw["kv_norm"], cfg.norm_eps)
+    k_pe = _rotate(ckv[..., kvl:][:, None], rope)         # [B, 1, T, dr]
+    kv = heads(c_kv @ lw["wkv_b"], dn + dv)
+    k = jnp.concatenate(
+        [kv[..., :dn], jnp.broadcast_to(k_pe, (B, h, T, dr))], -1)
+    o = ring_attention(q, k, kv[..., dn:], axis_name=AXIS_SP, causal=True)
+    o = o.transpose(0, 2, 1, 3).reshape(B, T, h * dv)
+    return jax.lax.psum(o @ lw["wo"], AXIS_TP)
+
+
 def _dense_ffn(x, w1, w2):
     import jax
     import jax.numpy as jnp
@@ -285,29 +479,124 @@ def _dense_ffn(x, w1, w2):
     return jax.lax.psum(h @ w2, AXIS_TP)
 
 
-def _moe_ffn(cfg, x, router, we1, we2, ep_size):
-    """Switch-style top-1 MoE with all_to_all dispatch over "ep".
+def _gated_ffn(x, wg, wu, wd):
+    """SiLU-gated feed-forward: (silu(x Wg) * (x Wu)) Wd.  The two
+    products come out in the activations' type (what `remat="dots"`
+    keeps of them is half of what float32 outputs would be: 0.6 GB of
+    the glm cell's step) and the gate is taken in float32."""
+    import jax
+    import jax.numpy as jnp
 
-    x: [B, T, E] local tokens; we1: [NE/ep, E, F/tp] local expert shard.
-    Tokens are bucketed by destination expert (capacity-dropped),
-    exchanged over the ep ring, processed by the local experts, and sent
-    back.  With ep=1 the all_to_all is the identity and this reduces to
-    single-host switch routing.
+    g = (x @ wg).astype(jnp.float32)
+    u = (x @ wu).astype(jnp.float32)
+    h = (jax.nn.silu(g) * u).astype(x.dtype)
+    return jax.lax.psum(h @ wd, AXIS_TP)
+
+
+def _route(cfg, flat, router, bias=None):
+    """Scores, selection and weights of the routed experts: ONE piece of
+    code for every mesh and both dispatches.  flat: [n_tok, E].  Returns
+    (expert ids [n_tok, top_k] int32 over ALL n_experts, weights [n_tok,
+    top_k] float32).  Scores are float32 (float32 accumulation of the
+    stored operands): softmax or sigmoid over all experts.  `bias` is
+    added for the selection alone: the weights are the unbiased scores
+    of the selected, divided by their sum over all top_k (held here or
+    not) under `moe_norm_topk`, times `moe_scale`.  Gradients reach the
+    router through the weights only."""
+    import jax
+    import jax.numpy as jnp
+
+    logits = jnp.einsum("ne,ex->nx", flat, router,
+                        preferred_element_type=jnp.float32)
+    scores = jax.nn.softmax(logits, axis=-1) \
+        if cfg.moe_score == "softmax" else jax.nn.sigmoid(logits)
+    select = scores if bias is None else \
+        scores + jax.lax.stop_gradient(bias.astype(jnp.float32))
+    _, idx = jax.lax.top_k(jax.lax.stop_gradient(select), cfg.top_k)
+    w = jnp.take_along_axis(scores, idx, axis=1)
+    if cfg.moe_norm_topk:
+        w = w / (w.sum(-1, keepdims=True) + 1e-20)
+    if cfg.moe_scale != 1.0:
+        w = w * cfg.moe_scale
+    return idx.astype(jnp.int32), w
+
+
+def _experts_grouped(cfg, flat, idx, w, lw):
+    """The held experts' part of the routed result, with no token
+    dropped (ep = 1).  The (token, expert) pairs that fall in the held
+    range [expert_first, expert_first + held) are sorted by expert and
+    the experts' products run as grouped products over them
+    (`jax.lax.ragged_dot`: rows of one group meet one expert's matrix),
+    then each row is scattered back onto its token, weighted.  The row
+    bound n_tok * min(top_k, held) is static and no routing can overflow
+    it (a token's top_k experts are distinct), which is what makes it
+    dropless by construction; what experts held elsewhere would add is
+    left out.  we_*: [held, E, F/tp].  Returns ([n_tok, E], stats)."""
+    # (silu of unwritten rows in between is harmless: those rows meet no
+    # expert's matrix in the next grouped product either)
+    import jax
+    import jax.numpy as jnp
+
+    n, E = flat.shape
+    k = cfg.top_k
+    held = cfg.experts_held or cfg.n_experts
+    rows = n * min(k, held)
+    with jax.named_scope("dispatch"):
+        local = idx - cfg.expert_first
+        here = (local >= 0) & (local < held)
+        # pairs held elsewhere sort behind every group and get no weight
+        key = jnp.where(here, local, held).reshape(n * k)
+        order = jnp.argsort(key, stable=True)[:rows]
+        key_s = key[order]
+        tok = order // k
+        sizes = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
+        # rows past the last group belong to no expert.  The TPU's
+        # grouped product does not WRITE them, in either pass: what it
+        # leaves there is whatever the memory held.  So the layer masks
+        # what it feeds (the transpose of this `where` then masks the
+        # cotangent that comes back for those rows, before it is
+        # scattered onto tokens) and what it takes out
+        used = (key_s < held)[:, None]
+        xs = jnp.where(used, flat[tok], 0)                # [rows, E]
+        ws = jnp.where(used[:, 0], w.reshape(n * k)[order], 0.0)
+    with jax.named_scope("experts"):
+        def grouped(a, b):
+            return jax.lax.ragged_dot(a, b, sizes,
+                                      preferred_element_type=jnp.float32)
+
+        hid = (jax.nn.silu(grouped(xs, lw["we_g"]))
+               * grouped(xs, lw["we_u"])).astype(flat.dtype)
+        y = grouped(hid, lw["we_d"])
+        y = jax.lax.psum(jnp.where(used, y, 0.0), AXIS_TP)  # row-parallel
+    with jax.named_scope("combine"):
+        out = jnp.zeros((n, E), jnp.float32).at[tok].add(
+            y * ws[:, None]).astype(flat.dtype)
+    f32 = jnp.float32
+    stats = {"moe_pairs": here.sum().astype(f32),
+             "moe_tokens": _pvary_all(jnp.asarray(n, f32)),
+             "moe_load_max": sizes.max().astype(f32)}
+    return out, stats
+
+
+def _experts_bucketed(cfg, flat, expert, gate, we1, we2, ep_size):
+    """Switch-style top-1 dispatch with all_to_all over "ep": the
+    exchange for ep > 1 (static exchange shapes), and the top-1 path on
+    every mesh.  It DROPS: each expert takes at most capacity_factor *
+    n_tok / n_experts tokens and the overflow contributes nothing.
+
+    flat: [n_tok, E] local tokens; expert, gate: [n_tok] from `_route`;
+    we1: [NE/ep, E, F/tp] local expert shard.  Tokens are bucketed by
+    destination expert, exchanged over the ep ring, processed by the
+    local experts, and sent back.  With ep=1 the all_to_all is the
+    identity and this reduces to single-host switch routing.
     """
     import jax
     import jax.numpy as jnp
 
-    B, T, E = x.shape
+    n_tok, E = flat.shape
     NE = cfg.n_experts
     ne_loc = NE // ep_size
-    n_tok = B * T
     cap = max(1, int(cfg.capacity_factor * n_tok / NE))
-
-    flat = x.reshape(n_tok, E)
-    logits = (flat @ router).astype(jnp.float32)          # [n_tok, NE]
-    gates = jax.nn.softmax(logits, axis=-1)
-    expert = jnp.argmax(gates, axis=-1)                    # [n_tok]
-    gate = jnp.take_along_axis(gates, expert[:, None], 1)[:, 0]
 
     # position of each token within its expert bucket; drop overflow
     onehot = jax.nn.one_hot(expert, NE, dtype=jnp.int32)   # [n_tok, NE]
@@ -338,7 +627,7 @@ def _moe_ffn(cfg, x, router, we1, we2, ep_size):
     # (upcasting b/we1 would force the multi-pass f32 matmul path)
     h = jax.nn.gelu(jnp.einsum(
         "nce,nef->ncf", b, we1,
-        preferred_element_type=jnp.float32)).astype(x.dtype)
+        preferred_element_type=jnp.float32)).astype(flat.dtype)
     y = jnp.einsum("ncf,nfe->nce", h, we2)
     y = jax.lax.psum(y, AXIS_TP)                           # row-parallel
 
@@ -350,8 +639,30 @@ def _moe_ffn(cfg, x, router, we1, we2, ep_size):
     else:
         y = y.reshape(NE, cap, E)
 
-    out = y[expert, safe_pos] * gate[:, None].astype(x.dtype)
-    return out.reshape(B, T, E)
+    return y[expert, safe_pos] * gate[:, None].astype(flat.dtype)
+
+
+def _moe_ffn(cfg, x, lw, ep_size):
+    """The expert layer's feed-forward: route (`_route`), the held
+    routed experts' part (grouped and dropless, or the capacity-bucketed
+    exchange), and the shared expert for every token.  x: [B, T, E].
+    Returns (f [B, T, E], stats: `MOE_STATS` or {})."""
+    import jax
+
+    B, T, E = x.shape
+    flat = x.reshape(B * T, E)
+    with jax.named_scope("router"):
+        idx, w = _route(cfg, flat, lw["router"], lw.get("router_bias"))
+    if cfg.ffn == "swiglu":
+        f, stats = _experts_grouped(cfg, flat, idx, w, lw)
+    else:
+        f, stats = _experts_bucketed(cfg, flat, idx[:, 0], w[:, 0],
+                                     lw["we1"], lw["we2"], ep_size), {}
+    if cfg.n_shared_experts:
+        with jax.named_scope("shared_expert"):
+            f = f + _gated_ffn(x, lw["ws_g"], lw["ws_u"],
+                               lw["ws_d"]).reshape(B * T, E)
+    return f.reshape(B, T, E), stats
 
 
 def _pvary_all(x):
@@ -370,37 +681,101 @@ def _pvary_all(x):
     return x
 
 
-def _stage_fn(cfg, params_stage, x, tp_size, ep_size):
-    """Run this pipeline stage's layers_per_stage layers over x via
-    lax.scan (weights stacked on the layer axis)."""
+def _merge_stats(a, b):
+    """Two sets of `MOE_STATS` as one: counts add, the fullest expert is
+    the fuller."""
+    import jax.numpy as jnp
+
+    if not a or not b:
+        return a or b
+    return {k: (jnp.maximum(a[k], b[k]) if k == "moe_load_max"
+                else a[k] + b[k]) for k in a}
+
+
+def _stage_fn(cfg, kind, params_stage, x, tp_size, ep_size, rope=None):
+    """Run one segment's layers (this pipeline stage's share of them)
+    over x via lax.scan (weights stacked on the layer axis).  `kind` is
+    "dense" or "moe"; `rope` the rotary table where positions are
+    rotary.  Returns (x, stats of the segment's expert layers or {})."""
     import jax
 
     x = _pvary_all(x)
 
-    # the named scopes (here, and `embed` / `loss` / `adam` below) are
-    # metadata on the device program's instructions: a trace's device
-    # time can be summed by them (PERF.md)
+    # the named scopes (here, `router` .. `combine` in the expert layer,
+    # and `embed` / `mtp` / `loss` / `adam` below) are metadata on the
+    # device program's instructions: a trace's device time can be summed
+    # by them (PERF.md)
     def layer(x, lw):
-        with jax.named_scope("attn"):
-            h = x + _attention(cfg, _rms_norm(x, lw["ln1"]),
-                               lw["wq"], lw["wk"], lw["wv"], lw["wo"],
-                               tp_size)
+        if cfg.attention == "mla":
+            with jax.named_scope("mla"):
+                h = x + _mla(cfg, _rms_norm(x, lw["ln1"], cfg.norm_eps),
+                             lw, tp_size, rope)
+        else:
+            with jax.named_scope("attn"):
+                h = x + _attention(cfg, _rms_norm(x, lw["ln1"],
+                                                  cfg.norm_eps),
+                                   lw["wq"], lw["wk"], lw["wv"], lw["wo"],
+                                   tp_size)
         with jax.named_scope("ffn"):
-            z = _rms_norm(h, lw["ln2"])
-            if cfg.n_experts:
-                f = _moe_ffn(cfg, z, lw["router"], lw["we1"], lw["we2"],
-                             ep_size)
+            z = _rms_norm(h, lw["ln2"], cfg.norm_eps)
+            if kind == "moe":
+                f, stats = _moe_ffn(cfg, z, lw, ep_size)
+            elif cfg.ffn == "gelu":
+                f, stats = _dense_ffn(z, lw["w1"], lw["w2"]), {}
             else:
-                f = _dense_ffn(z, lw["w1"], lw["w2"])
-            return h + f, None
+                f, stats = _gated_ffn(z, lw["wg"], lw["wu"], lw["wd"]), {}
+            return h + f, stats
 
     if cfg.remat != "none":
         from ..executor import apply_remat
 
         layer = apply_remat(layer, cfg.remat, prevent_cse=False)
 
-    out, _ = jax.lax.scan(layer, x, params_stage)
-    return out
+    out, per_layer = jax.lax.scan(layer, x, params_stage)
+    if not per_layer:
+        return out, {}
+    return out, {k: (v.max() if k == "moe_load_max" else v.sum())
+                 for k, v in per_layer.items()}
+
+
+def _segment_params(cfg, params, prefix, kind):
+    """One segment's stacked layer weights, [layers, ...] each: the
+    leading pp axis is sharded, so inside shard_map it has extent 1."""
+    return {leaf: params[prefix + leaf][0]
+            for leaf in _layer_leaves(cfg, kind)}
+
+
+def _run_stack(cfg, params, x, tp_size, ep_size, rope):
+    """The model's layers in order, segment by segment (the multi-token-
+    prediction block is not one of them).  Returns (x, stats)."""
+    stats = {}
+    for prefix, kind, _ in _segments(cfg):
+        if prefix != "mtp.":
+            x, st = _stage_fn(cfg, kind,
+                              _segment_params(cfg, params, prefix, kind),
+                              x, tp_size, ep_size, rope)
+            stats = _merge_stats(stats, st)
+    return x, stats
+
+
+def _embed(cfg, params, tokens, tp_idx, V_loc, positions):
+    """Vocab-sharded embedding lookup (local rows + psum over tp), plus
+    the learned positions where the model has a table."""
+    import jax
+    import jax.numpy as jnp
+
+    local_tok = tokens - tp_idx * V_loc
+    in_shard = (local_tok >= 0) & (local_tok < V_loc)
+    emb = jnp.where(
+        in_shard[..., None],
+        params["embed"][jnp.clip(local_tok, 0, V_loc - 1)], 0.0)
+    # exactly one tp shard contributes a non-zero row per token
+    # (vocab-sharded one-hot), so a native-dtype psum is exact
+    # and halves the ICI bytes vs upcasting to f32 first
+    emb = jax.lax.psum(emb, AXIS_TP)
+    if "pos" in params:
+        emb = emb + params["pos"][positions][None]
+    return emb.astype(jnp.dtype(cfg.dtype))               # [B, T, E]
 
 
 def _sharded_xent(logits_loc, labels, vocab_shard_size):
@@ -461,7 +836,9 @@ def _build_loss_fn(cfg: TransformerConfig, mesh, n_micro: int):
     grad_axes = _grad_psum_axes(cfg)
 
     def loss_fn(params, tokens, labels):
-        """tokens/labels: local shard [B_loc, T_loc] (dp × sp)."""
+        """tokens/labels: local shard [B_loc, T_loc] (dp × sp).  Returns
+        (loss, stats): the routed experts' counters of `MOE_STATS` where
+        `_has_stats(cfg)`, else {}."""
         pp_idx = jax.lax.axis_index(AXIS_PP)
         sp_idx = jax.lax.axis_index(AXIS_SP)
         tp_idx = jax.lax.axis_index(AXIS_TP)
@@ -469,40 +846,26 @@ def _build_loss_fn(cfg: TransformerConfig, mesh, n_micro: int):
         if B % n_micro:
             raise MXNetError("local batch %d %% n_micro %d" % (B, n_micro))
 
-        # vocab-sharded embedding lookup: local rows + psum over tp
+        pos_global = sp_idx * T + jnp.arange(T)
+        rope = _rotary_table(cfg, pos_global) \
+            if cfg.attention == "mla" else None
         with jax.named_scope("embed"):
-            local_tok = tokens - tp_idx * V_loc
-            in_shard = (local_tok >= 0) & (local_tok < V_loc)
-            emb = jnp.where(
-                in_shard[..., None],
-                params["embed"][jnp.clip(local_tok, 0, V_loc - 1)], 0.0)
-            # exactly one tp shard contributes a non-zero row per token
-            # (vocab-sharded one-hot), so a native-dtype psum is exact
-            # and halves the ICI bytes vs upcasting to f32 first
-            emb = jax.lax.psum(emb, AXIS_TP)
-            pos_global = sp_idx * T + jnp.arange(T)
-            x = (emb + params["pos"][pos_global][None]).astype(
-                jnp.dtype(cfg.dtype))                     # [B, T, E]
-
-        # my stage's layer stack: params["wq"][pp_idx] etc (leading pp
-        # axis is sharded, so inside shard_map it has extent 1)
-        stage_params = {}
-        for name in ("wq", "wk", "wv", "wo", "ln1", "ln2", "w1", "w2",
-                     "router", "we1", "we2"):
-            if name in params:
-                stage_params[name] = params[name][0]      # [lps, ...]
+            x = _embed(cfg, params, tokens, tp_idx, V_loc, pos_global)
 
         is_last = (pp_idx == pp - 1)
 
         def run_stage(state):
-            return _stage_fn(cfg, stage_params, state, tp, ep)
+            return _run_stack(cfg, params, state, tp, ep, rope)
 
         n_steps = n_micro + pp - 1
         if n_steps == 1:
             # one stage, one microbatch: one call, and never a loop of
             # one trip (the docstring above says what that costs)
-            h = run_stage(x)
+            h, stats = run_stage(x)
         else:
+            if _has_stats(cfg):
+                raise MXNetError("the routed experts' counters need one "
+                                 "stage and one microbatch")
             mb, E = B // n_micro, cfg.d_model
             x_mb = x.reshape(n_micro, mb, T, E)
             perm_fwd = [(i, (i + 1) % pp) for i in range(pp)]
@@ -513,7 +876,7 @@ def _build_loss_fn(cfg: TransformerConfig, mesh, n_micro: int):
                 state, out_buf = carry
                 feed = x_mb[jnp.clip(s, 0, n_micro - 1)]
                 inp = jnp.where(is_first, feed, state)
-                out = run_stage(inp)
+                out, _ = run_stage(inp)
                 slot = jnp.clip(s - (pp - 1), 0, n_micro - 1)
                 out_buf = out_buf.at[slot].set(
                     jnp.where(is_last, out, out_buf[slot]))
@@ -524,16 +887,60 @@ def _build_loss_fn(cfg: TransformerConfig, mesh, n_micro: int):
             state0 = _pvary_all(jnp.zeros((mb, T, E), x.dtype))
             _, out_buf = jax.lax.fori_loop(0, n_steps, step,
                                            (state0, out_buf))
-            h = out_buf.reshape(B, T, E)
+            h, stats = out_buf.reshape(B, T, E), {}
+
+        mtp_loss = None
+        if cfg.mtp_depth:
+            # predict token i+2 from the stack's output at i and the
+            # embedding of token i+1: one more layer of the model's kind
+            # on [norm(emb) | norm(h)] W_eh, then the SHARED head.  The
+            # sequence keeps its length (the kernels' tiles): the last
+            # position has no next token, is fed the first one's
+            # embedding, stays causal-invisible to the others and is
+            # left out of the mean
+            if sp > 1:
+                raise MXNetError("the multi-token-prediction block "
+                                 "shifts along the sequence: sp = 1")
+            with jax.named_scope("mtp"):
+                eps = cfg.norm_eps
+                nxt = _embed(cfg, params, jnp.roll(tokens, -1, axis=1),
+                             tp_idx, V_loc, pos_global)
+                u = jnp.concatenate(
+                    [_rms_norm(nxt, params["mtp.ln_e"], eps),
+                     _rms_norm(h, params["mtp.ln_h"], eps)], -1) \
+                    @ params["mtp.eh"]
+                kind = _segments(cfg)[-1][1]
+                u, st = _stage_fn(
+                    cfg, kind, _segment_params(cfg, params, "mtp.", kind),
+                    u, tp, ep, rope)
+                stats = _merge_stats(stats, st)
+
+                def head(u, ln, unembed, labels):
+                    nll = _sharded_xent(
+                        (_rms_norm(u, ln, eps) @ unembed).reshape(
+                            B * T, V_loc),
+                        jnp.roll(labels, -1, axis=1).reshape(B * T), V_loc)
+                    valid = (jnp.arange(T) < T - 1).astype(nll.dtype)
+                    return (nll.reshape(B, T) * valid).sum() \
+                        / (B * (T - 1))
+
+                # a second set of logits: kept for the backward pass it
+                # would lie beside the main head's at the step's memory
+                # peak, so it is made again there (one product more)
+                mtp_loss = jax.checkpoint(head)(
+                    u, params["mtp.ln_f"], params["unembed"], labels)
 
         # only the last stage's h is the real model output; psum the
         # masked loss over pp so every rank agrees (others contribute 0)
         with jax.named_scope("loss"):
-            h = _rms_norm(h, params["ln_f"])
+            h = _rms_norm(h, params["ln_f"], cfg.norm_eps)
             logits = h @ params["unembed"]                # [B, T, V/tp]
             nll = _sharded_xent(logits.reshape(B * T, V_loc),
                                 labels.reshape(B * T), V_loc)
-            local_loss = nll.mean() * jnp.where(is_last, 1.0, 0.0)
+            local_loss = nll.mean()
+            if mtp_loss is not None:
+                local_loss = local_loss + cfg.mtp_weight * mtp_loss
+            local_loss = local_loss * jnp.where(is_last, 1.0, 0.0)
             # mean over dp × sp shards; sum over pp picks the last
             # stage; ep ranks hold identical copies, so psum/ep is exact
             # (and makes the per-path gradient normalization come out
@@ -542,7 +949,15 @@ def _build_loss_fn(cfg: TransformerConfig, mesh, n_micro: int):
             loss = jax.lax.psum(local_loss,
                                 (AXIS_PP, AXIS_DP, AXIS_SP, AXIS_EP)) \
                 / (mesh.shape[AXIS_DP] * sp * ep)
-        return loss
+        if stats:
+            # one value for the whole mesh: counts add over the data
+            # shards (dp, sp); the other axes hold copies
+            every = (AXIS_DP, AXIS_PP, AXIS_TP, AXIS_SP, AXIS_EP)
+            stats = jax.lax.stop_gradient({
+                k: (jax.lax.pmax(v, every) if k == "moe_load_max" else
+                    jax.lax.psum(v, every) / float(pp * tp * ep))
+                for k, v in stats.items()})
+        return loss, stats
 
     return loss_fn
 
@@ -560,13 +975,14 @@ def _build_device_step(cfg: TransformerConfig, mesh, n_micro: int,
         # carry the cross-replica reduction — the explicit KVStore-style
         # allreduce of the reference (`kvstore_local.h:173`) is folded
         # into the transpose here.
-        loss, grads = jax.value_and_grad(loss_fn)(params, tokens, labels)
+        (loss, stats), grads = jax.value_and_grad(
+            loss_fn, has_aux=True)(params, tokens, labels)
         new_params = {}
         for name, g in grads.items():
             new_params[name] = (params[name].astype(jnp.float32)
                                 - lr * g.astype(jnp.float32)).astype(
                 params[name].dtype)
-        return new_params, loss
+        return (new_params, loss) + ((stats,) if stats else ())
 
     return device_step
 
@@ -612,9 +1028,11 @@ def _build_adam_zero1_step(cfg: TransformerConfig, mesh, n_micro: int,
     b1, b2 = betas
 
     def device_step(params, opt_state, tokens, labels):
-        loss, grads = jax.value_and_grad(loss_fn)(params, tokens, labels)
+        (loss, stats), grads = jax.value_and_grad(
+            loss_fn, has_aux=True)(params, tokens, labels)
         with jax.named_scope("adam"):
-            return _adam(params, opt_state, grads, loss)
+            return _adam(params, opt_state, grads, loss) \
+                + ((stats,) if stats else ())
 
     def _adam(params, opt_state, grads, loss):
         dp_idx = lax.axis_index(AXIS_DP)
@@ -650,6 +1068,14 @@ def _build_adam_zero1_step(cfg: TransformerConfig, mesh, n_micro: int,
     return device_step
 
 
+def _check_mesh(cfg, mesh):
+    if _has_stats(cfg) and mesh.shape[AXIS_EP] > 1:
+        raise MXNetError(
+            "gated (swiglu) experts run the held experts' grouped "
+            "products on ep = 1; the exchange over ep > 1 is the "
+            "capacity-bucketed one (GELU experts), which drops")
+
+
 def _make_step_common(cfg, mesh, n_micro, lr, optimizer, betas, eps,
                       k_steps):
     """Shared plumbing for make_train_step / make_fused_train_steps:
@@ -672,6 +1098,10 @@ def _make_step_common(cfg, mesh, n_micro, lr, optimizer, betas, eps,
     if optimizer not in ("sgd", "adam"):
         raise MXNetError("optimizer must be 'sgd' or 'adam' (got %r)"
                          % (optimizer,))
+    _check_mesh(cfg, mesh)
+    # the routed experts' counters ride beside the loss where the config
+    # has them (`_has_stats`); no other program's outputs change
+    extra = ({k: P() for k in MOE_STATS},) if _has_stats(cfg) else ()
     if optimizer == "sgd":
         device_step = _build_device_step(cfg, mesh, n_micro, lr)
         if k_steps is None:
@@ -679,14 +1109,16 @@ def _make_step_common(cfg, mesh, n_micro, lr, optimizer, betas, eps,
         else:
             def device_fn(params, toks_stack, labs_stack):
                 def body(p, batch):
-                    return device_step(p, batch[0], batch[1])
+                    out = device_step(p, batch[0], batch[1])
+                    return out[0], out[1:]
 
-                return lax.scan(body, params, (toks_stack, labs_stack),
-                                length=k_steps)
+                params, per_step = lax.scan(
+                    body, params, (toks_stack, labs_stack), length=k_steps)
+                return (params,) + per_step
 
         sm = jax.shard_map(device_fn, mesh=mesh,
                            in_specs=(pspecs, data_spec, data_spec),
-                           out_specs=(pspecs, P()))
+                           out_specs=(pspecs, P()) + extra)
         return jax.jit(sm, donate_argnums=(0,)), shardings
 
     device_step = _build_adam_zero1_step(cfg, mesh, n_micro, lr,
@@ -696,21 +1128,20 @@ def _make_step_common(cfg, mesh, n_micro, lr, optimizer, betas, eps,
     else:
         def device_fn(params, opt_state, toks_stack, labs_stack):
             def body(carry, batch):
-                p, o, loss = device_step(carry[0], carry[1],
-                                         batch[0], batch[1])
-                return (p, o), loss
+                out = device_step(carry[0], carry[1], batch[0], batch[1])
+                return out[:2], out[2:]
 
-            (params, opt_state), losses = lax.scan(
+            (params, opt_state), per_step = lax.scan(
                 body, (params, opt_state), (toks_stack, labs_stack),
                 length=k_steps)
-            return params, opt_state, losses
+            return (params, opt_state) + per_step
 
     ospecs = _opt_state_specs(cfg, mesh)
     ostate_specs = {"m": dict(ospecs), "v": dict(ospecs), "t": P()}
     sm = jax.shard_map(device_fn, mesh=mesh,
                        in_specs=(pspecs, ostate_specs, data_spec,
                                  data_spec),
-                       out_specs=(pspecs, ostate_specs, P()))
+                       out_specs=(pspecs, ostate_specs, P()) + extra)
     step = jax.jit(sm, donate_argnums=(0, 1))
     shardings["opt_state"] = {
         "m": {k: NamedSharding(mesh, v) for k, v in ospecs.items()},
@@ -761,12 +1192,37 @@ def make_fused_train_steps(cfg: TransformerConfig, mesh, k_steps: int,
                              eps, k_steps=k_steps)
 
 
+def publish_moe_stats(stats) -> Dict[str, float]:
+    """Add the routed experts' counters a step (or a fused program: [K]
+    arrays) returned to `mx.profiler`'s stats: `moe_pairs` and
+    `moe_tokens` add up, `moe_load_max` is a watermark
+    (docs/observability.md).  One host read of three small arrays: call
+    it where the host may wait for the program (a sampled step, the end
+    of a window), not after every step.  Returns what it added."""
+    import jax
+    import numpy as np
+
+    from .. import profiler
+
+    host = {k: np.asarray(v) for k, v in jax.device_get(stats).items()}
+    out = {}
+    for k in MOE_STATS:
+        if k == "moe_load_max":
+            out[k] = float(host[k].max())
+            profiler.max_stat(k, int(out[k]))
+        else:
+            out[k] = float(host[k].sum())
+            profiler.inc_stat(k, int(out[k]))
+    return out
+
+
 def make_forward(cfg: TransformerConfig, mesh):
     """Jitted SPMD forward (logits) for inference/eval."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
+    _check_mesh(cfg, mesh)
     tp = mesh.shape[AXIS_TP]
     V_loc = cfg.vocab // tp
     specs = param_specs(cfg)
@@ -777,32 +1233,21 @@ def make_forward(cfg: TransformerConfig, mesh):
         sp_idx = jax.lax.axis_index(AXIS_SP)
         tp_idx = jax.lax.axis_index(AXIS_TP)
         B, T = tokens.shape
-        local_tok = tokens - tp_idx * V_loc
-        in_shard = (local_tok >= 0) & (local_tok < V_loc)
-        emb = jnp.where(in_shard[..., None],
-                        params["embed"][jnp.clip(local_tok, 0,
-                                                 V_loc - 1)], 0.0)
-        # exactly one tp shard contributes a non-zero row per token
-        # (vocab-sharded one-hot), so a native-dtype psum is exact
-        # and halves the ICI bytes vs upcasting to f32 first
-        emb = jax.lax.psum(emb, AXIS_TP)
         pos_global = sp_idx * T + jnp.arange(T)
-        x = (emb + params["pos"][pos_global][None]).astype(
-            jnp.dtype(cfg.dtype))
-        stage_params = {k: params[k][0] for k in params
-                        if params[k].ndim >= 3 and k not in
-                        ("embed", "pos", "unembed")}
+        rope = _rotary_table(cfg, pos_global) \
+            if cfg.attention == "mla" else None
+        x = _embed(cfg, params, tokens, tp_idx, V_loc, pos_global)
         pp = mesh.shape[AXIS_PP]
         state = x
         for s in range(pp):  # unrolled: stage s runs everywhere, keep
-            out = _stage_fn(cfg, stage_params, state, tp,
-                            mesh.shape[AXIS_EP])
+            out, _ = _run_stack(cfg, params, state, tp,
+                                mesh.shape[AXIS_EP], rope)
             state = jnp.where(pp_idx == s, out, state)
             if pp > 1 and s < pp - 1:
                 state = jax.lax.ppermute(
                     state, AXIS_PP,
                     [(i, (i + 1) % pp) for i in range(pp)])
-        h = _rms_norm(state, params["ln_f"])
+        h = _rms_norm(state, params["ln_f"], cfg.norm_eps)
         logits = h @ params["unembed"]
         # only the last stage holds the real output: mask + psum to
         # replicate over pp; ep ranks are identical copies so psum/ep
