@@ -76,10 +76,12 @@ class _Sizes(object):
             # (bh, T, d): the bench shape; bh=6, which once failed to
             # lower; T=640 (blocks fall to 128) at d=64; a long
             # sequence; d=256 at T=4096 (latent attention's q.k and v
-            # width: two lane tiles a head)
+            # width: two lane tiles a head); `gpt2m_fused_k8`'s own
+            # shape (d=64 at 512 x 512 blocks: the causal walk's
+            # sub-tiled diagonal at the width its claim rests on)
             self.attn_shapes = [(64, 1024, 128), (6, 1024, 128),
                                 (2, 640, 64), (8, 4096, 128),
-                                (4, 4096, 256)]
+                                (4, 4096, 256), (128, 1024, 64)]
             self.attn_ragged, self.attn_ragged_block = (2, 200, 64), 128
             self.lm = dict(vocab=8192, d_model=1024, n_heads=8,
                            n_layers=8, d_ff=4096, max_len=1024)

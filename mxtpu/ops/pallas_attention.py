@@ -18,9 +18,14 @@ path each trace took (`flash_attention_{pallas,reference}`).  The
 backward pass is a `jax.custom_vjp` with the BLOCKED recompute formulation (paper §3.1):
 scores are rebuilt block by block against the LSE the forward saved
 (the kernel emits it as a second output), in two sweeps (dq; dk/dv)
-with fully-masked causal blocks skipped — backward memory is
-O(T·d + block²) like the forward; the T×T matrix is never
-materialized in either direction.  The sweeps themselves are Pallas
+— backward memory is O(T·d + block²) like the forward; the T×T matrix
+is never materialized in either direction.  Under `causal=True` all
+three kernels walk the score matrix by block class (`_causal_walk`):
+blocks above the diagonal are skipped, blocks below it take no mask,
+and a block the diagonal crosses is cut into static 128-wide
+sub-tiles of which only those on or below the diagonal are computed;
+`profiler.stats()` counts the tiles (`flash_tiles_{total,visited,
+masked}`).  The sweeps themselves are Pallas
 kernels when shapes divide the blocks (`_flash_bwd_dq_kernel`,
 `_flash_bwd_dkv_kernel`), with equivalent jnp loops as the ragged /
 non-TPU fallback.
@@ -43,6 +48,7 @@ from .registry import register
 
 _NEG_INF = -1e30
 _LANES = 128
+_SUB = 128     # side of the causal walk's sub-tiles (see _causal_walk)
 
 
 def _vma_union(likes):
@@ -135,9 +141,9 @@ def _count_path(path):
 
 
 def _causal_mask(i, j, block_q, block_k):
-    """(block_q, block_k) mask of the (i, j) score block, one copy for
-    the forward and both backward sweeps; a 2-D iota, the form the
-    Pallas TPU guide asks for."""
+    """(block_q, block_k) mask of the (i, j) score block: the whole-block
+    form, for a block on the diagonal where `block_q != block_k`; a
+    2-D iota, the form the Pallas TPU guide asks for."""
     from jax import lax
     import jax.numpy as jnp
 
@@ -145,6 +151,130 @@ def _causal_mask(i, j, block_q, block_k):
     q_idx = lax.broadcasted_iota(jnp.int32, shape, 0) + i * block_q
     k_idx = lax.broadcasted_iota(jnp.int32, shape, 1) + j * block_k
     return q_idx >= k_idx
+
+
+def _sub_tile(block_q, block_k):
+    """(rows, columns) of the tiles the causal walk tells apart: square
+    `_SUB`-wide sub-tiles of equal blocks (the whole block where `_SUB`
+    does not divide it), whole blocks where `block_q != block_k`."""
+    if block_q == block_k and block_q % _SUB == 0:
+        return _SUB, _SUB
+    return block_q, block_k
+
+
+def _mask_corner(s, tri, axis):
+    """`s` with the ONE sub-tile the diagonal crosses masked by the
+    local lower-triangular `tri`: the last columns of a row chunk's
+    column prefix (axis=1), or the first rows of a column chunk's row
+    suffix (axis=0).  The rest of `s` lies below the diagonal and is
+    not touched."""
+    import jax.numpy as jnp
+
+    c = tri.shape[0]
+    if s.shape == tri.shape:
+        return jnp.where(tri, s, _NEG_INF)
+    if axis == 1:
+        w = s.shape[1] - c
+        return jnp.concatenate(
+            [s[:, :w], jnp.where(tri, s[:, w:], _NEG_INF)], axis=1)
+    return jnp.concatenate(
+        [jnp.where(tri, s[:c], _NEG_INF), s[c:]], axis=0)
+
+
+def _causal_walk(step, i, j, block_q, block_k, by_rows):
+    """THE causal walk, one copy for the forward and both backward
+    sweeps: which class the (i, j) block is follows from the block
+    indices and the static block shape alone, and only that class's
+    work is emitted.  `step(rows, cols, mask)` handles q rows `rows`
+    against k rows `cols` of the block (static slices); `mask` maps
+    the f32 scores to masked scores, or is None.
+
+    1. above the diagonal: skipped;
+    2. strictly below it: ONE unmasked step over the whole block (no
+       iota, no compare, no select);
+    3. crossed by it: with equal blocks, static `_SUB`-wide chunks --
+       per q row chunk one step over its k column prefix (`by_rows`:
+       forward and dq, whose state is per q row), or per k column
+       chunk one step over its q row suffix (dkv, whose state is per k
+       row); sub-tiles above the diagonal are never emitted, and the
+       one sub-tile on it takes a LOCAL triangular mask with no
+       `program_id` in it.  With unequal blocks, the whole block under
+       `_causal_mask`."""
+    from jax import lax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    full = slice(None)
+    visited = j * block_k <= (i + 1) * block_q - 1
+    below = (j + 1) * block_k - 1 <= i * block_q
+
+    @pl.when(below)
+    def _unmasked():
+        step(full, full, None)
+
+    @pl.when(jnp.logical_and(visited, jnp.logical_not(below)))
+    def _diagonal():
+        if block_q != block_k:
+            mask = _causal_mask(i, j, block_q, block_k)
+            step(full, full, lambda s: jnp.where(mask, s, _NEG_INF))
+            return
+        c = _sub_tile(block_q, block_k)[0]
+        tri = lax.broadcasted_iota(jnp.int32, (c, c), 0) \
+            >= lax.broadcasted_iota(jnp.int32, (c, c), 1)
+        for lo in range(0, block_q, c):
+            hi = lo + c
+            if by_rows:
+                step(slice(lo, hi), slice(0, hi),
+                     lambda s: _mask_corner(s, tri, 1))
+            else:
+                step(slice(lo, block_q), slice(lo, hi),
+                     lambda s: _mask_corner(s, tri, 0))
+
+
+def _count_tiles(tq, tk, block_q, block_k, causal):
+    """Per traced kernel call and per head, in tiles of `_sub_tile`:
+    how many the score matrix has (`flash_tiles_total`), how many the
+    kernels compute (`flash_tiles_visited`) and how many of those they
+    mask (`flash_tiles_masked`), so a caller can see the walk engaged."""
+    from .. import profiler as _prof
+
+    cq, ck = _sub_tile(block_q, block_k)
+    a = np.arange(-(-tq // cq))[:, None]
+    b = np.arange(tk // ck)[None, :]
+    visited = np.ones((a.size, b.size), bool)
+    masked = ~visited
+    if causal:      # the same two tests as _causal_walk's, per tile
+        visited = b * ck <= (a + 1) * cq - 1
+        masked = visited & ((b + 1) * ck - 1 > a * cq)
+    _prof.inc_stat("flash_tiles_total", int(visited.size))
+    _prof.inc_stat("flash_tiles_visited", int(visited.sum()))
+    _prof.inc_stat("flash_tiles_masked", int(masked.sum()))
+
+
+def _k_index_map(causal, block_q, block_k):
+    """Index map of a k or v tile in a k-innermost sweep (grid b, i,
+    j): block j, but a causal step above the diagonal (skipped in the
+    kernel) names the last visited block again, so no tile is fetched
+    for it."""
+    import jax.numpy as jnp
+
+    if not causal:
+        return lambda b, i, j: (b, j, 0)
+    return lambda b, i, j: (
+        b, jnp.minimum(j, ((i + 1) * block_q - 1) // block_k), 0)
+
+
+def _q_index_map(causal, block_q, block_k, nq):
+    """The same for a q-side tile in the q-innermost dkv sweep (grid
+    b, j, i): a skipped step names the first q block that sees k
+    block j."""
+    import jax.numpy as jnp
+
+    if not causal:
+        return lambda b, j, i: (b, i, 0)
+    return lambda b, j, i: (
+        b, jnp.maximum(i, jnp.minimum((j * block_k) // block_q, nq - 1)),
+        0)
 
 
 # ---------------------------------------------------------------------------
@@ -190,31 +320,30 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, sm_scale, causal,
         m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    def _step():
+    def _step(rows, cols, mask):
         # native-dtype operands on the MXU, f32 accumulate; the
         # softmax scale applies to the f32 scores (not the bf16 q,
         # which would round it into the inputs)
-        s = _dot_f32(q_ref[0], k_ref[0]) * sm_scale   # (bq, bk)
-        if causal:
-            s = jnp.where(_causal_mask(i, j, block_q, block_k), s,
-                          _NEG_INF)
-        m_prev = m_ref[:, 0:1]                        # (bq, 1)
-        l_prev = l_ref[:, 0:1]
-        m_cur = jnp.max(s, axis=1, keepdims=True)     # (bq, 1)
+        s = _dot_f32(q_ref[0, rows], k_ref[0, cols]) * sm_scale
+        if mask is not None:
+            s = mask(s)
+        m_prev = m_ref[rows, 0:1]                     # (rows, 1)
+        l_prev = l_ref[rows, 0:1]
+        m_cur = jnp.max(s, axis=1, keepdims=True)     # (rows, 1)
         m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)                        # (bq, bk)
+        p = jnp.exp(s - m_new)                        # (rows, cols)
         alpha = jnp.exp(m_prev - m_new)               # rescale old state
         l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + _dot_f32(p, v_ref[0],
-                                                   ((1,), (0,)))
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+        acc_ref[rows] = acc_ref[rows] * alpha + _dot_f32(
+            p, v_ref[0, cols], ((1,), (0,)))
+        lanes = (m_new.shape[0], m_ref.shape[1])
+        m_ref[rows] = jnp.broadcast_to(m_new, lanes)
+        l_ref[rows] = jnp.broadcast_to(l_new, lanes)
 
     if causal:
-        # skip k blocks entirely above the causal diagonal
-        pl.when(j * block_k <= (i + 1) * block_q - 1)(_step)
+        _causal_walk(_step, i, j, block_q, block_k, by_rows=True)
     else:
-        _step()
+        _step(slice(None), slice(None), None)
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _finish():
@@ -239,6 +368,7 @@ def _flash_forward_pallas(q, k, v, sm_scale, causal, block_q, block_k,
     bh, tq, d = q.shape
     tk = k.shape[1]
     grid = (bh, pl.cdiv(tq, block_q), pl.cdiv(tk, block_k))
+    kmap = _k_index_map(causal, block_q, block_k)
     kernel = functools.partial(_flash_kernel, sm_scale=sm_scale,
                                causal=causal, block_q=block_q,
                                block_k=block_k, want_lse=want_lse)
@@ -256,8 +386,8 @@ def _flash_forward_pallas(q, k, v, sm_scale, causal, block_q, block_k,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, d), kmap),
+            pl.BlockSpec((1, block_k, d), kmap),
         ],
         out_specs=tuple(out_specs),
         scratch_shapes=[
@@ -273,22 +403,23 @@ def _flash_forward_pallas(q, k, v, sm_scale, causal, block_q, block_k,
     return outs[0], None
 
 
-def _bwd_p_ds(q_ref, k_ref, v_ref, g_ref, lse_ref, dlt_ref, i, j, *,
-              sm_scale, causal, block_q, block_k):
-    """Shared backward block math: rebuild the score block against the
-    saved LSE and return (p, ds, q, k, g) — ONE copy of the masking and
-    the ds formula for both sweeps."""
+def _bwd_p_ds(q_ref, k_ref, v_ref, g_ref, lse_ref, dlt_ref, rows, cols,
+              mask, sm_scale):
+    """Shared backward math of q rows `rows` against k rows `cols` of
+    the block: rebuild the scores against the saved LSE and return
+    (p, ds, q, k, g) -- ONE copy of the ds formula for both sweeps;
+    `mask` is the causal walk's (None below the diagonal)."""
     import jax.numpy as jnp
 
-    q = q_ref[0]                       # native dtype (see _dot_f32)
-    k = k_ref[0]
-    v = v_ref[0]
-    g = g_ref[0]
-    lse = lse_ref[:]                   # (bq, 1) — bh dim is squeezed
-    dlt = dlt_ref[:]                   # by the None in its BlockSpec
+    q = q_ref[0, rows]                 # native dtype (see _dot_f32)
+    k = k_ref[0, cols]
+    v = v_ref[0, cols]
+    g = g_ref[0, rows]
+    lse = lse_ref[rows]                # (rows, 1) -- bh dim is squeezed
+    dlt = dlt_ref[rows]                # by the None in its BlockSpec
     s = _dot_f32(q, k) * sm_scale
-    if causal:
-        s = jnp.where(_causal_mask(i, j, block_q, block_k), s, _NEG_INF)
+    if mask is not None:
+        s = mask(s)
     p = jnp.exp(s - lse)
     dp = _dot_f32(g, v)
     ds = p * (dp - dlt) * sm_scale
@@ -310,17 +441,16 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, dlt_ref,
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    def _step():
+    def _step(rows, cols, mask):
         _, ds, _, k, _ = _bwd_p_ds(
-            q_ref, k_ref, v_ref, g_ref, lse_ref, dlt_ref, i, j,
-            sm_scale=sm_scale, causal=causal, block_q=block_q,
-            block_k=block_k)
-        acc_ref[:] = acc_ref[:] + _dot_f32(ds, k, ((1,), (0,)))
+            q_ref, k_ref, v_ref, g_ref, lse_ref, dlt_ref, rows, cols,
+            mask, sm_scale)
+        acc_ref[rows] = acc_ref[rows] + _dot_f32(ds, k, ((1,), (0,)))
 
     if causal:
-        pl.when(j * block_k <= (i + 1) * block_q - 1)(_step)
+        _causal_walk(_step, i, j, block_q, block_k, by_rows=True)
     else:
-        _step()
+        _step(slice(None), slice(None), None)
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _finish():
@@ -342,19 +472,18 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, dlt_ref,
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    def _step():
+    def _step(rows, cols, mask):
         p, ds, q, _, g = _bwd_p_ds(
-            q_ref, k_ref, v_ref, g_ref, lse_ref, dlt_ref, i, j,
-            sm_scale=sm_scale, causal=causal, block_q=block_q,
-            block_k=block_k)
-        dv_acc[:] = dv_acc[:] + _dot_f32(p, g, ((0,), (0,)))
-        dk_acc[:] = dk_acc[:] + _dot_f32(ds, q, ((0,), (0,)))
+            q_ref, k_ref, v_ref, g_ref, lse_ref, dlt_ref, rows, cols,
+            mask, sm_scale)
+        dv_acc[cols] = dv_acc[cols] + _dot_f32(p, g, ((0,), (0,)))
+        dk_acc[cols] = dk_acc[cols] + _dot_f32(ds, q, ((0,), (0,)))
 
     if causal:
         # q blocks strictly above this k block's diagonal see none of it
-        pl.when((i + 1) * block_q - 1 >= j * block_k)(_step)
+        _causal_walk(_step, i, j, block_q, block_k, by_rows=False)
     else:
-        _step()
+        _step(slice(None), slice(None), None)
 
     @pl.when(i == pl.num_programs(2) - 1)
     def _finish():
@@ -385,7 +514,8 @@ def _flash_backward_pallas(q, k, v, g, out, lse, sm_scale, causal,
     nk = tk // block_k
 
     qspec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
-    kspec = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0))
+    kspec = pl.BlockSpec((1, block_k, d),
+                         _k_index_map(causal, block_q, block_k))
     rspec = pl.BlockSpec((None, block_q, 1), lambda b, i, j: (b, i, 0))
 
     dq = pl.pallas_call(
@@ -402,10 +532,10 @@ def _flash_backward_pallas(q, k, v, g, out, lse, sm_scale, causal,
     )(q, k, v, g, lse3, delta3)
 
     # dkv grid: (bh, nk, nq) — q innermost; index maps swap (i, j)
-    qspec2 = pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0))
+    qmap = _q_index_map(causal, block_q, block_k, nq)
+    qspec2 = pl.BlockSpec((1, block_q, d), qmap)
     kspec2 = pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0))
-    rspec2 = pl.BlockSpec((None, block_q, 1),
-                          lambda b, j, i: (b, i, 0))
+    rspec2 = pl.BlockSpec((None, block_q, 1), qmap)
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, sm_scale=sm_scale,
                           causal=causal, block_q=block_q,
@@ -463,6 +593,7 @@ def _flash_impl(q, k, v, sm_scale, causal, block_q, block_k, want_lse):
         _count_path("reference")
         return _reference_attention_lse(q, k, v, sm_scale, causal)
     _count_path("pallas")
+    _count_tiles(tq, tk, block_q, block_k, causal)
     pq = (-tq) % block_q
     if pq:
         import jax.numpy as jnp
@@ -515,6 +646,7 @@ def _flash_bwd(sm_scale, causal, block_q, block_k, res, g):
             and _tiles(bq, bk, D):
         # kernel path (same math as the jnp sweeps below, on the MXU)
         _count_path("pallas")
+        _count_tiles(Tq, Tk, bq, bk, causal)
         return _flash_backward_pallas(q, k, v, g, out, lse_saved,
                                       sm_scale, causal, bq, bk)
     _count_path("reference")
@@ -615,10 +747,15 @@ def flash_attention(q, k, v, sm_scale=None, causal=False, block_q=512,
     q/k/v: (batch, heads, seq, head_dim) or (batch*heads, seq,
     head_dim).  Returns the same layout as the input.
 
-    Default 512x512 blocks: measured on chip (r5s3 sweep, d=128
-    bf16 causal fwd+bwd) they run 63-70 TFLOPS vs 12-14 at the old
-    128x128 — small blocks pay Mosaic per-grid-step overhead on
-    ~2 MFLOP matmuls and re-stream K/V tiles 4x as often.  Blocks
+    Default 512x512 blocks, walked by class when `causal` (see
+    `_causal_walk`).  Measured on a v5e (PERF.md section 5, PR 31;
+    bf16, causal, the kernels alone, ms a call, parent -> walk):
+    [128, 1024, 64] fwd 0.974 -> 0.796, dq 0.709 -> 0.628, dkv 0.841
+    -> 0.759; [40, 4096, 256] fwd 4.377 -> 3.977, dq 4.651 -> 4.015,
+    dkv 5.866 -> 4.433.  Half the forward's time at d=64 is its two
+    row reductions; the mask, the scale and the cast are free, and
+    the matrix unit is not what binds.  No block sweep per (T, d) is
+    on record.  Blocks
     are clamped to the sequence lengths below, so short-sequence and
     unit-test shapes are unaffected.
     """

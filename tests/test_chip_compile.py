@@ -102,6 +102,19 @@ def _lm_program_text(mesh, remat, k_steps):
     return step.lower(params, opt, data, data).compile().as_text()
 
 
+_FLASH_CALL = re.compile(
+    r"%(mx_flash_\w+?)[.\d]* = .*?\[(\d+),(\d+),(\d+)\].*custom-call\(")
+
+
+def _flash_kernels(text):
+    """The flash kernels in a compiled program's text, by name, with
+    the [bh, t, d] each returns first."""
+    kernels = {}
+    for name, bh, t, d in _FLASH_CALL.findall(text):
+        kernels.setdefault(name, set()).add((int(bh), int(t), int(d)))
+    return kernels
+
+
 _COMPUTATION = re.compile(r"^(ENTRY )?%?([\w.\-]+) \(.*\{\s*$")
 _WHILE = re.compile(r"\bwhile\(.*\bbody=%?([\w.\-]+)")
 _COPY = re.compile(r"= \w+\[([\d,]*)\][^ ]* copy\(")
@@ -152,6 +165,14 @@ def test_lm_step_keeps_layer_stacks_in_place(one_chip_mesh, monkeypatch,
     monkeypatch.setattr(pa, "_on_tpu", lambda: True)
     text = _lm_program_text(one_chip_mesh, remat, k_steps)
     assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+    # the three flash kernels, by name, at the cell's [128, 1024, 64]
+    # (16 heads x 8 sequences): Mosaic lowered the causal walk's
+    # sub-tiled diagonal at d=64 for the described chip
+    want = {(WIDTHS["n_heads"] * BATCH, SEQ,
+             WIDTHS["d_model"] // WIDTHS["n_heads"])}
+    kernels = _flash_kernels(text)
+    assert kernels == {"mx_flash_fwd": want, "mx_flash_dq": want,
+                       "mx_flash_dkv": want}, kernels
     whiles, copies = _whiles_and_stack_copies(text, WIDTHS["n_layers"])
     # the loops that run once per layer: every `while` of the per-step
     # program; in the fused one, those inside the K loop, which is the
@@ -172,8 +193,6 @@ def test_lm_step_keeps_layer_stacks_in_place(one_chip_mesh, monkeypatch,
 # fused_k4_tokens_2x4k.json), through the config the cell's driver builds
 
 _CHIP_BYTES = 16.9e9        # a v5e chip's `bytes_limit` (PERF.md, PR 21)
-_FLASH_CALL = re.compile(
-    r"%(mx_flash_\w+?)[.\d]* = .*?\[(\d+),(\d+),(\d+)\].*custom-call\(")
 
 
 def _glm_cell():
@@ -238,11 +257,9 @@ def test_glm_cell_program_fits_one_chip_and_keeps_its_kernels(
     assert need > 0.75 * _CHIP_BYTES, need
 
     text = compiled.as_text()
-    kernels = {}
-    for name, bh, t, d in _FLASH_CALL.findall(text):
-        kernels.setdefault(name, set()).add((int(bh), int(t), int(d)))
     heads = config["num_attention_heads"] * b
     want = {(heads, config["input"]["length"], config["v_head_dim"])}
+    kernels = _flash_kernels(text)
     assert kernels == {"mx_flash_fwd": want, "mx_flash_dq": want,
                        "mx_flash_dkv": want}, kernels
     assert "ragged-dot" in text, "the grouped products left Mosaic"

@@ -224,3 +224,89 @@ def test_pallas_backward_kernels_match_jnp_sweeps(causal, monkeypatch):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-4, atol=2e-5,
                                    err_msg=name)
+
+
+# (bh, tq, tk, d, block_q, block_k, causal): every class of the causal
+# walk -- skipped, unmasked, diagonal in sub-tiles -- and what stays
+# outside it
+_WALK_CASES = {
+    "skip+unmasked+subtiled": (2, 1024, 1024, 64, 512, 512, True),
+    "one_diagonal_block": (2, 512, 512, 64, 512, 512, True),
+    "blocks256_d128": (1, 1024, 1024, 128, 256, 256, True),
+    "fallback_bq>bk": (1, 1024, 1024, 64, 512, 256, True),
+    "fallback_bq<bk": (1, 1024, 1024, 64, 256, 512, True),
+    "blocks_fall_to_128": (1, 640, 640, 64, None, None, True),
+    "ragged_q_padded_rows": (1, 900, 1024, 64, 512, 512, True),
+    "not_causal": (1, 1024, 1024, 64, 512, 512, False),
+}
+
+
+@pytest.mark.parametrize("case", list(_WALK_CASES), ids=list(_WALK_CASES))
+def test_causal_walk_matches_reference(case):
+    """Forward and all three gradients against `_reference_attention`
+    over block shapes that reach every class of the causal walk."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxtpu import profiler
+    from mxtpu.ops.pallas_attention import (_reference_attention,
+                                            flash_attention)
+
+    bh, tq, tk, d, block_q, block_k, causal = _WALK_CASES[case]
+    blocks = {} if block_q is None else dict(block_q=block_q,
+                                             block_k=block_k)
+    rng = np.random.RandomState(31)
+    q = jnp.asarray(rng.normal(0, 1, (bh, tq, d)).astype(np.float32))
+    k, v = (jnp.asarray(rng.normal(0, 1, (bh, tk, d)).astype(np.float32))
+            for _ in range(2))
+    w = jnp.asarray(rng.normal(0, 1, (bh, tq, d)).astype(np.float32))
+    scale = 1.0 / np.sqrt(d)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=causal, **blocks)
+
+    def ref(q, k, v):
+        return _reference_attention(q, k, v, scale, causal)
+
+    before = dict(profiler.stats())
+    got, vjp = jax.vjp(flash, q, k, v)
+    grads = vjp(w)
+    # forward and backward took the kernels; a ragged q takes the
+    # forward kernel on padded rows and the jnp sweeps backward
+    took = {p: profiler.get_stat("flash_attention_" + p)
+            - before.get("flash_attention_" + p, 0)
+            for p in ("pallas", "reference")}
+    assert took == ({"pallas": 1, "reference": 1} if tq != tk
+                    else {"pallas": 2, "reference": 0}), took
+    gold, vjp_ref = jax.vjp(ref, q, k, v)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(gold),
+                               rtol=2e-4, atol=2e-5)
+    for a, b, name in zip(grads, vjp_ref(w), "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-3, atol=2e-4,
+                                   err_msg="d%s" % name)
+
+
+@pytest.mark.parametrize("causal,blocks,want", [
+    (True, (512, 512), (64, 36, 8)),      # the walk: 128-wide sub-tiles
+    (True, (512, 256), (8, 6, 4)),        # whole blocks: the fallback
+    (False, (512, 512), (64, 64, 0)),
+], ids=["walk", "fallback", "not_causal"])
+def test_flash_tiles_stats(causal, blocks, want):
+    """`flash_tiles_{total,visited,masked}`: what one traced call adds
+    per head at gpt2-medium's shape (T=1024, d=64).  The parent's
+    kernels visited and masked 48 of 64."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxtpu import profiler
+    from mxtpu.ops.pallas_attention import flash_attention
+
+    names = ["flash_tiles_" + n for n in ("total", "visited", "masked")]
+    x = jax.ShapeDtypeStruct((2, 1024, 64), jnp.float32)
+    before = [profiler.get_stat(n) for n in names]
+    jax.eval_shape(lambda q, k, v: flash_attention(
+        q, k, v, causal=causal, block_q=blocks[0], block_k=blocks[1]),
+        x, x, x)
+    got = tuple(profiler.get_stat(n) - b for n, b in zip(names, before))
+    assert got == want
