@@ -55,9 +55,7 @@ def main():
     ap.add_argument("--remat", default="none",
                     choices=("none", "dots", "dots_no_batch", "full"),
                     help="per-layer gradient checkpointing; 'full' is "
-                         "what makes very long sequences fit one chip "
-                         "(sweep: benchmark/python/"
-                         "bench_long_context.py)")
+                         "what makes very long sequences fit one chip")
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
 

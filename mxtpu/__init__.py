@@ -84,7 +84,6 @@ from . import health
 from . import perf
 from . import xprof
 from . import hbm
-from . import tune
 from . import resilience
 from . import checkpoint
 from . import monitor

@@ -31,10 +31,9 @@ Three layers on top of the PR-2 atomic CRC-manifest machinery
 
 The per-role snapshot bundles the FULL run state: RNG stream
 (`mx.random.get_state`), DataLoader/sampler position (epoch, batch
-index, shuffle seed — `DataLoader.state()`), trainer step count, and
-the applied `mx.tune` knob provenance, so a resumed run is trajectory-
-identical to the uninterrupted one (`tools/check_checkpoint.py`
-enforces 1e-5).
+index, shuffle seed — `DataLoader.state()`) and trainer step count, so
+a resumed run is trajectory-identical to the uninterrupted one
+(`tools/check_checkpoint.py` enforces 1e-5).
 """
 from __future__ import annotations
 
@@ -123,14 +122,13 @@ def _fleet_timeout() -> float:
 
 
 # ---------------------------------------------------------------------------
-# full-run state (RNG / DataLoader position / tune provenance)
+# full-run state (RNG / DataLoader position)
 # ---------------------------------------------------------------------------
 
 def collect_run_state(loaders=None, extra: Optional[Dict] = None) -> Dict:
     """JSON-able bundle of everything outside params/optimizer that a
-    deterministic resume needs: the threefry RNG chain, each named
-    DataLoader's (epoch, batch, seed) position, and the applied
-    `mx.tune` knob provenance."""
+    deterministic resume needs: the threefry RNG chain and each named
+    DataLoader's (epoch, batch, seed) position."""
     from . import random as _rnd
 
     key = _rnd.get_state()
@@ -138,14 +136,7 @@ def collect_run_state(loaders=None, extra: Optional[Dict] = None) -> Dict:
         "rng": None if key is None
         else np.asarray(key).astype(np.uint32).tolist(),
         "loaders": {},
-        "tune": None,
     }
-    try:
-        from . import tune as _tune
-
-        state["tune"] = _tune.current_applied()
-    except Exception:
-        pass
     for name, ld in dict(loaders or {}).items():
         if callable(getattr(ld, "state", None)):
             state["loaders"][str(name)] = ld.state()

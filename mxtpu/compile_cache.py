@@ -46,7 +46,6 @@ __all__ = [
     "configure_persistent_cache",
     "persistent_cache_dir",
     "persistent_cache_bypassed",
-    "graph_fingerprint",
     "set_bucket_policy",
     "get_bucket_policy",
     "bucket_batch",
@@ -201,37 +200,6 @@ def _patch_atomic_cache_writes() -> None:
 
     put._mxtpu_atomic = True
     cls.put = put
-
-
-# ---------------------------------------------------------------------------
-# Graph identity
-# ---------------------------------------------------------------------------
-
-def graph_fingerprint(symbol) -> str:
-    """Stable, NAME-INDEPENDENT identity of a symbolic graph.
-
-    sha256 over a canonical serialization of the graph's structure:
-    per-node op kind, sorted attr items, input topology (node index +
-    output slot) and aux flag, plus the head list.  Node *names* are
-    deliberately excluded — gluon auto-uniquifies block prefixes per
-    process (``dense0`` here is ``dense3`` there), and the tuning DB
-    (`mx.tune`) keys entries on this fingerprint precisely so two
-    processes binding the same architecture agree on the key.
-    """
-    import hashlib
-    import json as _json
-
-    data = _json.loads(symbol.tojson())
-    canon = {
-        "nodes": [
-            [n["op"], sorted(n.get("attrs", {}).items()),
-             n.get("inputs", []), bool(n.get("is_aux", False))]
-            for n in data["nodes"]
-        ],
-        "heads": data.get("heads", []),
-    }
-    blob = _json.dumps(canon, sort_keys=True, separators=(",", ":"))
-    return hashlib.sha256(blob.encode("utf-8")).hexdigest()
 
 
 # ---------------------------------------------------------------------------

@@ -67,7 +67,6 @@ class FusedTrainLoop(object):
         import jax
 
         if steps_per_program is None:
-            # MXTPU_STEPS_PER_PROGRAM: the `mx.tune` registered knob —
             # an explicit constructor arg always wins over the env
             steps_per_program = int(
                 os.environ.get("MXTPU_STEPS_PER_PROGRAM", "8") or 8)

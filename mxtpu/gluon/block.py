@@ -380,19 +380,6 @@ class HybridBlock(Block):
         out_sym, out_fmt, in_fmt = self._trace_symbol(*args)
         self._out_fmt = out_fmt
         self._in_fmt = in_fmt
-        # mx.tune: with MXTPU_TUNE=apply, install this graph's
-        # persisted tuning config BEFORE the CachedOp builds, so the
-        # knobs (passes subset, buckets, donation, ...) shape the
-        # traced programs.  One bool check when off (the default).
-        from .. import tune as _tune
-
-        if _tune.apply_enabled():
-            _tune.maybe_apply(
-                symbol=out_sym,
-                profile=_tune.profile_of_shapes(
-                    [("data%d" % i, a.shape) for i, a in enumerate(args)
-                     if hasattr(a, "shape")]),
-                site="hybridize")
         # "program_name" keys the mx.inspect registry record by THIS
         # block, so retraces across cache rebuilds stay one program
         self._cached_op = CachedOp(

@@ -3,10 +3,10 @@
 
 Gates are computed by a convolution over the input plus a convolution
 over the hidden state (h2h kernels must be odd so SAME padding keeps
-the spatial shape).  NCHW-family layouts only (`NCW`/`NCHW`/`NCDHW`) —
-the TPU build runs conv internals channels-last regardless via
-MXTPU_CONV_LAYOUT, so the API layout adds nothing here (documented
-scope cut vs the reference's conv_layout parameter).
+the spatial shape).  NCHW-family layouts only (`NCW`/`NCHW`/`NCDHW`):
+XLA picks the convolution's internal layout itself, so the API layout
+adds nothing here (documented scope cut vs the reference's conv_layout
+parameter).
 """
 from __future__ import annotations
 

@@ -240,7 +240,7 @@ class DataLoader(object):
         self._pos_epoch = epoch
         self._pos_batch = skip
         it = self._iter_impl(skip)
-        # MXTPU_PREFETCH_DEVICE=N (an `mx.tune` registered knob):
+        # MXTPU_PREFETCH_DEVICE=N:
         # a lookahead thread pulls the NEXT batch and completes its
         # host->device transfer while the consumer computes on the
         # current one, so the input_wait gauge below measures only

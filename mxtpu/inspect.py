@@ -375,11 +375,6 @@ class ProgramRecord(object):
         # registration), e.g. "zero1:n=4,axis=dp" — rides every
         # telemetry ``compile`` event as the ``sharding`` field
         self.sharding: Optional[str] = None
-        # tuning provenance (mx.tune): the auto-applied tuning-DB
-        # config this program was built under, e.g.
-        # "tune:key=ab12cd34,donate=0,passes=default" — set by
-        # program() when `MXTPU_TUNE=apply` resolved a DB entry
-        self.tuning: Optional[str] = None
         # latest measured per-op attribution (mx.xprof, compact form:
         # totals + per-class rollup + top sinks) — set by
         # xprof.attach() whenever this program is profiled
@@ -455,8 +450,7 @@ class ProgramRecord(object):
         ev = _tel.record("compile", site=site, step=_tel.current_step(),
                          program=self.name, variant=kind, flops=0.0,
                          peak_bytes=0, compile_s=0.0, blame=blame,
-                         passes=pass_prov, sharding=self.sharding,
-                         tuning=self.tuning)
+                         passes=pass_prov, sharding=self.sharding)
         if not _ENABLED:
             return None
         _prof.inc_stat("inspect_compiles")
@@ -533,8 +527,6 @@ class ProgramRecord(object):
             d["passes"] = _passes.provenance_summary(self.pass_report)
         if self.sharding is not None:
             d["sharding"] = self.sharding
-        if self.tuning is not None:
-            d["tuning"] = self.tuning
         if self.op_profile is not None:
             d["op_profile"] = self.op_profile
         if analyze and sig_infos:
@@ -644,18 +636,6 @@ def program(site: str, name: str,
                 plan = _cur_plan()
                 if plan is not None:
                     rec.sharding = plan.describe()
-    except Exception:
-        pass
-    # tuning provenance: the auto-applied `mx.tune` DB config active
-    # in this process (knobs are process-global env, so every program
-    # registered after the apply was built under it)
-    try:
-        if rec.tuning is None:
-            from . import tune as _tune
-
-            prov = _tune.current_applied()
-            if prov is not None:
-                rec.tuning = prov
     except Exception:
         pass
     return rec
@@ -1030,8 +1010,6 @@ def report(name_or_record=None, kind: Optional[str] = None) -> Dict[str, Any]:
         out["blame"] = blames
     if rec.pass_report is not None:
         out["pass_report"] = rec.pass_report
-    if rec.tuning is not None:
-        out["tuning"] = rec.tuning
     if rec.op_profile is not None:
         out["op_profile"] = rec.op_profile
     try:

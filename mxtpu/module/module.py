@@ -222,17 +222,6 @@ class Module(BaseModule):
         self.inputs_need_grad = inputs_need_grad
         self.binded = True
 
-        # mx.tune: with MXTPU_TUNE=apply, a persisted tuning config for
-        # this graph (+ backend + batch profile) installs BEFORE the
-        # executor group builds, so the knobs shape this bind's
-        # programs.  Off (default) this is one bool check.
-        from .. import tune as _tune
-
-        if _tune.apply_enabled():
-            _tune.maybe_apply(symbol=self._symbol,
-                              profile=_tune.profile_of_shapes(data_shapes),
-                              site="module.bind")
-
         shared_group = None
         if shared_module is not None:
             if not (shared_module.binded and

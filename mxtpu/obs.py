@@ -4,8 +4,7 @@ Every observability layer before this one is instant-or-post-hoc:
 `mxtpu/telemetry.py` gauges report the LAST value, chrome traces and
 ``cluster.json`` exist only after ``merge_dir`` runs at exit, and
 nothing survives across runs.  This module adds the time axis and the
-scrape surface a production fleet (and the future `mx.tune` autotuner,
-which searches over *measured trials*) needs.  Four pieces:
+scrape surface a production fleet needs.  Four pieces:
 
   * **Sampler** — a per-role background thread
     (``MXTPU_OBS_SAMPLE_S``, default 5s; ``MXTPU_OBS=0`` opts out)
@@ -41,13 +40,11 @@ which searches over *measured trials*) needs.  Four pieces:
     terminal dashboard with sparklines.
 
   * **Run ledger** — with ``MXTPU_RUN_DIR`` set, every sample row plus
-    one final summary row (bench-row schema keys from
-    `benchmark/python/bench_common.py`, knobs = the ``MXTPU_*`` env)
-    appends to ``MXTPU_RUN_DIR/<run_id>.jsonl``; ``MXTPU_RUN_ID`` (set
-    for the whole fleet by ``tools/launch.py``) makes one run = one
-    file.  ``tools/compare_runs.py`` diffs two runs into a
-    knob/metric delta report — the trial-history substrate `mx.tune`
-    will search.
+    one final summary row (headline metrics, knobs = the ``MXTPU_*``
+    env) appends to ``MXTPU_RUN_DIR/<run_id>.jsonl``; ``MXTPU_RUN_ID``
+    (set for the whole fleet by ``tools/launch.py``) makes one run =
+    one file.  ``tools/compare_runs.py`` diffs two runs into a
+    knob/metric delta report.
 
 Cost discipline: disabled (``MXTPU_OBS=0``) means no thread, no
 socket, no file; enabled, a sample is a handful of dict reads
@@ -742,8 +739,7 @@ def ledger_append(row: Dict[str, Any]) -> Optional[str]:
 
 
 def summary_row() -> Dict[str, Any]:
-    """The run's FINAL ledger row: one bench-schema record (the
-    ``mxtpu-bench-v1`` keys from `benchmark/python/bench_common.py`)
+    """The run's FINAL ledger row: one ``mxtpu-bench-v1`` record
     holding the headline throughput/step-time/MFU/phases, the full
     ``MXTPU_*`` knob environment, and the final counter snapshot the
     sample rows reconcile against."""

@@ -214,11 +214,6 @@ def invoke_jax(opdef: OpDef, jax_inputs: Sequence, attrs: Dict[str, Any], rng_ke
     return out
 
 
-def clear_executable_cache():
-    """Drop all cached jitted callables (test hook)."""
-    _jitted.cache_clear()
-
-
 def index_dtype():
     """Widest integer dtype actually available for emitted indices:
     int64 only under jax_enable_x64 (otherwise JAX truncates with a

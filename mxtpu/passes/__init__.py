@@ -6,18 +6,15 @@ and TVM's fusion/layout playbook — arXiv 1802.04799).  Every compile
 path (Executor bind, CachedOp, FusedTrainLoop, control-flow subgraph
 lowering) funnels through ``executor._build_graph_fn``, which calls
 :func:`optimize_for_build` here, so graph-level decisions — folding,
-fusion grouping, layout — are composable passes instead of call-site
-hacks.
+fusion grouping — are composable passes instead of call-site hacks.
 
 Built-in passes, in canonical execution order:
 
   ``dce``    identity elimination + reachability liveness
   ``fold``   constant folding (initializer-only subgraphs evaluated
              once at bind; ``MXTPU_FOLD_MAX_BYTES`` caps embeds)
-  ``layout`` NHWC propagation over the conv stack (inert unless
-             ``MXTPU_LAYOUT=nhwc`` or explicitly listed)
   ``cse``    common-subexpression elimination (value-keyed for folded
-             constants; dedupes layout's sibling-branch transposes)
+             constants)
   ``fuse``   elementwise-chain fusion grouping (one node, one
              named_scope, one `mx.inspect` layer per chain)
 
@@ -32,10 +29,9 @@ Spelling order never matters: the manager always executes in canonical
 order.  :func:`scope` overrides the spec for a ``with`` block (tests,
 A/B comparisons); `Symbol.optimize` applies a one-off spec.
 
-Every pass is OUTPUT-IDENTICAL — bitwise for dce/fold/cse/fuse
-(including RNG-consuming graphs: ``ensure_rng_ids`` pins a stable
-per-node fold_in id so rewrites cannot reseed dropout), float-tolerant
-for layout (reduction reassociation) — enforced in tier-1 by
+Every pass is OUTPUT-IDENTICAL, bitwise (including RNG-consuming
+graphs: ``ensure_rng_ids`` pins a stable per-node fold_in id so
+rewrites cannot reseed dropout) — enforced in tier-1 by
 ``tools/check_passes.py``.  Optimized graphs are cached per (graph
 identity, spec); provenance reports ride on `mx.inspect` program
 records and telemetry ``compile`` events, and per-pass timings land in
@@ -58,27 +54,23 @@ from .graph import (clone_graph, consumer_map, ensure_rng_ids,
 from .dce_cse import CSEPass, DeadNodePass
 from .fold import ConstantFoldPass
 from .fuse import ElemwiseFusionPass, FUSABLE_OPS
-from .layout import LayoutPass, layout_requested
 from .sharding import ShardingPass, shard_requested
 
 __all__ = [
     "GraphPass", "PassManager", "register_pass", "pass_names",
     "DeadNodePass", "CSEPass", "ConstantFoldPass", "ElemwiseFusionPass",
-    "LayoutPass", "ShardingPass", "optimize", "optimize_for_build",
+    "ShardingPass", "optimize", "optimize_for_build",
     "provenance_for", "provenance_summary", "ensure_rng_ids",
     "rng_id_of", "scope", "current_spec", "FUSABLE_OPS",
 ]
 
 # canonical order is registration order (see core.PassManager doc).
-# layout runs BEFORE cse so the entry transposes it inserts on sibling
-# branches (residual blocks transpose the same tensor twice) dedupe.
 # shard runs LAST (annotation-only): its specs must land on the
 # variables that SURVIVE dce/fold/cse and sit under the final fused
 # graph — and it must never give the rewriting passes annotated nodes
 # they'd have to preserve.
 register_pass("dce", DeadNodePass)
 register_pass("fold", ConstantFoldPass)
-register_pass("layout", LayoutPass)
 register_pass("cse", CSEPass)
 register_pass("fuse", ElemwiseFusionPass)
 register_pass("shard", ShardingPass)
@@ -96,14 +88,7 @@ _OPT_CACHE: "collections.OrderedDict[Tuple, Dict[str, Any]]" = \
 # ---------------------------------------------------------------------------
 
 def _default_names() -> List[str]:
-    out = []
-    for n in pass_names():
-        if n == "layout" and not layout_requested():
-            continue
-        if n == "shard" and not shard_requested():
-            continue
-        out.append(n)
-    return out
+    return [n for n in pass_names() if n != "shard" or shard_requested()]
 
 
 def parse_spec(spec: Union[None, str, Sequence[str]]) -> Tuple[str, ...]:
@@ -143,13 +128,13 @@ _SPEC_MEMO: Dict[Tuple, Tuple[str, ...]] = {}
 def current_spec() -> Tuple[str, ...]:
     """The active pass set: a :func:`scope` override if one is live,
     else ``MXTPU_PASSES`` (re-read per call — flip it between binds).
-    Parses are memoized by (raw string, layout request) — this runs on
+    Parses are memoized by (raw string, shard request) — this runs on
     every graph build."""
     ov = getattr(_local, "spec", None)
     if ov is not None:
         return ov
     raw = getenv("MXTPU_PASSES") or "default"
-    memo_key = (raw, layout_requested(), shard_requested())
+    memo_key = (raw, shard_requested())
     spec = _SPEC_MEMO.get(memo_key)
     if spec is None:
         spec = parse_spec(raw)

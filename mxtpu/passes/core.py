@@ -2,20 +2,16 @@
 
 A pass is a named graph-to-graph rewrite over the SymbolNode DAG that
 must be OUTPUT-IDENTICAL: for any inputs (and RNG key), the rewritten
-graph produces the same outputs as the original — bitwise for the
-default passes (dce/fold/cse/fuse, which never change the op sequence
-applied to any value), within float tolerance for layout (permuting a
-reduction's iteration order may legally reassociate sums).  The parity
+graph produces the same outputs as the original, bitwise (dce/fold/
+cse/fuse never change the op sequence applied to any value).  The parity
 contract is enforced by ``tools/check_passes.py`` (tier-1) across all
 three dispatch paths.
 
 The manager owns ordering: passes always execute in the canonical
-order (``dce, fold, layout, cse, fuse``) regardless of how the enabled
+order (``dce, fold, cse, fuse``) regardless of how the enabled
 set was spelled, because the phases feed each other — identity
 elimination exposes constants, folding creates value-keyed CSE
-opportunities, CSE dedupes layout's sibling-branch transposes and
-lengthens single-consumer chains, and layout must see raw elementwise
-ops before fusion makes them opaque.  Per-pass
+opportunities, and CSE lengthens single-consumer chains.  Per-pass
 wall time and node deltas land in ``profiler.stats()`` as
 ``pass_runs::<name>`` / ``pass_wall_us::<name>`` /
 ``pass_nodes_removed::<name>``.

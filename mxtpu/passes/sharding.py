@@ -16,8 +16,8 @@ with dce/fold/cse/fuse in any spelled order (canonical order places it
 LAST, after fusion, so annotations land on the surviving variables of
 the final graph).
 
-Like ``layout``, it joins the default pass set only when requested —
-here, when a `ShardingPlan` is active (`mx.shard.current_plan()`).
+It joins the default pass set only when requested: when a
+`ShardingPlan` is active (`mx.shard.current_plan()`).
 """
 from __future__ import annotations
 

@@ -3,9 +3,7 @@
 `mxtpu/telemetry.py` answers "how fast is each rank stepping",
 `mxtpu/inspect.py` answers "what did XLA build" — this module joins
 the two so "img/s went down" becomes "which PHASE of which PROGRAM on
-which rank ate the time" (the measurement substrate ROADMAP items 1-2
-consume; arXiv 1802.04799's premise that optimization is search over
-*measurements*).  Three pieces:
+which rank ate the time".  Two pieces:
 
   * **Step-phase decomposition** — every dispatch path (Executor
     ``_jit_*``, CachedOp, FusedTrainLoop, the `mx.serve` batcher)
@@ -47,18 +45,11 @@ consume; arXiv 1802.04799's premise that optimization is search over
     and rolled up per rank in ``launch.py --telemetry-dir``'s
     cluster.json (per-rank MFU spread = straggler signal).
 
-  * **Perf-regression ratchet** — `tools/check_perf.py` runs two
-    tier-1-sized micro-benches through the shared structured-result
-    runner (`benchmark/python/bench_common.py`) and fails on a >25%
-    step-time regression vs the on-disk baseline
-    (``benchmark/baselines/<backend>.json``) while asserting the
-    always-on hook here costs <10us/step.
-
 Cost discipline: the unsampled per-call path is two
 ``time.perf_counter`` reads, one small locked dict update, one gauge
-store and one histogram bump — measured ~3us, asserted <10us by
-``tools/check_perf.py``.  ``MXTPU_PERF=0`` turns every hook into one
-bool check.  MFU figures in :func:`metrics_block` use only analysis
+store and one histogram bump; what the hooks cost the fused loop is
+read on the chip (``observer_ms_per_step.fused``, PERF.md §5).
+``MXTPU_PERF=0`` turns every hook into one bool check.  MFU figures in :func:`metrics_block` use only analysis
 the inspect registry has ALREADY cached (a heartbeat must never
 trigger an XLA compile); :func:`report` forces the analysis.
 
@@ -105,7 +96,7 @@ _ENABLED = getenv_bool("MXTPU_PERF", True)
 
 #: THE peak table: published per-chip peaks keyed by the
 #: ``device_kind`` JAX reports.  Every MFU / roofline figure in the
-#: tree (`mx.perf`, `mx.xprof`, `tools/hlo_report.py`, `bench.py`)
+#: tree (`mx.perf`, `mx.xprof`, `tools/hlo_report.py`)
 #: reads this one table; an accelerator that is not in it is an error
 #: (:func:`device_peaks`), never a default.
 DEVICE_PEAKS = {

@@ -46,8 +46,7 @@ under live traffic.  Four layers, smallest first:
 SLO surface: per-model request-latency histograms
 (`telemetry.Histogram`, p50/p95/p99) plus queue-depth / in-flight /
 batch-occupancy gauges, all visible in ``mx.telemetry.metrics()``
-under ``"serve"`` and ``"histograms"`` — the same numbers
-``benchmark/python/bench_serving.py`` reports throughput against.
+under ``"serve"`` and ``"histograms"``.
 
 See `docs/serving.md` for the architecture and the chaos workflow.
 """
@@ -236,11 +235,6 @@ class Server(object):
         self.queue_cap = queue_cap or _queue_cap_default()
         self.batch_wait_s = _batch_wait_default() \
             if batch_wait_s is None else float(batch_wait_s)
-        # env-defaulted values may be re-resolved after an `mx.tune`
-        # auto-apply in add_model; EXPLICIT constructor args win over
-        # any tuned config
-        self._batch_wait_explicit = batch_wait_s is not None
-        self._max_batch_explicit = max_batch is not None
         self.request_timeout_s = _timeout_default() \
             if request_timeout_s is None else float(request_timeout_s)
         self.bucket_spec = bucket_spec or _cc.get_bucket_policy() or "pow2"
@@ -279,21 +273,6 @@ class Server(object):
         while running (multi-tenant hosting adds models live)."""
         if self._stopped:
             raise MXNetError("server is stopped")
-        # mx.tune: with MXTPU_TUNE=apply, a persisted serve config for
-        # this model name installs its knobs (batch wait, bucket cap)
-        # before the entry is built and warmed.  Explicit constructor
-        # args always win over the tuned env defaults.
-        from . import tune as _tune
-
-        if _tune.apply_enabled():
-            applied = _tune.maybe_apply(name=name,
-                                        profile="serve",
-                                        site="serve.add_model")
-            if applied is not None:
-                if not self._batch_wait_explicit:
-                    self.batch_wait_s = _batch_wait_default()
-                if not self._max_batch_explicit:
-                    self.max_batch = _max_batch_default()
         cap = int(max_batch or self.max_batch)
         predict = self._as_predict(model, dtype)
         entry = _ModelEntry(name, predict, dtype,

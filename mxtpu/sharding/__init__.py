@@ -20,9 +20,8 @@ Trainer/Module, a `with plan.activate():` scope, or ``MXTPU_SHARD=zero1``
   * :func:`reshard` moves params/state between two plans' layouts
     (train<->serve, arXiv 2112.01075) in one device_put per leaf.
 
-See `docs/sharding.md` for the workflow, `tools/check_sharding.py`
-(tier-1) for the parity + memory contract, and
-`benchmark/python/bench_sharding.py` for the scaling seed.
+See `docs/sharding.md` for the workflow and `tools/check_sharding.py`
+(tier-1) for the parity + memory contract.
 """
 from __future__ import annotations
 

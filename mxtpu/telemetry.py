@@ -127,11 +127,6 @@ __all__ = [
 #: ids + name + ``dur_s``, ts = the span's END like ``step`` records;
 #: :func:`merge_dir` renders them as X spans and has
 #: ``tracing.stitch`` join cross-process traces with flow events.)
-#: (``tuning`` = one `mx.tune` lifecycle point: a measured trial
-#: (``action="trial"``, trial id + score + config), a finished search
-#: session (``action="session"``), or a DB config auto-applied at
-#: bind/hybridize/add_model (``action="apply"``, with the same
-#: provenance string `mx.inspect` stamps on program records).)
 #: (``op_profile`` = one `mx.xprof` per-op attribution attached to a
 #: program: acquisition source (xplane/replay), op count, per-step
 #: device time, per-op-class rollup and the top sink's name/class/
@@ -140,7 +135,7 @@ __all__ = [
 EVENT_KINDS = ("step", "compile", "kvstore", "kvstore_round", "retry",
                "failover", "membership", "checkpoint", "monitor",
                "timeout", "flight", "anomaly", "tensor_stats", "serve",
-               "reshard", "perf", "span", "tuning", "resume",
+               "reshard", "perf", "span", "resume",
                "op_profile")
 
 #: ``profiler.stats()`` keys that are point-in-time gauges, not
@@ -458,10 +453,7 @@ class Histogram(object):
     is "is p99 under 200ms", not "is p99 198.3ms or 198.4ms".
 
     This is the serving SLO primitive: `mx.serve` keeps one per model
-    for request latency (p50/p95/p99 surfaced via :func:`metrics`),
-    and ``benchmark/python/bench_serving.py``'s closed-loop clients
-    feed the same class, so server-side and client-side latency
-    distributions are directly comparable.
+    for request latency (p50/p95/p99 surfaced via :func:`metrics`).
 
     Use the module-level :func:`histogram` get-or-create registry to
     have a histogram's :meth:`snapshot` ride along in

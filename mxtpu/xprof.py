@@ -33,9 +33,7 @@ device-idle gaps, and a top-K-sinks report (:func:`report`,
 
 Consumers: `mx.inspect` program records grow an ``op_profile`` field,
 telemetry gets an ``op_profile`` event kind (cluster.json /
-``tools/dash.py`` name each rank's top sink), `mx.tune` search priors
-accept measured per-op times (`tune.search.cost_model_priors`), and
-`bench_common` rows can carry the breakdown.
+``tools/dash.py`` name each rank's top sink).
 
 Env: ``MXTPU_XPROF`` (default 1) gates everything — disabled, every
 entry point is one bool check; ``MXTPU_XPROF_EVERY=N`` auto-profiles
@@ -953,8 +951,8 @@ def top_sink() -> Optional[Dict[str, Any]]:
 
 def bench_breakdown(prof: Optional[Dict[str, Any]] = None,
                     k: int = 5) -> Optional[Dict[str, Any]]:
-    """The compact breakdown `bench_common` rows carry under
-    ``--profile``: per-op-class us + top-k sinks (ledger-diffable by
+    """The compact breakdown the run ledger's summary row carries:
+    per-op-class us + top-k sinks (diffable by
     ``tools/compare_runs.py``)."""
     prof = prof or last()
     if not prof:

@@ -281,6 +281,39 @@ def test_trainer_boundary_checkpoint_and_bitwise_resume(tmp_path):
         np.testing.assert_array_equal(pa[k], pb[k], err_msg=k)
 
 
+def test_bundle_with_the_parents_tune_key_restores(tmp_path, monkeypatch):
+    """Bundles written before the autotuner was removed carry its
+    provenance under ``run_state["tune"]``; the reader has nothing to
+    do with the key and must restore such a bundle like any other."""
+    collect = ck.collect_run_state
+
+    def as_the_parent_wrote_it(loaders=None, extra=None):
+        state = collect(loaders, extra)
+        state["tune"] = "tune:key=ab12cd34,donate=0,passes=default"
+        return state
+
+    monkeypatch.setattr(ck, "collect_run_state", as_the_parent_wrote_it)
+    batches = _batches(3)
+    net, tr, _ = _make_net_trainer(init_seed=7)
+    fc = ck.FleetCheckpointer(trainer=tr, directory=str(tmp_path))
+    _train_steps(net, tr, batches[:2])
+    assert fc.checkpoint(2, wait=True) and fc.flush(timeout=10)
+    _train_steps(net, tr, batches[2:])
+    found = ck.find_resume(str(tmp_path))
+    assert found is not None
+    _, _, man = ck.load_worker_bundle(found[0], 0, epoch=2)
+    assert man["bundle"]["run_state"]["tune"].startswith("tune:key=")
+
+    # the restore puts the RNG chain (the dropout masks) back too
+    net2, tr2, _ = _make_net_trainer(init_seed=99)
+    meta = ck.restore_worker(trainer=tr2, directory=found[0])
+    assert meta["step"] == 2 and tr2.step_count == 2
+    _train_steps(net2, tr2, batches[2:])
+    pa, pb = _params_np(tr), _params_np(tr2)
+    for k in pa:
+        np.testing.assert_array_equal(pa[k], pb[k], err_msg=k)
+
+
 def test_zero1_fleet_bundle_reshards_n_to_m(tmp_path):
     """A fleet bundle written by a 2-replica ZeRO-1 trainer restores
     into a 4-replica one through the SAME fleet-manifest path (the

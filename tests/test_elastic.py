@@ -129,6 +129,7 @@ def test_reregister_after_scheduler_restart(monkeypatch):
     monkeypatch.setenv("MXTPU_SCHED_RECONNECT", "20")
     monkeypatch.setenv("MXTPU_RETRY_BASE", "0.05")
     sched1, _ = _start_scheduler(monkeypatch, nw=1, ns=0, dead="30")
+    reregistered = profiler.get_stat("elastic_sched_reregister")
     worker = _ps.Worker()
     assert worker.node_id in sched1._last_beat
     port = sched1._port
@@ -145,10 +146,15 @@ def test_reregister_after_scheduler_restart(monkeypatch):
     sched2 = _ps.Scheduler(port=port)  # restarted on the same address
     threading.Thread(target=sched2.run, daemon=True).start()
 
+    # the scheduler's tables fill while it serves the request; the
+    # heartbeat thread counts the re-registration once the reply is
+    # back, so wait for both
     deadline = time.time() + 15
     while time.time() < deadline:
         if worker.node_id in sched2._last_beat and \
-                worker.node_id in sched2._worker_order:
+                worker.node_id in sched2._worker_order and \
+                profiler.get_stat("elastic_sched_reregister") > \
+                reregistered:
             break
         time.sleep(0.1)
     else:
@@ -156,7 +162,6 @@ def test_reregister_after_scheduler_restart(monkeypatch):
                     "scheduler")
     # rank preserved across the restart
     assert sched2._rank_of(worker.node_id) == worker.rank == 0
-    assert profiler.get_stat("elastic_sched_reregister") >= 1
     worker.close()
     sched2._die()
 

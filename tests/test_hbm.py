@@ -3,8 +3,7 @@ decode on all three dispatch paths (Executor / CachedOp /
 FusedTrainLoop) including donation-aliasing, the live census + planted
 leak detector, headroom/capacity planning, and the consumer wiring
 (telemetry metrics block, obs sample/OpenMetrics, health OOM
-forensics, cluster rollup, dash cell, bench rows, compare_runs
-shifts, ZeRO-1 measured freed bytes).
+forensics, cluster rollup, dash cell, ZeRO-1 measured freed bytes).
 """
 import json
 import os
@@ -326,34 +325,6 @@ def test_dash_renders_hbm_cell():
     assert "hbm(u/free)" in frame
     assert "1.0M/1.0G" in frame
     assert "LEAK suspects: worker3" in frame
-
-
-def test_bench_row_carries_hbm_keys():
-    sys.path.insert(0, os.path.join(REPO, "benchmark", "python"))
-    import bench_common
-
-    ex = _executor(train=True)
-    hbm.plan(ex._insp)
-    r = bench_common.row("b", "m", 1.0, "x")
-    assert r["peak_hbm_bytes"] > 0
-    assert r["hbm_plan"]["classes"]["params"] > 0
-
-
-def test_compare_runs_hbm_shifts():
-    import compare_runs
-
-    a = {"peak_hbm_bytes": 1000,
-         "hbm_plan": {"classes": {"params": 400, "grads": 100,
-                                  "activations_temps": 500}}}
-    b = {"peak_hbm_bytes": 2000,
-         "hbm_plan": {"classes": {"params": 400, "grads": 100,
-                                  "activations_temps": 1500}}}
-    rows, pa, pb = compare_runs.hbm_shifts(a, b)
-    assert (pa, pb) == (1000, 2000)
-    # biggest mover first: the activation growth is the headline
-    assert rows[0][0] == "activations_temps"
-    assert rows[0][1] == 500 and rows[0][2] == 1500
-    assert compare_runs.hbm_shifts(a, {}) is None
 
 
 def test_zero1_measured_freed_bytes():

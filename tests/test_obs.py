@@ -293,7 +293,7 @@ def test_compare_runs_reports_knob_and_metric_deltas(tmp_path):
 
     a = mk("ra", {"MXTPU_PASSES": "default"}, 1000.0, 900.0,
            {"host_dispatch": 120.0})
-    b = mk("rb", {"MXTPU_PASSES": "off", "MXTPU_LAYOUT": "nhwc"},
+    b = mk("rb", {"MXTPU_PASSES": "off", "MXTPU_DONATE": "0"},
            1200.0, 750.0, {"host_dispatch": 80.0})
     env = dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu")
     # B is FASTER, so the ratchet flag must stay quiet on this pass
@@ -305,7 +305,7 @@ def test_compare_runs_reports_knob_and_metric_deltas(tmp_path):
     assert r.returncode == 0, r.stdout + r.stderr
     out = r.stdout
     assert "MXTPU_PASSES" in out and "default -> off" in out
-    assert "MXTPU_LAYOUT" in out and "(unset) -> nhwc" in out
+    assert "MXTPU_DONATE" in out and "(unset) -> 0" in out
     assert "throughput" in out and "+20.0%" in out
     assert "host_dispatch" in out and "-33.3%" in out
     # reversed (A after B) the step-time ratchet must fire

@@ -2050,3 +2050,60 @@ def test_op_sweep(name):
         pytest.fail("sweep case %r does not match any registered op "
                     "(renamed or removed?)" % name)
     CASES[name]()
+
+
+# ---------------------------------------------------------------------------
+# channels-last through an operator's OWN attribute (`layout="NHWC"`,
+# `axis=-1`): the same numbers as the NCHW form, forward and gradient.
+# Weights keep their NCHW-family shapes (OIHW, per-channel vectors).
+# ---------------------------------------------------------------------------
+def _forward_and_grads(name, inputs, attrs, head=None):
+    """(output, head gradient, gradient of every input); the head is
+    drawn once the output's shape is known, unless given."""
+    arrs = [nd.array(x) for x in inputs]
+    for a in arrs:
+        a.attach_grad()
+    with mx.autograd.record(train_mode=True):
+        out = imperative_invoke(name, *arrs, **attrs)
+        out = out[0] if isinstance(out, (list, tuple)) else out
+    if head is None:
+        head = _a(*out.shape)
+    out.backward(nd.array(head))
+    return out.asnumpy(), head, [a.grad.asnumpy() for a in arrs]
+
+
+_TO_NHWC, _TO_NCHW = (0, 2, 3, 1), (0, 3, 1, 2)
+_CHANNELS_LAST = {
+    # op: (its inputs after the data, its attributes, what turns it
+    # channels-last)
+    "Convolution": (
+        lambda: [_a(6, 2, 3, 2), _a(6)],
+        dict(kernel=(3, 2), num_filter=6, num_group=2, stride=(2, 1),
+             dilate=(1, 2), pad=(1, 0)),
+        dict(layout="NHWC")),
+    "Pooling": (
+        lambda: [],
+        dict(kernel=(3, 2), stride=(2, 1), pad=(1, 0), pool_type="avg",
+             pooling_convention="full", count_include_pad=False),
+        dict(layout="NHWC")),
+    "BatchNorm": (
+        lambda: [_pos(4), _a(4), np.zeros(4, np.float32),
+                 np.ones(4, np.float32)],
+        dict(fix_gamma=False),
+        dict(axis=-1)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_CHANNELS_LAST))
+def test_channels_last_attribute_matches_nchw(name):
+    rest, attrs, channels_last = _CHANNELS_LAST[name]
+    x, rest = _a(2, 4, 7, 6), rest()
+    out, head, grads = _forward_and_grads(name, [x] + rest, attrs)
+    out_cl, _, grads_cl = _forward_and_grads(
+        name, [x.transpose(_TO_NHWC)] + rest, dict(attrs, **channels_last),
+        head=head.transpose(_TO_NHWC))
+    assert_almost_equal(out_cl.transpose(_TO_NCHW), out, rtol=1e-5,
+                        atol=1e-6)
+    grads_cl[0] = grads_cl[0].transpose(_TO_NCHW)
+    for g, g_cl in zip(grads, grads_cl):
+        assert_almost_equal(g_cl, g, rtol=1e-5, atol=1e-6)

@@ -399,17 +399,16 @@ def test_one_step_schedule_matches_the_loop():
                 err_msg=str((remat, axes, k)))
 
 
-def test_fused_train_steps_matches_sequential():
+@pytest.mark.parametrize("optimizer", ["adam", "sgd"])
+@pytest.mark.parametrize("remat", ["none", "dots", "full"])
+def test_fused_train_steps_matches_sequential(remat, optimizer):
     """make_fused_train_steps: K lax.scan-fused steps must produce the
     SAME losses and final params as K sequential make_train_step calls
-    (the FusedTrainLoop principle applied to the SPMD transformer).
-    n_micro is 1 and pp is 1 here, so both sides take the loss's
-    straight path (no pipeline loop): this is also that path's fused
-    against sequential check."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
+    (the FusedTrainLoop principle applied to the SPMD transformer),
+    under each remat policy and both optimizers (`gpt2m_fused_k8` runs
+    "dots" with Adam).  n_micro is 1 and pp is 1 here, so both sides
+    take the loss's straight path (no pipeline loop): this is also that
+    path's fused against sequential check."""
     from mxtpu import parallel
     from mxtpu.parallel import transformer as T
 
@@ -420,39 +419,35 @@ def test_fused_train_steps_matches_sequential():
     axes = {"dp": 2, "pp": 1, "tp": 2, "sp": 2, "ep": 1}
     cfg = T.TransformerConfig(vocab=64, d_model=32, n_heads=2,
                               n_layers=2, d_ff=64, max_len=32,
-                              dtype="float32")
+                              dtype="float32", remat=remat)
     mesh = parallel.create_mesh(axes)
 
-    params = T.init_params(cfg, mesh, seed=0)
-    opt = T.init_opt_state(cfg, mesh)
-    step, sh = T.make_train_step(cfg, mesh, lr=1e-2, optimizer="adam")
+    def fresh_state():
+        # what a step carries beside the data: the weights, and Adam's
+        # moments where the optimizer has any
+        state = [T.init_params(cfg, mesh, seed=0)]
+        if optimizer == "adam":
+            state.append(T.init_opt_state(cfg, mesh))
+        return state
+
+    state = fresh_state()
+    step, sh = T.make_train_step(cfg, mesh, lr=1e-2, optimizer=optimizer)
     seq = []
     for k in range(K):
         tok = jax.device_put(jnp.asarray(toks_np[k]), sh["data"])
         lab = jax.device_put(jnp.asarray(labs_np[k]), sh["data"])
-        params, opt, loss = step(params, opt, tok, lab)
+        *state, loss = step(*state, tok, lab)
         seq.append(float(loss))
 
-    params2 = T.init_params(cfg, mesh, seed=0)
-    opt2 = T.init_opt_state(cfg, mesh)
     fstep, fsh = T.make_fused_train_steps(cfg, mesh, K, lr=1e-2,
-                                          optimizer="adam")
-    params2, opt2, losses = fstep(
-        params2, opt2,
+                                          optimizer=optimizer)
+    *state2, losses = fstep(
+        *fresh_state(),
         jax.device_put(jnp.asarray(toks_np), fsh["data"]),
         jax.device_put(jnp.asarray(labs_np), fsh["data"]))
     np.testing.assert_allclose([float(l) for l in np.asarray(losses)],
                                seq, rtol=1e-5)
-    for k in params:
-        np.testing.assert_allclose(np.asarray(params2[k]),
-                                   np.asarray(params[k]),
+    for k in state[0]:
+        np.testing.assert_allclose(np.asarray(state2[0][k]),
+                                   np.asarray(state[0][k]),
                                    rtol=1e-4, atol=1e-5)
-
-    # sgd variant runs and optimizes
-    fstep_s, fsh_s = T.make_fused_train_steps(cfg, mesh, K, lr=1e-2,
-                                              optimizer="sgd")
-    p3, losses_s = fstep_s(
-        T.init_params(cfg, mesh, seed=0),
-        jax.device_put(jnp.asarray(toks_np), fsh_s["data"]),
-        jax.device_put(jnp.asarray(labs_np), fsh_s["data"]))
-    assert np.isfinite(np.asarray(losses_s)).all()

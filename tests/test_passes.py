@@ -36,8 +36,6 @@ def test_parse_spec_grammar():
     assert P.parse_spec("fuse,dce") == ("dce", "fuse")
     assert P.parse_spec("default,-fuse") == ("dce", "fold", "cse")
     assert P.parse_spec(["cse", "dce"]) == ("dce", "cse")
-    # layout joins the default set only when MXTPU_LAYOUT asks for it
-    assert "layout" in P.parse_spec("layout")
 
 
 def test_parse_spec_unknown_pass_raises():
@@ -157,24 +155,6 @@ def test_fuse_stops_at_multi_consumer():
     out = sym.tanh(e) + sym.sin(e)
     opt, _ = out.optimize(passes="fuse", return_report=True)
     assert "exp" in _op_names(opt)
-
-
-def test_layout_pass_wraps_and_cancels():
-    d = sym.Variable("data")
-    h = sym.Convolution(data=d, kernel=(3, 3), num_filter=4,
-                        pad=(1, 1), name="c1")
-    h = sym.Activation(data=h, act_type="relu", name="r1")
-    h = sym.Convolution(data=h, kernel=(3, 3), num_filter=4,
-                        pad=(1, 1), name="c2")
-    opt, rep = h.optimize(passes="layout", return_report=True)
-    st = rep["passes"][0]
-    assert st["convs_rewritten"] == 2
-    assert st["transposes_cancelled"] >= 2
-    n_t = sum(1 for n in _op_names(opt) if n == "transpose")
-    assert n_t == 2  # one enter + one exit for the whole stack
-    convs = [n for n in _nodes(opt)
-             if not n.is_variable and n.op.name == "Convolution"]
-    assert all(c.attrs.get("layout") == "NHWC" for c in convs)
 
 
 # ---------------------------------------------------------------------------

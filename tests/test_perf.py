@@ -2,9 +2,7 @@
 `docs/observability.md` §Performance): phase schema on all three
 dispatch paths, sampled-sync cadence, MFU math, roofline
 classification, disabled mode, metrics/histogram surface, and the
-input-wait double-count fix.  The end-to-end ratchet contract (<10us
-hook, baseline regression, report acceptance) is guarded by
-`tools/check_perf.py` via `tests/test_tools.py`."""
+input-wait double-count fix."""
 import os
 
 import numpy as np

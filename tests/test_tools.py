@@ -13,9 +13,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # guards whose assertions are structural (events present, within-run
 # determinism/parity) run their fleets with HLO optimization passes
 # skipped — measured 20-40% faster on the 1-core CI box with every
-# gate intact (tier-1 870s suite budget).  NEVER apply this to
-# check_perf (ratchets against a committed baseline) or to
-# check_sharding/check_xprof (both fail under the flag).
+# gate intact.  NEVER apply this to check_sharding/check_xprof (both
+# fail under the flag).
 _DEOPT = {"JAX_DISABLE_MOST_OPTIMIZATIONS": "1"}
 
 
@@ -97,11 +96,9 @@ def test_check_passes_guard():
     """tools/check_passes.py: the graph-rewrite pipeline must be
     bitwise output-identical (passes on vs off) on a real small-model
     train run across all three dispatch paths, strictly reduce node
-    count, add zero retraces, hold the per-pass time budget, and the
-    NHWC layout pass must cut graph-level transposes vs the per-op
-    form while staying within 1e-4 (see mxtpu/passes/,
-    docs/passes.md)."""
-    out = _run(["tools/check_passes.py", "--layout"], timeout=420)
+    count, add zero retraces and hold the per-pass time budget (see
+    mxtpu/passes/, docs/passes.md)."""
+    out = _run(["tools/check_passes.py"], timeout=420)
     assert "check_passes OK" in out
 
 
@@ -130,48 +127,6 @@ def test_check_health_guard():
     health path must stay under its 10us budget."""
     out = _run(["tools/check_health.py"])
     assert "check_health OK" in out
-
-
-def test_check_perf_guard(tmp_path):
-    """tools/check_perf.py: the perf-regression ratchet.  Baselines
-    are written and compared ON THIS MACHINE (temp file) so the check
-    is a same-box ratchet, then the compare run must pass, assert the
-    always-on mx.perf hook under its 10us/step budget, and the
-    mx.perf.report() acceptance (dominant phase named, MFU in (0,1])
-    must hold on the 50-step MLP train run.  The committed CPU
-    baseline (benchmark/baselines/cpu.json) must exist and parse —
-    it is the reference-box default for interactive use."""
-    import json as _json
-
-    with open(os.path.join(REPO, "benchmark", "baselines",
-                           "cpu.json")) as f:
-        committed = _json.load(f)
-    assert committed["backend"] == "cpu"
-    assert committed["benches"]["mlp_train_step"]["step_time_us"] > 0
-    base = str(tmp_path / "cpu.json")
-    _run(["tools/check_perf.py", "--update-baseline",
-          "--baseline", base], timeout=420)
-    out = _run(["tools/check_perf.py", "--baseline", base],
-               timeout=420)
-    assert "check_perf OK" in out
-
-
-def test_check_perf_ratchet_catches_slowdown(tmp_path):
-    """tools/check_perf.py --slow-us: a deliberately slowed bench
-    (injected per-step sleep) must FAIL the ratchet with a named
-    regression — the self-test that the guard can actually fire."""
-    base = str(tmp_path / "cpu.json")
-    _run(["tools/check_perf.py", "--update-baseline", "--baseline",
-          base, "--steps", "30"], timeout=420)
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    env["PYTHONPATH"] = REPO
-    r = subprocess.run(
-        [sys.executable, "tools/check_perf.py", "--baseline", base,
-         "--steps", "30", "--slow-us", "2000"],
-        capture_output=True, text=True, env=env, cwd=REPO, timeout=420)
-    assert r.returncode == 1, (r.returncode, r.stdout, r.stderr)
-    assert "REGRESSION" in r.stderr, r.stderr
 
 
 def test_check_resilience_guard():
@@ -322,21 +277,6 @@ def test_check_hbm_guard():
     assert "check_hbm OK" in out
 
 
-def test_check_tune_guard():
-    """tools/check_tune.py: a short REAL tuning session over >= 2
-    knobs (donate x passes) must (a) persist a valid tuning-DB entry
-    keyed on (graph fingerprint, backend, batch profile) with every
-    trial left as a ledger row carrying its knob set, (b) auto-apply
-    on a FRESH bind in a new process under MXTPU_TUNE=apply with the
-    provenance string visible on mx.inspect.programs() records, and
-    (c) never regress: the tuned config re-measured against the
-    untuned baseline via compare_runs.py --fail-on-slower (see
-    mxtpu/tune/, docs/tuning.md)."""
-    out = _run(["tools/check_tune.py", "--steps", "6", "--trials", "4"],
-               timeout=420)
-    assert "check_tune OK" in out
-
-
 def test_launch_propagates_child_exit(tmp_path):
     """Satellite: a nonzero worker exit must surface as a nonzero
     launcher exit (silent child death looked like success before)."""
@@ -401,3 +341,30 @@ def test_diagnose_runs():
     out = _run(["tools/diagnose.py", "--timeout", "5"], timeout=200)
     assert "registered ops:" in out
     assert "Accelerator" in out
+
+
+def test_env_vars_doc_lists_what_the_code_reads():
+    """docs/env_vars.md names exactly the MXTPU_* variables that appear
+    in mxtpu/ and tools/: a variable nothing reads any more leaves the
+    table with its reader, and a new one arrives documented."""
+    import re
+
+    name = re.compile(r"MXTPU_[A-Z0-9_]+")
+
+    def names_in(text):
+        # a trailing "_" is a family's prefix (`MXTPU_PEAK_*`,
+        # `startswith("MXTPU_")`), not a variable
+        return {n for n in name.findall(text) if not n.endswith("_")}
+
+    in_code = set()
+    for top in ("mxtpu", "tools"):
+        for d, _, files in os.walk(os.path.join(REPO, top)):
+            for f in files:
+                if f.endswith(".py"):
+                    with open(os.path.join(d, f)) as fh:
+                        in_code |= names_in(fh.read())
+    with open(os.path.join(REPO, "docs", "env_vars.md")) as fh:
+        documented = names_in(fh.read())
+    assert documented == in_code, (
+        "undocumented: %s; documented but read nowhere: %s"
+        % (sorted(in_code - documented), sorted(documented - in_code)))

@@ -19,8 +19,7 @@ Four checks (any failure = rc 1; wired into tests/test_tools.py):
   3. **Disarmed budget** — with the census off (``MXTPU_HBM=0``
      semantics via ``hbm.enable(False)``) the step-path surfaces
      (``observe_used``/``census``/``metrics_block``) must cost
-     < 10us/call (MIN over batches, same discipline as
-     tools/check_perf.py).
+     < 10us/call (MIN over batches).
   4. **Capacity bracket** — in a CPU-memory-capped subprocess
      (RLIMIT_AS = VmSize + margin, set AFTER warming the bucket
      ladder), ``hbm.max_batch(headroom_bytes=margin)`` must bracket
@@ -218,7 +217,7 @@ def check_disarmed_budget(failures):
     hbm.enable(False)
     try:
         # MIN over batches: the budget is about the cheap path, not
-        # scheduler noise (same discipline as tools/check_perf.py)
+        # scheduler noise
         best = float("inf")
         n = 3000
         for _batch in range(5):

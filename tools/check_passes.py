@@ -20,16 +20,7 @@ semantics-changing pass cannot land silently):
      ``--budget-ms`` (default 800 ms; the first fold pays a one-off
      cold jit for its eager evals).
 
-``--layout`` adds the NHWC layout-pass check: conv-stack outputs with
-``MXTPU_LAYOUT=nhwc`` + passes on must match the plain NCHW graph
-within 1e-4 (layout legally reassociates BatchNorm/pooling
-reductions, so bitwise is not required), and the LOWERED StableHLO
-histogram (`inspect.hlo_histogram`) must show STRICTLY FEWER
-transposes than the per-op ``MXTPU_CONV_LAYOUT=NHWC`` form — the
-graph-level proof that the pass cancels per-op transpose pairs.
-
-Usage: python tools/check_passes.py [--steps N] [--layout]
-                                    [--budget-ms MS]
+Usage: python tools/check_passes.py [--steps N] [--budget-ms MS]
 """
 import argparse
 import os
@@ -191,89 +182,6 @@ def check_budget(budget_ms, failures):
                   % (name, avg_ms, runs))
 
 
-def check_layout(mx, np, P, failures):
-    import jax
-
-    from mxtpu import sym
-    from mxtpu.executor import _build_graph_fn
-
-    def stack():
-        d = sym.Variable("data")
-        h = sym.Convolution(data=d, kernel=(3, 3), num_filter=8,
-                            pad=(1, 1), name="c1")
-        h = sym.BatchNorm(data=h, name="bn1")
-        h = sym.Activation(data=h, act_type="relu", name="r1")
-        h = sym.Convolution(data=h, kernel=(3, 3), num_filter=8,
-                            pad=(1, 1), name="c2")
-        h = sym.Pooling(data=h, kernel=(2, 2), stride=(2, 2),
-                        pool_type="max", name="p1")
-        return sym.Flatten(h)
-
-    def lowered_hist(env, spec):
-        for k in ("MXTPU_LAYOUT", "MXTPU_CONV_LAYOUT"):
-            os.environ.pop(k, None)
-        os.environ.update(env)
-        try:
-            net = stack()
-            with P.scope(spec):
-                fn = _build_graph_fn(net, net.list_arguments(),
-                                     net.list_auxiliary_states(), False)
-            shapes, _, aux_s = net.infer_shape(data=(2, 3, 16, 16))
-            args = [jax.ShapeDtypeStruct(s, np.float32) for s in shapes]
-            aux = [jax.ShapeDtypeStruct(s, np.float32) for s in aux_s]
-            key = jax.ShapeDtypeStruct((2,), np.uint32)
-            txt = jax.jit(fn).lower(args, aux, key).as_text()
-            return mx.inspect.hlo_histogram(txt)
-        finally:
-            for k in ("MXTPU_LAYOUT", "MXTPU_CONV_LAYOUT"):
-                os.environ.pop(k, None)
-
-    def outputs(env, spec):
-        for k in ("MXTPU_LAYOUT", "MXTPU_CONV_LAYOUT"):
-            os.environ.pop(k, None)
-        os.environ.update(env)
-        try:
-            net = stack()
-            with P.scope(spec):
-                ex = net.simple_bind(mx.cpu(), data=(2, 3, 16, 16),
-                                     grad_req="write")
-            rng = np.random.RandomState(1)
-            for k, a in sorted(ex.arg_dict.items()):
-                if k != "data":
-                    a[:] = mx.nd.array(rng.rand(*a.shape)
-                                       .astype("float32"))
-            x = mx.nd.array(np.random.RandomState(2)
-                            .rand(2, 3, 16, 16).astype("float32"))
-            out = ex.forward(is_train=True, data=x)[0].asnumpy()
-            ex.backward()
-            return out, ex.grad_dict["c1_weight"].asnumpy()
-        finally:
-            for k in ("MXTPU_LAYOUT", "MXTPU_CONV_LAYOUT"):
-                os.environ.pop(k, None)
-
-    o_base, g_base = outputs({}, "off")
-    o_pass, g_pass = outputs({"MXTPU_LAYOUT": "nhwc"}, "default")
-    d_out = float(np.abs(o_base - o_pass).max())
-    d_grad = float(np.abs(g_base - g_pass).max())
-    if d_out > 1e-4 or d_grad > 1e-4:
-        failures.append("layout pass diverged: out %g grad %g"
-                        % (d_out, d_grad))
-    else:
-        print("OK: layout outputs/grads within 1e-4 "
-              "(out %g, grad %g)" % (d_out, d_grad))
-
-    h_perop = lowered_hist({"MXTPU_CONV_LAYOUT": "NHWC"}, "off")
-    h_pass = lowered_hist({"MXTPU_LAYOUT": "nhwc"}, "default")
-    t_perop = h_perop["n_transposes_surviving"]
-    t_pass = h_pass["n_transposes_surviving"]
-    if t_pass < t_perop:
-        print("OK: layout pass emits %d transposes vs %d per-op "
-              "(graph-level, lowered StableHLO)" % (t_pass, t_perop))
-    else:
-        failures.append("layout pass did not reduce transposes: "
-                        "%d (pass) vs %d (per-op)" % (t_pass, t_perop))
-
-
 def check_retrace_free(mx, failures):
     """Passes run pre-trace: dispatching the SAME shapes twice must
     not tick any *_trace counter on the second dispatch."""
@@ -304,8 +212,6 @@ def main():
                     help="train steps per parity run (even; default 4)")
     ap.add_argument("--budget-ms", type=int, default=800,
                     help="max avg wall ms per pass run")
-    ap.add_argument("--layout", action="store_true",
-                    help="also check the NHWC layout pass")
     args = ap.parse_args()
 
     import numpy as np
@@ -317,8 +223,6 @@ def main():
     check_parity(mx, np, P, args.steps, failures)
     check_reduction(mx, P, failures)
     check_retrace_free(mx, failures)
-    if args.layout:
-        check_layout(mx, np, P, failures)
     check_budget(args.budget_ms, failures)
 
     if failures:

@@ -23,7 +23,7 @@ wired into tests/test_tools.py):
   5. **Disabled-mode budget** — with profiling off (``MXTPU_XPROF=0``
      semantics via ``xprof.enable(False)``), the per-chunk
      ``maybe_autoprofile`` hook must cost < 10us/step (MIN over
-     batches, same discipline as tools/check_perf.py).
+     batches).
 
 Also asserts the consumer wiring: the profile lands on the program's
 `mx.inspect` record (``op_profile``), emits the ``op_profile``

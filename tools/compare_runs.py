@@ -2,22 +2,21 @@
 """Diff two `mx.obs` run-ledger files: knob deltas + metric shifts.
 
 Each run (``MXTPU_RUN_DIR`` armed) leaves ``<run_id>.jsonl`` holding
-timestamped sample rows, any ``bench_common`` bench rows, and one
-final summary row per role (bench-row schema: throughput /
-step_time_us / mfu / phases / knobs).  This tool answers the question
-the future `mx.tune` autotuner asks of its trial history: *what
-changed between these two runs, and what did it do to the numbers?*
+timestamped sample rows and one final summary row per role
+(throughput / step_time_us / mfu / phases / knobs).  This tool
+answers: *what changed between these two runs, and what did it do to
+the numbers?*
 
   * **knob deltas** — every ``MXTPU_*`` / ``JAX_PLATFORMS`` /
     ``XLA_FLAGS`` key that was added, removed or changed between the
     runs' recorded environments;
   * **metric deltas** — headline throughput, step time, MFU and the
-    primary bench metric, side by side with the relative change;
+    summary row's own metric, side by side with the relative change;
   * **phase shifts** — the per-step phase attribution
     (input_wait/host_dispatch/...) of run A vs run B, naming where
     the time moved;
-  * **op-sink shifts** — when both runs' bench rows carry the
-    `mx.xprof` ``op_profile`` breakdown (seeds run with profiling),
+  * **op-sink shifts** — when both runs' summary rows carry the
+    `mx.xprof` ``op_profile`` breakdown (runs made with profiling),
     per-op-class device-time deltas plus the top-sink change: WHICH
     op class got slower, not just which phase;
   * **sample-series view** — per-run sample counts and averaged
@@ -44,11 +43,7 @@ sys.path.insert(0, ROOT)
 
 KNOB_KEYS_SKIP = ("MXTPU_RUN_ID", "MXTPU_TELEMETRY_DIR",
                   "MXTPU_PS_ROOT_PORT", "MXTPU_SERVE_PORT",
-                  "MXTPU_SERVE_PORTS", "MXTPU_SERVE_RANK",
-                  # tuner bookkeeping, not perf knobs: every trial
-                  # differs in these by construction
-                  "MXTPU_TUNE", "MXTPU_TUNE_TRIAL", "MXTPU_TUNE_DB",
-                  "MXTPU_BENCH_OUT")
+                  "MXTPU_SERVE_PORTS", "MXTPU_SERVE_RANK")
 
 
 def _read(path):
@@ -75,12 +70,8 @@ def _resolve(run_dir, name):
 
 
 def primary_row(rows):
-    """The run's headline record: the LAST bench row when the run
-    emitted one (`bench_common` writes them), else the summary row of
-    the busiest role (most steps — the trainer, not the scheduler)."""
-    benches = [r for r in rows if r.get("kind") == "bench"]
-    if benches:
-        return benches[-1]
+    """The run's headline record: the summary row of the busiest role
+    (most steps — the trainer, not the scheduler)."""
     summaries = [r for r in rows if r.get("kind") == "summary"]
     if summaries:
         return max(summaries, key=lambda r: r.get("value") or 0)
@@ -162,7 +153,7 @@ def _top_sink(row):
 
 def op_sink_shifts(a, b):
     """Per-op-class device-time deltas (us) when BOTH runs carry the
-    `mx.xprof` ``op_profile`` breakdown on their bench rows — this is
+    `mx.xprof` ``op_profile`` breakdown on their summary rows — this is
     the answer to WHICH op moved, one level below the phase shifts.
     Returns (class_rows, top_a, top_b) or None when either run lacks a
     profile."""
@@ -179,25 +170,6 @@ def op_sink_shifts(a, b):
     # biggest mover first — the headline of the diff
     rows.sort(key=lambda r: -abs((r[2] or 0) - (r[1] or 0)))
     return rows, _top_sink(a), _top_sink(b)
-
-
-def hbm_shifts(a, b):
-    """Per-class device-memory deltas (bytes) when BOTH runs carry the
-    `mx.hbm` plan on their bench rows — the answer to WHICH memory
-    class grew (params? activations? optimizer state?), one level
-    below the peak-bytes delta.  Returns (class_rows, peak_a, peak_b)
-    or None when either run lacks a plan."""
-    pa = (a.get("hbm_plan") or {}).get("classes") or {}
-    pb = (b.get("hbm_plan") or {}).get("classes") or {}
-    if not pa or not pb:
-        return None
-    rows = []
-    for k in sorted(set(pa) | set(pb)):
-        va, vb = pa.get(k, 0) or 0, pb.get(k, 0) or 0
-        if va or vb:
-            rows.append((k, va, vb, _pct(va, vb)))
-    rows.sort(key=lambda r: -abs((r[2] or 0) - (r[1] or 0)))
-    return rows, a.get("peak_hbm_bytes"), b.get("peak_hbm_bytes")
 
 
 def _fmt_num(v):
@@ -234,15 +206,6 @@ def report(path_a, path_b):
                          "pct": p}
                         for c, va, vb, p in class_rows],
             "top_sink_a": top_a, "top_sink_b": top_b,
-        }
-    mem = hbm_shifts(a, b)
-    if mem is not None:
-        class_rows, peak_a, peak_b = mem
-        out["hbm_shifts"] = {
-            "classes": [{"class": c, "a_bytes": va, "b_bytes": vb,
-                         "pct": p}
-                        for c, va, vb, p in class_rows],
-            "peak_hbm_bytes_a": peak_a, "peak_hbm_bytes_b": peak_b,
         }
     return out
 
@@ -299,19 +262,6 @@ def print_report(rep):
         print("  top sink: %s -> %s"
               % (sinks.get("top_sink_a") or "-",
                  sinks.get("top_sink_b") or "-"))
-    mem = rep.get("hbm_shifts")
-    if mem:
-        print()
-        print("memory-class shifts (bytes, mx.hbm):")
-        for d in mem["classes"]:
-            pct = ("  (%+.1f%%)" % d["pct"]) \
-                if d["pct"] is not None else ""
-            print("  %-28s %10s -> %10s%s"
-                  % (d["class"], _fmt_num(d["a_bytes"]),
-                     _fmt_num(d["b_bytes"]), pct))
-        print("  peak hbm: %s -> %s"
-              % (_fmt_num(mem.get("peak_hbm_bytes_a")),
-                 _fmt_num(mem.get("peak_hbm_bytes_b"))))
 
 
 def main(argv=None):

@@ -2,8 +2,7 @@
 """Per-op device-time report for a fused conv-stack train bench —
 the `mx.xprof` CLI.
 
-Builds a Conv-BN-ReLU stack (the layout-sensitive shape the MFU hunt
-cares about), trains it through `FusedTrainLoop` so the `mx.perf`
+Builds a Conv-BN-ReLU stack, trains it through `FusedTrainLoop` so the `mx.perf`
 observatory measures the program wall, then prints the measured
 top-K-sinks table: per-op wall, share, layer attribution
 (``jvp(layer)`` / ``transpose(jvp(layer))`` HLO op_name metadata),
