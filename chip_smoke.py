@@ -83,6 +83,11 @@ class _Sizes(object):
                                 (2, 640, 64), (8, 4096, 128),
                                 (4, 4096, 256), (128, 1024, 64)]
             self.attn_ragged, self.attn_ragged_block = (2, 200, 64), 128
+            # ((bh, T, d), heads): the entry on the activations' layout
+            # (`flash_attention_bthd`, [B, T, heads, d]) at the two LM
+            # cells' head widths, so a layout slip reads here before a
+            # cell's `correct` does
+            self.attn_bthd = [((128, 1024, 64), 16), ((4, 4096, 256), 2)]
             self.lm = dict(vocab=8192, d_model=1024, n_heads=8,
                            n_layers=8, d_ff=4096, max_len=1024)
             self.lm_batch, self.lm_k = 8, 2
@@ -92,6 +97,7 @@ class _Sizes(object):
             self.batch, self.image, self.fused_k = 8, 16, 2
             self.attn_shapes = [(6, 128, 32), (2, 160, 16), (2, 128, 64)]
             self.attn_ragged, self.attn_ragged_block = (2, 50, 16), 32
+            self.attn_bthd = [((4, 128, 64), 2), ((2, 128, 256), 2)]
             self.lm = dict(vocab=64, d_model=32, n_heads=2, n_layers=2,
                            d_ff=64, max_len=64)
             self.lm_batch, self.lm_k = 4, 2
@@ -334,9 +340,11 @@ def phase2_per_step(sizes, meter, mod):
 # phase 3: the Pallas kernel, then the TransformerLM train step
 # ---------------------------------------------------------------------------
 
-def _attention_check(shape, block=512):
+def _attention_check(shape, block=512, heads=None):
     """Kernel forward and gradients against the materializing
-    reference at one (bh, T, d); returns the worst normalized error."""
+    reference at one (bh, T, d); returns the worst normalized error.
+    With `heads`, through the entry on the activations' layout: the
+    same arrays as [bh / heads, T, heads, d]."""
     bh, t, d = shape
     rng = np.random.RandomState(t + d)
     q, k, v, cot = (jnp.asarray(rng.normal(0, 1, shape), jnp.bfloat16)
@@ -350,8 +358,16 @@ def _attention_check(shape, block=512):
                                 * cot.astype(jnp.float32)).sum()
 
     def flash(q, k, v):
-        return pa.flash_attention(q, k, v, causal=True, block_q=block,
-                                  block_k=block)
+        if heads is None:
+            return pa.flash_attention(q, k, v, causal=True, block_q=block,
+                                      block_k=block)
+        b = bh // heads
+        out = pa.flash_attention_bthd(
+            *(x.reshape(b, heads, t, d).transpose(0, 2, 1, 3)
+              for x in (q, k, v)), causal=True, block_q=block,
+            block_k=block)
+        return out.reshape(b, t, heads, d).transpose(0, 2, 1, 3) \
+            .reshape(bh, t, d)
 
     def ref(q, k, v):
         return pa._reference_attention(q, k, v, scale, True)
@@ -397,7 +413,8 @@ def phase3_lm(sizes, meter):
              "interpret mode is %s" % pa._interpret())
     info = {}
     stats0 = dict(profiler.stats())
-    errs = [_attention_check(s) for s in sizes.attn_shapes]
+    errs = [_attention_check(s) for s in sizes.attn_shapes] + \
+        [_attention_check(s, heads=h) for s, h in sizes.attn_bthd]
     info["attn_max_err"] = round(max(errs), 5)
     stats1 = dict(profiler.stats())
     _require(stats1.get("flash_attention_pallas", 0)

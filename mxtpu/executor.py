@@ -57,12 +57,38 @@ _REMAT_POLICIES = {
     "full": None,
 }
 
+# The name (`jax.ad_checkpoint.checkpoint_name`) under which code that
+# says for itself what "dots" keeps of it names a product's output.
+REMAT_DOT = "dot"
 
-def apply_remat(fn, policy_name, prevent_cse=True):
-    """Wrap fn in `jax.checkpoint` under the named policy ('full' =
-    save nothing, 'dots' = save matmul outputs, 'dots_no_batch').
-    The ONE remat vocabulary — the symbolic executor's mirror pass and
-    the SPMD transformer's per-layer remat both route through here.
+
+def apply_remat(fn, policy_name, prevent_cse=True, named=False):
+    """Wrap fn in `jax.checkpoint` under the named policy.  The ONE
+    remat vocabulary — the symbolic executor's mirror pass and the SPMD
+    transformer's per-layer remat both route through here:
+
+    * 'full': save nothing, recompute everything from fn's inputs;
+    * 'dots_no_batch': save the outputs of products with no batch
+      dimensions;
+    * 'dots': save the products' outputs and recompute the elementwise
+      chains between them, AND save the flash-attention kernel's two
+      results, its merged output and its row log-sums (named `FLASH_OUT`
+      / `FLASH_LSE` by `ops/pallas_attention.py`; a `pallas_call` is no
+      product, so without the names the backward pass ran the whole
+      forward kernel a second time to have them).  Which outputs count
+      as products: every `dot_general` / convolution, for a fn that
+      names nothing (the symbolic executor's graphs); with `named`, the
+      values fn itself names `REMAT_DOT` and no other.  The LM's blocks
+      (`parallel/transformer.py`) name each product they want kept and
+      leave out the ONE that is cheapest to rebuild per byte from what
+      is kept anyway, which pays for the kernel's output in the same
+      layer: the out-projection `o @ wo` in `_attention` (the kept
+      merged output is its operand: one [B*T, E] x [E, E] product, a
+      ninth of the kernel call it saves at gpt2-medium's widths), the
+      up-projection of q out of its latent `c_q @ wq_b` in `_mla`
+      (as wide as the kept output, a tenth of the kernel call at
+      GLM-4.7-Flash's widths; `o @ wo` is narrower and dearer there).
+
     Pass prevent_cse=False when fn is a `lax.scan` body: the CSE
     barriers are unnecessary under scan (per the jax.checkpoint docs)
     and only cost backward throughput."""
@@ -73,6 +99,13 @@ def apply_remat(fn, policy_name, prevent_cse=True):
                          % (sorted(_REMAT_POLICIES), policy_name))
     attr = _REMAT_POLICIES[policy_name]
     policy = getattr(jax.checkpoint_policies, attr) if attr else None
+    if policy_name == "dots":
+        from .ops.pallas_attention import FLASH_OUT, FLASH_LSE
+
+        cp = jax.checkpoint_policies
+        policy = cp.save_only_these_names(REMAT_DOT, FLASH_OUT, FLASH_LSE) \
+            if named else cp.save_from_both_policies(
+                policy, cp.save_only_these_names(FLASH_OUT, FLASH_LSE))
     return jax.checkpoint(fn, policy=policy, prevent_cse=prevent_cse)
 
 

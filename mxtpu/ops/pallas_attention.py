@@ -34,6 +34,14 @@ Registered as `_contrib_flash_attention` (q, k, v of shape
 (batch, heads, seq, head_dim)).  `mxtpu.parallel`'s blockwise /
 ring attention routes its local-chunk compute here automatically
 wherever the kernel backend exists (see `_use_pallas`).
+
+`flash_attention_bthd` is the same on the activations' own layout
+([batch, seq, heads, head_dim] in, [batch, seq, heads * head_dim]
+out): the head split, the kernels and the merge are inside ONE
+`custom_vjp`, whose residuals are q, k, v as given, the MERGED output
+and the log-sums, the last two under the names `FLASH_OUT` /
+`FLASH_LSE` so that a remat policy can keep them (`executor.
+apply_remat`'s "dots" does; the LM's blocks call this entry).
 """
 from __future__ import annotations
 
@@ -138,6 +146,20 @@ def _count_path(path):
     from .. import profiler as _prof
 
     _prof.inc_stat("flash_attention_" + path)
+
+
+def _count_fwd(named):
+    """Count, per trace, the forward kernels (`flash_fwd_traced`) and
+    those of them traced for differentiation, whose output and log-sums
+    go out under `FLASH_OUT` / `FLASH_LSE` (`flash_fwd_named`).  A
+    recomputation under `jax.checkpoint` replays traced equations and
+    is no trace, so no trace-time count sees it; the compiled programs'
+    call sites are held by tests/test_chip_compile.py."""
+    from .. import profiler as _prof
+
+    _prof.inc_stat("flash_fwd_traced")
+    if named:
+        _prof.inc_stat("flash_fwd_named")
 
 
 def _causal_mask(i, j, block_q, block_k):
@@ -491,10 +513,11 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, dlt_ref,
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _flash_backward_pallas(q, k, v, g, out, lse, sm_scale, causal,
+def _flash_backward_pallas(q, k, v, g, delta, lse, sm_scale, causal,
                            block_q, block_k):
     """Pallas backward: two kernel launches (dq; dk/dv) over the saved
-    LSE — the TPU-kernel analog of the jnp blocked sweeps below."""
+    LSE and `delta = rowsum(out * g)` — the TPU-kernel analog of the jnp
+    blocked sweeps below."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -506,8 +529,6 @@ def _flash_backward_pallas(q, k, v, g, out, lse, sm_scale, causal,
     # (block_q, 1) — sublanes divisible by 8, lane dim equal to the
     # array's.  A (1, block_q) rank-2 block would fail that check
     # whenever bh is neither 1 nor a multiple of 8.
-    delta = (out.astype(jnp.float32) * g.astype(jnp.float32)) \
-        .sum(axis=-1)
     lse3 = lse[..., None]
     delta3 = delta[..., None]
     nq = tq // block_q
@@ -593,6 +614,7 @@ def _flash_impl(q, k, v, sm_scale, causal, block_q, block_k, want_lse):
         _count_path("reference")
         return _reference_attention_lse(q, k, v, sm_scale, causal)
     _count_path("pallas")
+    _count_fwd(want_lse)
     _count_tiles(tq, tk, block_q, block_k, causal)
     pq = (-tq) % block_q
     if pq:
@@ -606,15 +628,57 @@ def _flash_impl(q, k, v, sm_scale, causal, block_q, block_k, want_lse):
                                  block_k, want_lse)
 
 
+# The names the differentiated forward gives its two results.  A
+# `jax.checkpoint` policy that lists them (`executor.apply_remat`'s
+# "dots") keeps them, and the backward pass then does not run the
+# forward kernel a second time to have them; outside `jax.checkpoint`
+# a name is a no-op.
+FLASH_OUT = "flash_out"
+FLASH_LSE = "flash_lse"
+
+
+def _split_heads(x):
+    """[B, T, H, D], the activations' layout, -> (B*H, T, D), the
+    kernels'."""
+    b, t, h, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
+
+
+def _merge_heads(x, b):
+    """(B*H, T, D) -> [B, T, H, D]."""
+    bh, t, d = x.shape
+    return x.reshape(b, bh // b, t, d).transpose(0, 2, 1, 3)
+
+
+def _flash_merged(q, k, v, sm_scale, causal, block_q, block_k, want_lse):
+    """`_flash_impl` on [B, T, H, D]: split, the forward, merge.
+    Returns ([B, T, H * D], log-sums (B*H, T) or None)."""
+    b, t, h, d = q.shape
+    out, lse = _flash_impl(_split_heads(q), _split_heads(k),
+                           _split_heads(v), sm_scale, causal, block_q,
+                           block_k, want_lse)
+    return _merge_heads(out, b).reshape(b, t, h * d), lse
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def _flash(q, k, v, sm_scale, causal, block_q, block_k):
-    return _flash_impl(q, k, v, sm_scale, causal, block_q, block_k,
-                       want_lse=False)[0]
+    """q, k, v: [B, T, H, D]; returns [B, T, H * D].  The head split,
+    the kernels and the merge are all inside the `custom_vjp`, so what
+    it keeps for the backward pass is in the activations' layout: q, k,
+    v as given, the MERGED output and the log-sums.  (A `[B*H, T, 64]`
+    bf16 array is padded to 128 lanes on the chip; `[B, T, H * 64]` is
+    not.)"""
+    return _flash_merged(q, k, v, sm_scale, causal, block_q, block_k,
+                         want_lse=False)[0]
 
 
 def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k):
-    out, lse = _flash_impl(q, k, v, sm_scale, causal, block_q, block_k,
-                           want_lse=True)
+    from jax.ad_checkpoint import checkpoint_name
+
+    out, lse = _flash_merged(q, k, v, sm_scale, causal, block_q, block_k,
+                             want_lse=True)
+    out = checkpoint_name(out, FLASH_OUT)
+    lse = checkpoint_name(lse, FLASH_LSE)
     return out, (q, k, v, out, lse)
 
 
@@ -629,25 +693,45 @@ def _block_mask(causal, q0, k0, bq, bk):
 
 
 def _flash_bwd(sm_scale, causal, block_q, block_k, res, g):
+    """The backward rule, on the activations' layout: `delta = rowsum(out
+    * g)` is taken from the merged output and the merged cotangent, so
+    the output is never needed in (B*H, T, D) again; q, k, v and the
+    cotangent are split for the sweeps and dq, dk, dv merged back."""
+    import jax.numpy as jnp
+
+    q, k, v, out, lse = res
+    b, tq, h, d = q.shape
+    g = g.reshape(q.shape)
+    delta = (out.reshape(q.shape).astype(jnp.float32)
+             * g.astype(jnp.float32)).sum(axis=-1)          # [B, T, H]
+    delta = delta.transpose(0, 2, 1).reshape(b * h, tq)
+    grads = _flash_bwd_sweeps(
+        _split_heads(q), _split_heads(k), _split_heads(v),
+        _split_heads(g), delta, lse, sm_scale, causal, block_q, block_k)
+    return tuple(_merge_heads(x, b) for x in grads)
+
+
+def _flash_bwd_sweeps(q, k, v, g, delta, lse_saved, sm_scale, causal,
+                      block_q, block_k):
     """Blocked recompute backward (flash attention paper §3.1): scores
     are rebuilt block by block against the LSE saved by the forward, so
     backward memory stays O(T·d + block²) — the T×T matrix is never
     materialized.  Two sweeps (dq; dk/dv), with fully-masked causal
-    blocks skipped via loop bounds."""
+    blocks skipped via loop bounds.  All of (B*H, T, D); `delta` and
+    `lse_saved` (B*H, T)."""
     import jax.numpy as jnp
     from jax import lax
 
-    q, k, v, out, lse_saved = res
     B, Tq, D = q.shape
     Tk = k.shape[1]
-    # blocks arrive pre-clamped by flash_attention (the only entry)
+    # blocks arrive pre-clamped by flash_attention_bthd (the only entry)
     bq, bk = block_q, block_k
     if _use_pallas() and Tq % bq == 0 and Tk % bk == 0 \
             and _tiles(bq, bk, D):
         # kernel path (same math as the jnp sweeps below, on the MXU)
         _count_path("pallas")
         _count_tiles(Tq, Tk, bq, bk, causal)
-        return _flash_backward_pallas(q, k, v, g, out, lse_saved,
+        return _flash_backward_pallas(q, k, v, g, delta, lse_saved,
                                       sm_scale, causal, bq, bk)
     _count_path("reference")
     # pad to block multiples; padded K columns are masked by giving
@@ -660,12 +744,11 @@ def _flash_bwd(sm_scale, causal, block_q, block_k, res, g):
     k32 = jnp.pad(k.astype(jnp.float32), ((0, 0), (0, pk), (0, 0)))
     v32 = jnp.pad(v.astype(jnp.float32), ((0, 0), (0, pk), (0, 0)))
     g32 = jnp.pad(g.astype(jnp.float32), ((0, 0), (0, pq), (0, 0)))
-    o32 = jnp.pad(out.astype(jnp.float32), ((0, 0), (0, pq), (0, 0)))
     lse = jnp.pad(lse_saved, ((0, 0), (0, pq)))
+    delta = jnp.pad(delta, ((0, 0), (0, pq)))           # (B, Tq+pq)
     nq = (Tq + pq) // bq
     nk = (Tk + pk) // bk
     k_valid = jnp.arange(Tk + pk) < Tk  # padded keys never attend
-    delta = (o32 * g32).sum(axis=-1)    # (B, Tq+pq)
 
     def scores(qi, i, j):
         kj = lax.dynamic_slice_in_dim(k32, j * bk, bk, 1)
@@ -745,7 +828,12 @@ def flash_attention(q, k, v, sm_scale=None, causal=False, block_q=512,
     """Multi-head attention, flash-style.
 
     q/k/v: (batch, heads, seq, head_dim) or (batch*heads, seq,
-    head_dim).  Returns the same layout as the input.
+    head_dim).  Returns the same layout as the input.  A caller that
+    holds q, k, v as its products leave them, [batch, seq, heads,
+    head_dim], takes `flash_attention_bthd`: the same kernels behind
+    the same `custom_vjp`, which then keeps its residuals in that
+    layout (this entry is that one with every (batch, head) pair as a
+    batch row of one head).
 
     Default 512x512 blocks, walked by class when `causal` (see
     `_causal_walk`).  Measured on a v5e (PERF.md section 5, PR 31;
@@ -759,14 +847,28 @@ def flash_attention(q, k, v, sm_scale=None, causal=False, block_q=512,
     are clamped to the sequence lengths below, so short-sequence and
     unit-test shapes are unaffected.
     """
-    import jax.numpy as jnp
+    shape = q.shape
+    if q.ndim == 4:
+        q, k, v = (x.reshape((-1,) + x.shape[2:]) for x in (q, k, v))
+    out = flash_attention_bthd(q[:, :, None], k[:, :, None],
+                               v[:, :, None], sm_scale=sm_scale,
+                               causal=causal, block_q=block_q,
+                               block_k=block_k)
+    return out.reshape(shape)
 
-    squeeze4 = q.ndim == 4
-    if squeeze4:
-        b, h, t, d = q.shape
-        q = q.reshape(b * h, t, d)
-        k = k.reshape(b * h, k.shape[2], d)
-        v = v.reshape(b * h, v.shape[2], d)
+
+def flash_attention_bthd(q, k, v, sm_scale=None, causal=False,
+                         block_q=512, block_k=512):
+    """`flash_attention` on the activations' own layout: q, k, v of
+    [batch, seq, heads, head_dim] (a reshape of the projections'
+    [batch, seq, heads * head_dim]); returns [batch, seq, heads *
+    head_dim], what the out-projection reads.  The split to (batch *
+    heads, seq, head_dim), the three kernels and the merge sit inside
+    ONE `custom_vjp` (`_flash`), whose residuals are q, k, v as given,
+    the merged output and the log-sums; the last two carry the names
+    `FLASH_OUT` / `FLASH_LSE` (`jax.ad_checkpoint.checkpoint_name`), so
+    a remat policy that lists them keeps the forward kernel from
+    running twice."""
     if sm_scale is None:
         sm_scale = 1.0 / float(np.sqrt(q.shape[-1]))
     # fit blocks to the sequence lengths: clamp, then halve (512 ->
@@ -780,13 +882,8 @@ def flash_attention(q, k, v, sm_scale=None, causal=False, block_q=512,
             b //= 2
         return b
 
-    block_q = _fit(block_q, q.shape[1])
-    block_k = _fit(block_k, k.shape[1])
-    out = _flash(q, k, v, float(sm_scale), bool(causal), block_q,
-                 block_k)
-    if squeeze4:
-        out = out.reshape(b, h, t, d)
-    return out
+    return _flash(q, k, v, float(sm_scale), bool(causal),
+                  _fit(block_q, q.shape[1]), _fit(block_k, k.shape[1]))
 
 
 @register("_contrib_flash_attention")

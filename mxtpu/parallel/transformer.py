@@ -28,7 +28,7 @@ from typing import Any, Dict, Optional, Tuple
 
 from ..base import MXNetError
 from .mesh import create_mesh, AXIS_DP, AXIS_TP, AXIS_PP, AXIS_SP, AXIS_EP
-from .ring_attention import ring_attention, _match_vma
+from .ring_attention import ring_attention, _match_vma, _pallas_enabled
 
 __all__ = ["TransformerConfig", "init_params", "param_specs",
            "make_train_step", "make_fused_train_steps", "make_forward",
@@ -57,8 +57,13 @@ class TransformerConfig:
     # in the backward pass.  "full" recomputes each layer's internals
     # from its input (activation memory drops from O(layers * T *
     # d_ff) to O(layers * T * d_model) — what makes T>=8k trainable
-    # on one chip); "dots" saves matmul outputs and recomputes
-    # elementwise only.  Analog of the reference's
+    # on one chip); "dots" saves the products' outputs (the blocks
+    # name them: `_kept`) and the flash kernel's merged output and
+    # log-sums, and recomputes the elementwise chains between them
+    # and ONE product per attention block, the cheapest to rebuild
+    # per byte, which pays for the kernel's output: `o @ wo` in
+    # `_attention`, `c_q @ wq_b` in `_mla`.  So the backward pass
+    # runs no second forward kernel.  Analog of the reference's
     # MXNET_BACKWARD_DO_MIRROR (docs/faq/env_var.md) which this
     # repo's symbolic executor exposes as MXTPU_BACKWARD_DO_MIRROR;
     # same policy vocabulary (`executor.apply_remat`).
@@ -390,22 +395,50 @@ def _rms_norm(x, scale, eps=1e-6):
         * scale
 
 
+def _kept(y):
+    """Name a product's output for `remat="dots"` to keep
+    (`executor.apply_remat`).  Every product of a layer goes through
+    here but the one its attention block gives back; under any other
+    policy, and outside `jax.checkpoint`, the name is a no-op."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    from ..executor import REMAT_DOT
+
+    return checkpoint_name(y, REMAT_DOT)
+
+
+def _causal_attention(q, k, v):
+    """Causal attention over the ring-sharded sequence.  q, k, v: [B,
+    T_loc, h, D], as the projections leave them; returns [B, T_loc, h *
+    D], what the out-projection reads.  On one sequence shard with the
+    kernel available this is the flash kernels' entry that keeps this
+    layout (its `custom_vjp` holds the merged output and the log-sums
+    under names `remat="dots"` keeps); the sp > 1 ring, and a host
+    without the kernel, take the ring's own path in [B, h, T, D]."""
+    import jax
+
+    if jax.lax.axis_size(AXIS_SP) == 1 and _pallas_enabled():
+        from ..ops.pallas_attention import flash_attention_bthd
+
+        return flash_attention_bthd(q, k, v, causal=True)
+    B, T, h, D = v.shape
+    o = ring_attention(*(a.transpose(0, 2, 1, 3) for a in (q, k, v)),
+                       axis_name=AXIS_SP, causal=True)
+    return o.transpose(0, 2, 1, 3).reshape(B, T, h * D)
+
+
 def _attention(cfg, x, wq, wk, wv, wo, tp_size):
     """TP column/row-parallel attention with ring-sharded sequence.
     x: [B, T_loc, E]; wq/wk/wv: [E, E/tp] (local shard), wo: [E/tp, E]."""
     import jax
-    import jax.numpy as jnp
 
     B, T, E = x.shape
     h_loc = cfg.n_heads // tp_size
     D = E // cfg.n_heads
-
-    def split(h):
-        return h.reshape(B, T, h_loc, D).transpose(0, 2, 1, 3)
-
-    q, k, v = split(x @ wq), split(x @ wk), split(x @ wv)
-    o = ring_attention(q, k, v, axis_name=AXIS_SP, causal=True)
-    o = o.transpose(0, 2, 1, 3).reshape(B, T, h_loc * D)
+    q, k, v = (_kept(x @ w).reshape(B, T, h_loc, D) for w in (wq, wk, wv))
+    o = _causal_attention(q, k, v)
+    # not `_kept`: the attention's merged output IS kept under "dots",
+    # and this product is rebuilt from it (the cheapest per byte)
     out = o @ wo
     # row-parallel output projection: partial sums over tp
     return jax.lax.psum(out, AXIS_TP)
@@ -424,7 +457,9 @@ def _rotary_table(cfg, positions):
 
 def _rotate(x, rope):
     """Rotary embedding of x [..., T, d], rotate-half pairing: dim i is
-    paired with dim i + d/2."""
+    paired with dim i + d/2.  `rope`: (cos, sin) of [T, d / 2], or of any
+    shape that broadcasts against x's halves ([T, 1, d / 2] for x [B, T,
+    heads, d])."""
     import jax.numpy as jnp
 
     cos, sin = rope
@@ -453,30 +488,32 @@ def _mla(cfg, x, lw, tp_size, rope):
     kvl = cfg.kv_lora_rank
 
     def heads(y, d):
-        return y.reshape(B, T, h, d).transpose(0, 2, 1, 3)
+        return y.reshape(B, T, h, d)
 
-    c_q = _rms_norm(x @ lw["wq_a"], lw["q_norm"], cfg.norm_eps)
+    rope_h = tuple(r[:, None] for r in rope)    # [T, 1, dr / 2]: per head
+    c_q = _rms_norm(_kept(x @ lw["wq_a"]), lw["q_norm"], cfg.norm_eps)
+    # not `_kept`: as wide as the attention's merged output, which IS
+    # kept under "dots", and the cheapest product per byte to rebuild
     q = heads(c_q @ lw["wq_b"], dn + dr)
-    q = jnp.concatenate([q[..., :dn], _rotate(q[..., dn:], rope)], -1)
-    ckv = x @ lw["wkv_a"]
+    q = jnp.concatenate([q[..., :dn], _rotate(q[..., dn:], rope_h)], -1)
+    ckv = _kept(x @ lw["wkv_a"])
     c_kv = _rms_norm(ckv[..., :kvl], lw["kv_norm"], cfg.norm_eps)
-    k_pe = _rotate(ckv[..., kvl:][:, None], rope)         # [B, 1, T, dr]
-    kv = heads(c_kv @ lw["wkv_b"], dn + dv)
+    k_pe = _rotate(ckv[..., kvl:], rope)[:, :, None]      # [B, T, 1, dr]
+    kv = heads(_kept(c_kv @ lw["wkv_b"]), dn + dv)
     k = jnp.concatenate(
-        [kv[..., :dn], jnp.broadcast_to(k_pe, (B, h, T, dr))], -1)
-    o = ring_attention(q, k, kv[..., dn:], axis_name=AXIS_SP, causal=True)
-    o = o.transpose(0, 2, 1, 3).reshape(B, T, h * dv)
-    return jax.lax.psum(o @ lw["wo"], AXIS_TP)
+        [kv[..., :dn], jnp.broadcast_to(k_pe, (B, T, h, dr))], -1)
+    o = _causal_attention(q, k, kv[..., dn:])
+    return jax.lax.psum(_kept(o @ lw["wo"]), AXIS_TP)
 
 
 def _dense_ffn(x, w1, w2):
     import jax
     import jax.numpy as jnp
 
-    h = jax.nn.gelu(jnp.einsum(
+    h = jax.nn.gelu(_kept(jnp.einsum(
         "bte,ef->btf", x, w1,
-        preferred_element_type=jnp.float32)).astype(x.dtype)
-    return jax.lax.psum(h @ w2, AXIS_TP)
+        preferred_element_type=jnp.float32))).astype(x.dtype)
+    return jax.lax.psum(_kept(h @ w2), AXIS_TP)
 
 
 def _gated_ffn(x, wg, wu, wd):
@@ -487,10 +524,10 @@ def _gated_ffn(x, wg, wu, wd):
     import jax
     import jax.numpy as jnp
 
-    g = (x @ wg).astype(jnp.float32)
-    u = (x @ wu).astype(jnp.float32)
+    g = _kept(x @ wg).astype(jnp.float32)
+    u = _kept(x @ wu).astype(jnp.float32)
     h = (jax.nn.silu(g) * u).astype(x.dtype)
-    return jax.lax.psum(h @ wd, AXIS_TP)
+    return jax.lax.psum(_kept(h @ wd), AXIS_TP)
 
 
 def _route(cfg, flat, router, bias=None):
@@ -506,8 +543,8 @@ def _route(cfg, flat, router, bias=None):
     import jax
     import jax.numpy as jnp
 
-    logits = jnp.einsum("ne,ex->nx", flat, router,
-                        preferred_element_type=jnp.float32)
+    logits = _kept(jnp.einsum("ne,ex->nx", flat, router,
+                               preferred_element_type=jnp.float32))
     scores = jax.nn.softmax(logits, axis=-1) \
         if cfg.moe_score == "softmax" else jax.nn.sigmoid(logits)
     select = scores if bias is None else \
@@ -625,10 +662,10 @@ def _experts_bucketed(cfg, flat, expert, gate, we1, we2, ep_size):
 
     # native-dtype operands on the MXU, f32 accumulate + f32 gelu
     # (upcasting b/we1 would force the multi-pass f32 matmul path)
-    h = jax.nn.gelu(jnp.einsum(
+    h = jax.nn.gelu(_kept(jnp.einsum(
         "nce,nef->ncf", b, we1,
-        preferred_element_type=jnp.float32)).astype(flat.dtype)
-    y = jnp.einsum("ncf,nfe->nce", h, we2)
+        preferred_element_type=jnp.float32))).astype(flat.dtype)
+    y = _kept(jnp.einsum("ncf,nfe->nce", h, we2))
     y = jax.lax.psum(y, AXIS_TP)                           # row-parallel
 
     if ep_size > 1:
@@ -692,14 +729,10 @@ def _merge_stats(a, b):
                 else a[k] + b[k]) for k in a}
 
 
-def _stage_fn(cfg, kind, params_stage, x, tp_size, ep_size, rope=None):
-    """Run one segment's layers (this pipeline stage's share of them)
-    over x via lax.scan (weights stacked on the layer axis).  `kind` is
-    "dense" or "moe"; `rope` the rotary table where positions are
-    rotary.  Returns (x, stats of the segment's expert layers or {})."""
+def _layer_fn(cfg, kind, tp_size, ep_size, rope=None):
+    """One layer of a segment as the scan's body, (x, lw) -> (x, stats),
+    under the config's remat policy.  `kind` is "dense" or "moe"."""
     import jax
-
-    x = _pvary_all(x)
 
     # the named scopes (here, `router` .. `combine` in the expert layer,
     # and `embed` / `mtp` / `loss` / `adam` below) are metadata on the
@@ -726,11 +759,23 @@ def _stage_fn(cfg, kind, params_stage, x, tp_size, ep_size, rope=None):
                 f, stats = _gated_ffn(z, lw["wg"], lw["wu"], lw["wd"]), {}
             return h + f, stats
 
-    if cfg.remat != "none":
-        from ..executor import apply_remat
+    if cfg.remat == "none":
+        return layer
+    from ..executor import apply_remat
 
-        layer = apply_remat(layer, cfg.remat, prevent_cse=False)
+    # the blocks say by name what "dots" keeps of them (`_kept`)
+    return apply_remat(layer, cfg.remat, prevent_cse=False, named=True)
 
+
+def _stage_fn(cfg, kind, params_stage, x, tp_size, ep_size, rope=None):
+    """Run one segment's layers (this pipeline stage's share of them)
+    over x via lax.scan (weights stacked on the layer axis).  `kind` is
+    "dense" or "moe"; `rope` the rotary table where positions are
+    rotary.  Returns (x, stats of the segment's expert layers or {})."""
+    import jax
+
+    x = _pvary_all(x)
+    layer = _layer_fn(cfg, kind, tp_size, ep_size, rope)
     out, per_layer = jax.lax.scan(layer, x, params_stage)
     if not per_layer:
         return out, {}

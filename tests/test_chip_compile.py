@@ -64,9 +64,9 @@ def one_chip_mesh(topo):
                 (AXIS_DP, AXIS_PP, AXIS_TP, AXIS_SP, AXIS_EP))
 
 
-def _lm_program_text(mesh, remat, k_steps):
-    """The compiled text of the LM's Adam train program on `mesh`: K
-    steps fused (`k_steps`), or one step (None)."""
+def _lm_program(mesh, remat, k_steps):
+    """The LM's Adam train program compiled for `mesh`: K steps fused
+    (`k_steps`), or one step (None)."""
     import jax
     import jax.numpy as jnp
 
@@ -91,7 +91,7 @@ def _lm_program_text(mesh, remat, k_steps):
            "t": jax.ShapeDtypeStruct((), jnp.float32,
                                      sharding=sh["opt_state"]["t"])}
     data = jax.ShapeDtypeStruct(data_shape, jnp.int32, sharding=sh["data"])
-    return step.lower(params, opt, data, data).compile().as_text()
+    return step.lower(params, opt, data, data).compile()
 
 
 _FLASH_CALL = re.compile(
@@ -105,6 +105,19 @@ def _flash_kernels(text):
     for name, bh, t, d in _FLASH_CALL.findall(text):
         kernels.setdefault(name, set()).add((int(bh), int(t), int(d)))
     return kernels
+
+
+def _mosaic_call_sites(text):
+    """How many Mosaic kernel calls the compiled text holds (a call in a
+    loop's body is one site)."""
+    return text.count('custom_call_target="tpu_custom_call"')
+
+
+def _described_bytes(compiled):
+    """Arguments + temporaries of a program compiled for the described
+    chip: what the compiler planned, not a device reading."""
+    mem = compiled.memory_analysis()
+    return mem.argument_size_in_bytes + mem.temp_size_in_bytes
 
 
 _COMPUTATION = re.compile(r"^(ENTRY )?%?([\w.\-]+) \(.*\{\s*$")
@@ -155,8 +168,19 @@ def test_lm_step_keeps_layer_stacks_in_place(one_chip_mesh, monkeypatch,
     # that is the CPU: steer it in the test, not by an option of the
     # program, so that the Pallas kernels go through Mosaic
     monkeypatch.setattr(pa, "_on_tpu", lambda: True)
-    text = _lm_program_text(one_chip_mesh, remat, k_steps)
-    assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+    compiled = _lm_program(one_chip_mesh, remat, k_steps)
+    text = compiled.as_text()
+    # remat="dots" keeps the forward kernel's merged output and log-sums
+    # (PR 33), so the backward pass holds dq and dkv and NO second call
+    # of the forward: 3 sites where the parent had 4, as "full" still has
+    assert _mosaic_call_sites(text) == (3 if remat == "dots" else 4)
+    if (k_steps, remat) == (K_STEPS, "dots"):
+        # ... and pays for them with `o @ wo`, which is rebuilt: the
+        # parent's described 13.2965 GiB + 0.0594.  A later change that
+        # re-grows the saved set (the naive way costs +0.43 to +0.76
+        # GiB) fails here before `peak_hbm_gib` refuses it on the chip
+        assert _described_bytes(compiled) <= (13.2965 + 0.08) * 2 ** 30, \
+            _described_bytes(compiled)
     # the three flash kernels, by name, at the cell's [128, 1024, 64]
     # (16 heads x 8 sequences): Mosaic lowered the causal walk's
     # sub-tiled diagonal at d=64 for the described chip
@@ -242,11 +266,13 @@ def test_glm_cell_program_fits_one_chip_and_keeps_its_kernels(
     data = jax.ShapeDtypeStruct((k, b, config["input"]["length"]),
                                 jnp.int32, sharding=sh["data"])
     compiled = step.lower(params, opt, data, data).compile()
-    mem = compiled.memory_analysis()
-    need = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    need = _described_bytes(compiled)
     assert need < _CHIP_BYTES, "arguments + temporaries %.3e bytes" % need
     # a full chip, as a training job's is (PERF.md: 16.0e9 of 16.9e9)
     assert need > 0.75 * _CHIP_BYTES, need
+    # no more than the parent of PR 33 (16 027 537 920 described bytes):
+    # the kernel's kept output is paid for by `c_q @ wq_b`, rebuilt
+    assert need <= 16027537920 + 0.05 * 2 ** 30, need
 
     text = compiled.as_text()
     heads = config["num_attention_heads"] * b
@@ -255,6 +281,9 @@ def test_glm_cell_program_fits_one_chip_and_keeps_its_kernels(
     assert kernels == {"mx_flash_fwd": want, "mx_flash_dq": want,
                        "mx_flash_dkv": want}, kernels
     assert "ragged-dot" in text, "the grouped products left Mosaic"
+    # one forward call site fewer than before PR 33 (36): the expert
+    # layers' backward scan no longer runs the forward kernel again
+    assert _mosaic_call_sites(text) == 35
 
     layers = config["num_hidden_layers"] - config["first_k_dense_replace"]
     whiles, copies = _whiles_and_stack_copies(text, layers)

@@ -214,12 +214,14 @@ def test_pallas_backward_kernels_match_jnp_sweeps(causal, monkeypatch):
                               .astype(np.float32)) for _ in range(4))
     scale = 1.0 / np.sqrt(32)
     out, lse = fa._reference_attention_lse(q, k, v, scale, causal)
-    got = fa._flash_backward_pallas(q, k, v, g, out, lse, scale,
+    delta = (out * g).sum(-1)
+    got = fa._flash_backward_pallas(q, k, v, g, delta, lse, scale,
                                     causal, 64, 64)
     # jnp sweeps: disable the pallas route for the direct comparison
     monkeypatch.setenv("MXTPU_NO_PALLAS", "1")
     monkeypatch.delenv("MXTPU_PALLAS_INTERPRET", raising=False)
-    ref = fa._flash_bwd(scale, causal, 64, 64, (q, k, v, out, lse), g)
+    ref = fa._flash_bwd_sweeps(q, k, v, g, delta, lse, scale, causal,
+                               64, 64)
     for a, b, name in zip(got, ref, ("dq", "dk", "dv")):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-4, atol=2e-5,
@@ -310,3 +312,77 @@ def test_flash_tiles_stats(causal, blocks, want):
         x, x, x)
     got = tuple(profiler.get_stat(n) - b for n, b in zip(names, before))
     assert got == want
+
+
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("d", [64, 128, 256])
+def test_bthd_entry_matches_reference(d, causal):
+    """`flash_attention_bthd`, the entry on the activations' layout (q,
+    k, v [B, T, H, D] -> [B, T, H * D]): forward, the log-sums its
+    `custom_vjp` keeps, and all three gradients against
+    `_reference_attention_lse`; the residuals are q, k, v AS GIVEN, the
+    merged output and the log-sums."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxtpu.ops import pallas_attention as fa
+
+    b, t, h = 2, 256, 2
+    rng = np.random.RandomState(d)
+    q, k, v = (jnp.asarray(rng.normal(0, 1, (b, t, h, d))
+                           .astype(np.float32)) for _ in range(3))
+    w = jnp.asarray(rng.normal(0, 1, (b, t, h * d)).astype(np.float32))
+    scale = 1.0 / np.sqrt(d)
+
+    def split(x):
+        return x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
+
+    def ref(q, k, v):
+        out, lse = fa._reference_attention_lse(split(q), split(k),
+                                               split(v), scale, causal)
+        return out.reshape(b, h, t, d).transpose(0, 2, 1, 3) \
+            .reshape(b, t, h * d), lse
+
+    (gold, gold_lse), vjp_ref = jax.vjp(ref, q, k, v)
+    got, vjp = jax.vjp(lambda q, k, v: fa.flash_attention_bthd(
+        q, k, v, causal=causal, block_q=128, block_k=128), q, k, v)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(gold),
+                               rtol=2e-4, atol=2e-5)
+    for a, g, name in zip(vjp(w), vjp_ref((w, jnp.zeros_like(gold_lse))),
+                          "qkv"):
+        assert a.shape == (b, t, h, d)
+        np.testing.assert_allclose(np.asarray(a), np.asarray(g),
+                                   rtol=2e-3, atol=2e-4,
+                                   err_msg="d%s" % name)
+    out, res = fa._flash_fwd(q, k, v, scale, causal, 128, 128)
+    assert res[0] is q and res[1] is k and res[2] is v
+    assert res[3].shape == (b, t, h * d) and res[4].shape == (b * h, t)
+    np.testing.assert_allclose(np.asarray(res[4]), np.asarray(gold_lse),
+                               rtol=2e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["plain", "for_grad"])
+def test_flash_fwd_stats(grad):
+    """`flash_fwd_traced` / `flash_fwd_named`: a forward kernel traced
+    outside differentiation is not named; differentiating traces the
+    `custom_vjp`'s forward rule, whose output and log-sums go out under
+    `FLASH_OUT` / `FLASH_LSE`."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxtpu import profiler
+    from mxtpu.ops import pallas_attention as fa
+
+    x = jnp.ones((1, 128, 2, 64), jnp.float32)
+
+    def f(q):
+        return fa.flash_attention_bthd(q, x, x, causal=True).sum()
+
+    names = ("flash_fwd_traced", "flash_fwd_named")
+    before = [profiler.get_stat(n) for n in names]
+    jaxpr = jax.make_jaxpr(jax.grad(f) if grad else f)(x)
+    got = tuple(profiler.get_stat(n) - b for n, b in zip(names, before))
+    assert got == ((1, 1) if grad else (1, 0))
+    named = {e.params["name"] for e in jaxpr.eqns
+             if e.primitive.name == "name"}
+    assert named == ({fa.FLASH_OUT, fa.FLASH_LSE} if grad else set())
