@@ -88,6 +88,12 @@ class _Sizes(object):
             # cells' head widths, so a layout slip reads here before a
             # cell's `correct` does
             self.attn_bthd = [((128, 1024, 64), 16), ((4, 4096, 256), 2)]
+            # (batch, T, heads, q.k width, v width): latent attention
+            # whose q.k is wider than v, padded to the kernels' one width
+            self.attn_unequal = (2, 2048, 4, 192, 128)
+            # (batch, T, heads, d, chunk, re-basing): the chunked KDA
+            # core against the recurrence, at the published head
+            self.kda = (1, 2048, 4, 128, 64, 16)
             self.lm = dict(vocab=8192, d_model=1024, n_heads=8,
                            n_layers=8, d_ff=4096, max_len=1024)
             self.lm_batch, self.lm_k = 8, 2
@@ -98,6 +104,8 @@ class _Sizes(object):
             self.attn_shapes = [(6, 128, 32), (2, 160, 16), (2, 128, 64)]
             self.attn_ragged, self.attn_ragged_block = (2, 50, 16), 32
             self.attn_bthd = [((4, 128, 64), 2), ((2, 128, 256), 2)]
+            self.attn_unequal = (1, 128, 2, 24, 16)
+            self.kda = (1, 40, 2, 16, 16, 4)
             self.lm = dict(vocab=64, d_model=32, n_heads=2, n_layers=2,
                            d_ff=64, max_len=64)
             self.lm_batch, self.lm_k = 4, 2
@@ -376,17 +384,117 @@ def _attention_check(shape, block=512, heads=None):
         jax.jit(jax.grad(loss(flash), argnums=(0, 1, 2)))(q, k, v)
     want = (jax.jit(ref)(q, k, v),) + \
         jax.jit(jax.grad(loss(ref), argnums=(0, 1, 2)))(q, k, v)
+    # bf16 keeps 8 bits: 2^-8 per rounding, a few roundings deep
+    return _worst_error(got, want, ("out", "dq", "dk", "dv"),
+                        "flash at (bh, T, d)=%s" % (shape,), 3e-2)
+
+
+def _on_one_device_mesh(fn, *args):
+    """fn(*args) inside shard_map on a one-device mesh: the LM's layer
+    functions name the mesh's axes."""
+    from jax.sharding import PartitionSpec as P
+
+    return jax.jit(jax.shard_map(
+        fn, mesh=_mesh(), in_specs=tuple(P() for _ in args),
+        out_specs=P(), check_vma=False))(*args)
+
+
+def _worst_error(got, want, names, what, tol):
     worst = 0.0
-    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+    for name, a, b in zip(names, got, want):
         a = np.asarray(a.astype(jnp.float32))
         b = np.asarray(b.astype(jnp.float32))
-        _require(np.isfinite(a).all(), "%s not finite at %s" % (name, shape))
+        _require(np.isfinite(a).all(), "%s: %s not finite" % (what, name))
         err = float(np.abs(a - b).max() / (np.abs(b).max() + 1e-6))
-        # bf16 keeps 8 bits: 2^-8 per rounding, a few roundings deep
-        _require(err < 3e-2, "flash %s off the reference by %.3g of its "
-                 "range at (bh, T, d)=%s" % (name, err, shape))
+        _require(err < tol, "%s: %s off the reference by %.3g of its range"
+                 % (what, name, err))
         worst = max(worst, err)
     return worst
+
+
+def _unequal_width_check(shape):
+    """`transformer._padded_attention` (q.k wider than v: zero columns
+    up to the kernels' one width) against the materializing reference
+    at the widths as they are; forward and gradients."""
+    b, t, h, dqk, dv = shape
+    rng = np.random.RandomState(dqk + dv)
+    q, k = (jnp.asarray(rng.normal(0, 1, (b, t, h, dqk)), jnp.bfloat16)
+            for _ in range(2))
+    v = jnp.asarray(rng.normal(0, 1, (b, t, h, dv)), jnp.bfloat16)
+    cot = jnp.asarray(rng.normal(0, 1, (b, t, h * dv)), jnp.float32)
+
+    def loss(fn):
+        return lambda q, k, v: (fn(q, k, v).astype(jnp.float32) * cot).sum()
+
+    def ref(q, k, v):
+        o = pa._reference_attention(
+            *(a.transpose(0, 2, 1, 3).reshape(b * h, t, -1)
+              for a in (q, k, v)), dqk ** -0.5, True)
+        return o.reshape(b, h, t, dv).transpose(0, 2, 1, 3).reshape(
+            b, t, h * dv)
+
+    def both(fn):
+        return lambda q, k, v: (fn(q, k, v),) + jax.grad(
+            loss(fn), argnums=(0, 1, 2))(q, k, v)
+
+    got = _on_one_device_mesh(both(tf._padded_attention), q, k, v)
+    want = jax.jit(both(ref))(q, k, v)
+    return _worst_error(got, want, ("out", "dq", "dk", "dv"),
+                        "attention at widths %s" % (shape,), 3e-2)
+
+
+def _kda_check(shape):
+    """The chunked KDA core (`transformer._kda_chunked`, bfloat16
+    operands in its products, float32 decays, solve and state) against
+    the gated delta rule run one token at a time in float32; forward
+    and every input's gradient.  Returns (worst error, the largest
+    re-based span's nats)."""
+    b, t, h, d, chunk, rebase = shape
+    rng = np.random.RandomState(d + t)
+    q, k, v = (rng.normal(0, 1, (b, t, h, d)) for _ in range(3))
+    q /= np.linalg.norm(q, axis=-1, keepdims=True) * d ** 0.5
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    # decays over the gate's whole range, some channels at the floor
+    g = -5.0 / (1.0 + np.exp(-4.0 * rng.normal(0, 1, (b, t, h, d))))
+    g[..., :2] = -5.0
+    beta = 1.0 / (1.0 + np.exp(-rng.normal(0, 1, (b, t, h))))
+    args = tuple(jnp.asarray(a, jnp.float32) for a in (q, k, v, g, beta))
+    cot = jnp.asarray(rng.normal(0, 1, (b, t, h, d)), jnp.float32)
+    hi = jax.lax.Precision.HIGHEST
+
+    def recurrence(q, k, v, g, beta):
+        def token(S, xs):
+            qt, kt, vt, gt, bt = xs
+            S = jnp.exp(gt)[..., None] * S
+            u = bt[..., None] * (vt - jnp.einsum("bhkv,bhk->bhv", S, kt,
+                                                 precision=hi))
+            S = S + kt[..., :, None] * u[..., None, :]
+            return S, jnp.einsum("bhkv,bhk->bhv", S, qt, precision=hi)
+
+        o = jax.lax.scan(token, jnp.zeros((b, h, d, d), jnp.float32),
+                         tuple(jnp.moveaxis(a, 1, 0)
+                               for a in (q, k, v, g, beta)))[1]
+        return jnp.moveaxis(o, 0, 1)
+
+    def chunked(*a):
+        return tf._kda_chunked(*a, chunk, rebase, jnp.bfloat16)
+
+    def both(fn):
+        def run(*a):
+            (_, aux), grads = jax.value_and_grad(
+                lambda *a: (lambda o: ((o[0] * cot).sum(), o))(fn(*a)),
+                argnums=(0, 1, 2, 3, 4), has_aux=True)(*a)
+            return aux, grads
+        return run
+
+    (o, span), grads = _on_one_device_mesh(both(chunked), *args)
+    (want_o, _), want = jax.jit(both(
+        lambda *a: (recurrence(*a), 0.0)))(*args)
+    # bf16 operands: 2^-8 a rounding, through a 64-row solve
+    err = _worst_error((o,) + grads, (want_o,) + want,
+                       ("out", "dq", "dk", "dv", "dg", "dbeta"),
+                       "chunked KDA at %s" % (shape,), 5e-2)
+    return err, float(span)
 
 
 def _mesh(**axes):
@@ -416,6 +524,11 @@ def phase3_lm(sizes, meter):
     errs = [_attention_check(s) for s in sizes.attn_shapes] + \
         [_attention_check(s, heads=h) for s, h in sizes.attn_bthd]
     info["attn_max_err"] = round(max(errs), 5)
+    info["attn_unequal_widths_err"] = round(
+        _unequal_width_check(sizes.attn_unequal), 5)
+    err, span = _kda_check(sizes.kda)
+    info["kda_chunked_err"], info["kda_span_nats"] = round(err, 5), \
+        round(span, 2)
     stats1 = dict(profiler.stats())
     _require(stats1.get("flash_attention_pallas", 0)
              > stats0.get("flash_attention_pallas", 0)

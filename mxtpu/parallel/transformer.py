@@ -33,7 +33,7 @@ from .ring_attention import ring_attention, _match_vma, _pallas_enabled
 __all__ = ["TransformerConfig", "init_params", "param_specs",
            "make_train_step", "make_fused_train_steps", "make_forward",
            "dryrun", "init_opt_state", "param_shapes", "MOE_STATS",
-           "publish_moe_stats"]
+           "GROUP_STATS", "KDA_STATS", "publish_moe_stats"]
 
 _NEG_INF = -1e30
 # params below this element count keep replicated optimizer state
@@ -62,7 +62,8 @@ class TransformerConfig:
     # log-sums, and recomputes the elementwise chains between them
     # and ONE product per attention block, the cheapest to rebuild
     # per byte, which pays for the kernel's output: `o @ wo` in
-    # `_attention`, `c_q @ wq_b` in `_mla`.  So the backward pass
+    # `_attention`, `c_q @ wq_b` in `_mla` (`c_kv @ wkv_b` where q has
+    # no bottleneck).  So the backward pass
     # runs no second forward kernel.  Analog of the reference's
     # MXNET_BACKWARD_DO_MIRROR (docs/faq/env_var.md) which this
     # repo's symbolic executor exposes as MXTPU_BACKWARD_DO_MIRROR;
@@ -114,6 +115,36 @@ class TransformerConfig:
     # (DeepSeek-V3's form) after the stack, on the shared embedding and
     # head: params "mtp.<leaf>"; its loss is added times mtp_weight
     mtp_weight: float = 0.3
+    n_group: int = 1           # group-limited selection (DeepSeek-V3's
+    topk_group: int = 1        # `noaux_tc`): the experts lie in n_group
+    # groups of consecutive ids, a group's score is the sum of its two
+    # best selection scores, and top_k is taken inside the topk_group
+    # best groups.  n_group = 1 is the plain top_k, traced as before
+
+    # ---- a stack of TWO mixer kinds (Kimi Linear's hybrid): with
+    # kda_period = p > 0 the layer of published index i runs `attention`
+    # where (i + 1) % p == 0 and Kimi Delta Attention (`_kda`) otherwise.
+    # Each run of layers of one (mixer, ffn) kind is a segment; layers of
+    # one kind share stacked leaves: KDA layers are "kda.<leaf>" (leading
+    # dense ones "dense.kda.<leaf>")
+    kda_period: int = 0
+    layer_ids: Tuple[int, ...] = ()     # the published indices of the
+    # n_layers held here, ascending (a pipeline stage's share of a deeper
+    # model; the first n_dense_layers of them are the dense ones);
+    # () = 0 .. n_layers - 1
+    kda_head_dim: int = 0      # d_k = d_v of a KDA head; 0 = d_model //
+    # n_heads
+    kda_conv: int = 4          # taps of the causal depthwise convolutions
+    kda_gate_floor: float = -5.0    # the log-decay of one step lies in
+    # (floor, 0): floor * sigmoid(exp(A_log) * (x Wa + dt_bias))
+    kda_chunk: int = 64        # tokens a chunk of the chunked form holds
+    kda_rebase: int = 16       # ... and the steps after which the
+    # cumulative log-decay is re-based: a span's rebase * -floor nats
+    # (80) are split about its middle, so `exp` sees +-40 at most
+    qk_norm: bool = False      # mla: q_nope and k_nope RMS-normed per
+    # head, one [qk_nope_dim] scale each
+    head_gate: bool = False    # mla: a sigmoid gate per head on the
+    # attention's output, from x (KDA always has one)
 
     def __post_init__(self):
         from ..executor import _REMAT_POLICIES
@@ -126,13 +157,30 @@ class TransformerConfig:
         if self.attention not in ("mha", "mla"):
             bad = "attention must be 'mha' or 'mla'"
         elif self.attention == "mla" and (
-                min(self.q_lora_rank, self.kv_lora_rank, self.qk_nope_dim,
-                    self.v_head_dim) < 1 or self.qk_rope_dim < 2
-                or self.qk_rope_dim % 2
-                or self.qk_nope_dim + self.qk_rope_dim != self.v_head_dim):
-            bad = ("attention='mla' needs q_lora_rank, kv_lora_rank, "
-                   "qk_nope_dim, an even qk_rope_dim, and qk_nope_dim + "
-                   "qk_rope_dim == v_head_dim (the kernels' one width)")
+                min(self.kv_lora_rank, self.qk_nope_dim,
+                    self.v_head_dim) < 1 or self.q_lora_rank < 0
+                or self.qk_rope_dim < 2 or self.qk_rope_dim % 2):
+            bad = ("attention='mla' needs kv_lora_rank, qk_nope_dim, "
+                   "v_head_dim and an even qk_rope_dim (q_lora_rank 0 "
+                   "is q from one matrix)")
+        elif self.attention != "mla" and (self.qk_norm or self.head_gate):
+            bad = "qk_norm and head_gate are latent attention's"
+        elif self.kda_period and (
+                self.kda_period < 2 or self.kda_chunk < 1
+                or self.kda_rebase < 1 or self.kda_chunk % self.kda_rebase
+                or not 0 < -self.kda_gate_floor * self.kda_rebase <= 80
+                or self.kda_conv < 1
+                or (self.kda_head_dim or self.d_model // self.n_heads) < 1):
+            bad = ("kda_period needs a period >= 2, kda_chunk a multiple "
+                   "of kda_rebase, and -kda_gate_floor * kda_rebase in "
+                   "(0, 80]: exp() of a re-based span must stay finite "
+                   "in float32")
+        elif self.layer_ids and (
+                len(self.layer_ids) != self.n_layers
+                or list(self.layer_ids) != sorted(set(self.layer_ids))
+                or self.layer_ids[0] < 0):
+            bad = ("layer_ids are the n_layers published indices held "
+                   "here, ascending")
         elif self.ffn not in ("gelu", "swiglu"):
             bad = "ffn must be 'gelu' or 'swiglu'"
         elif self.moe_score not in ("softmax", "sigmoid"):
@@ -150,6 +198,15 @@ class TransformerConfig:
             elif self.expert_first < 0 or \
                     self.expert_first + held > self.n_experts:
                 bad = "the held experts lie outside 0..n_experts"
+            elif self.n_group < 1 or self.n_experts % self.n_group \
+                    or not 1 <= self.topk_group <= self.n_group \
+                    or (self.n_group > 1 and (
+                        self.n_experts // self.n_group < 2 or self.top_k
+                        > self.topk_group * (self.n_experts
+                                             // self.n_group))):
+                bad = ("n_group must divide n_experts into groups of at "
+                       "least 2, topk_group lie in 1..n_group, and top_k "
+                       "fit inside the kept groups")
             elif self.ffn == "gelu" and (
                     self.top_k != 1 or self.experts_held
                     or self.n_shared_experts):
@@ -161,40 +218,96 @@ class TransformerConfig:
             raise MXNetError("TransformerConfig: " + bad)
 
 
+def _kind_parts(cfg: TransformerConfig, kind: str) -> Tuple[str, str]:
+    """(mixer, feed-forward) of a segment's kind (see `_segments`): the
+    mixer "kda", or the config's `attention`; "dense" or "moe"."""
+    kda, _, ffn = kind.rpartition("+")
+    return (kda or cfg.attention), ffn
+
+
 def _segments(cfg: TransformerConfig):
     """The stack in segments, each a homogeneous run of layers with a
-    `lax.scan` of its own: [(parameter-name prefix, kind, layers)], kind
-    "dense" or "moe".  The model's repeated layer kind keeps the bare
-    leaf names; leading dense layers are "dense.<leaf>", the multi-
-    token-prediction block's layer "mtp.<leaf>"."""
-    kind = "moe" if cfg.n_experts else "dense"
-    segs = []
-    if cfg.n_dense_layers:
-        segs.append(("dense.", "dense", cfg.n_dense_layers))
-    segs.append(("", kind, cfg.n_layers - cfg.n_dense_layers))
+    `lax.scan` of its own, in the model's order: [(parameter-name
+    prefix, kind, first, layers)].  `kind` is "dense" or "moe" (the
+    feed-forward), with "kda+" in front where the mixer is Kimi Delta
+    Attention and not the config's `attention`.  Layers of one prefix
+    share stacked leaves and a segment holds rows [first, first +
+    layers) of them (a stack of two mixer kinds comes back to a kind
+    once per period).  The model's repeated layer kind keeps the bare
+    leaf names; leading dense layers are "dense.<leaf>", KDA layers
+    "kda.<leaf>" behind that, the multi-token-prediction block's layer
+    "mtp.<leaf>"."""
+    ffn = "moe" if cfg.n_experts else "dense"
+    ids = cfg.layer_ids or range(cfg.n_layers)
+    segs, rows = [], {}
+    for j, i in enumerate(ids):
+        lead = j < cfg.n_dense_layers
+        kda = bool(cfg.kda_period) and (i + 1) % cfg.kda_period != 0
+        prefix = ("dense." if lead else "") + ("kda." if kda else "")
+        kind = ("kda+" if kda else "") + ("dense" if lead else ffn)
+        if segs and segs[-1][0] == prefix:
+            segs[-1][3] += 1
+        else:
+            segs.append([prefix, kind, rows.get(prefix, 0), 1])
+        rows[prefix] = rows.get(prefix, 0) + 1
     if cfg.mtp_depth:
-        segs.append(("mtp.", kind, cfg.mtp_depth))
-    return segs
+        segs.append(["mtp.", ffn, 0, cfg.mtp_depth])
+    return [tuple(seg) for seg in segs]
+
+
+def _stacks(cfg: TransformerConfig):
+    """{prefix: (kind, layers)}: the stacked leaves' depth by prefix,
+    over all of the prefix's segments."""
+    out = {}
+    for prefix, kind, _, n in _segments(cfg):
+        out[prefix] = (kind, out.get(prefix, (kind, 0))[1] + n)
+    return out
 
 
 def _layer_leaves(cfg: TransformerConfig, kind: str):
-    """{leaf: (shape, spec, fan_in)} of ONE layer of `kind`: Megatron
-    layout on tp (columns of the first product, rows of the last),
-    experts on ep.  fan_in None marks a norm scale (ones)."""
+    """{leaf: (shape, spec, fan_in)} of ONE layer of `kind` (see
+    `_segments`): Megatron layout on tp (columns of the first product,
+    rows of the last), experts on ep.  fan_in None marks a norm scale
+    (ones), "A_log" the KDA heads' decay rates (log of U(1, 16)),
+    "dt_bias" the decay gate's bias (the inverse softplus of a step
+    drawn log-uniformly from (1e-3, 1e-1): slow decays at the start)."""
     E, H = cfg.d_model, cfg.n_heads
     col, row = (None, AXIS_TP), (AXIS_TP, None)
     out = {"ln1": ((E,), (None,), None), "ln2": ((E,), (None,), None)}
-    if cfg.attention == "mla":
+    mixer, kind = _kind_parts(cfg, kind)
+    if mixer == "kda":
+        d = cfg.kda_head_dim or E // H
+        for n in ("q", "k", "v"):
+            out["w" + n] = ((E, H * d), col, E)
+            out["conv_" + n] = ((cfg.kda_conv, H * d), col, cfg.kda_conv)
+        out.update({
+            "wa": ((E, H * d), col, E),          # the decay gate, full
+            "A_log": ((H,), (AXIS_TP,), "A_log"),
+            "dt_bias": ((H * d,), (AXIS_TP,), "dt_bias"),
+            "wb": ((E, H), col, E),              # write strength
+            "w_gate": ((E, H), col, E),          # the output's head gate
+            "o_norm": ((d,), (None,), None),
+            "wo": ((H * d, E), row, H * d)})
+    elif mixer == "mla":
         ql, kvl = cfg.q_lora_rank, cfg.kv_lora_rank
         dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+        if ql:
+            out.update({
+                "wq_a": ((E, ql), (None, None), E),
+                "q_norm": ((ql,), (None,), None),
+                "wq_b": ((ql, H * (dn + dr)), col, ql)})
+        else:
+            out["wq"] = ((E, H * (dn + dr)), col, E)
         out.update({
-            "wq_a": ((E, ql), (None, None), E),
-            "q_norm": ((ql,), (None,), None),
-            "wq_b": ((ql, H * (dn + dr)), col, ql),
             "wkv_a": ((E, kvl + dr), (None, None), E),
             "kv_norm": ((kvl,), (None,), None),
             "wkv_b": ((kvl, H * (dn + dv)), col, kvl),
             "wo": ((H * dv, E), row, H * dv)})
+        if cfg.qk_norm:
+            out["q_nope_norm"] = ((dn,), (None,), None)
+            out["k_nope_norm"] = ((dn,), (None,), None)
+        if cfg.head_gate:
+            out["w_gate"] = ((E, H), col, E)
     else:
         out.update({n: ((E, E), col, E) for n in ("wq", "wk", "wv")})
         out["wo"] = ((E, E), row, E)
@@ -230,17 +343,18 @@ def _leaves(cfg: TransformerConfig, pp: int):
     if cfg.n_layers % pp:
         raise MXNetError("n_layers=%d not divisible by pp=%d"
                          % (cfg.n_layers, pp))
-    segs = _segments(cfg)
-    if len(segs) > 1 and pp > 1:
-        raise MXNetError("a stack in segments (leading dense layers, a "
-                         "multi-token-prediction block) needs pp = 1")
+    stacks = _stacks(cfg)
+    if len(stacks) > 1 and pp > 1:
+        raise MXNetError("a stack in segments (leading dense layers, two "
+                         "mixer kinds, a multi-token-prediction block) "
+                         "needs pp = 1")
     E, V = cfg.d_model, cfg.vocab
     out = {"embed": ((V, E), P(AXIS_TP, None), E),   # vocab-sharded
            "ln_f": ((E,), P(None), None),
            "unembed": ((E, V), P(None, AXIS_TP), E)}
     if cfg.attention == "mha":                     # rotary: no table
         out["pos"] = ((cfg.max_len, E), P(None, None), E)
-    for prefix, kind, n in segs:
+    for prefix, (kind, n) in stacks.items():
         for leaf, (shape, spec, fan_in) in _layer_leaves(cfg,
                                                          kind).items():
             out[prefix + leaf] = ((pp, n // pp) + shape,
@@ -252,17 +366,33 @@ def _leaves(cfg: TransformerConfig, pp: int):
     return out
 
 
-def _has_stats(cfg: TransformerConfig) -> bool:
-    """Whether the expert layers run the grouped, dropless dispatch
-    (`_experts_grouped`: gated experts), whose step returns the routed
-    experts' per-step counters (`MOE_STATS`) beside its loss."""
-    return bool(cfg.n_experts and cfg.ffn == "swiglu")
-
-
 # per step, over every expert layer (the MTP block's too): token-expert
 # pairs the router put in the held range; tokens routed (tokens x expert
 # layers); the fullest held expert's pairs in any one layer
 MOE_STATS = ("moe_pairs", "moe_tokens", "moe_load_max")
+# with group-limited selection: tokens x expert layers whose kept groups
+# include the group of the first expert held here
+GROUP_STATS = ("moe_groups_kept_here",)
+# per step, over every KDA layer: tokens mixed; chunks scanned; the
+# largest |cumulative log-decay| of any re-based span, in nats (what
+# `exp` is given: 80 is the config's bound, 88.7 float32's cliff)
+KDA_STATS = ("kda_tokens", "kda_chunks", "kda_decay_span_max")
+_WATERMARKS = ("moe_load_max", "kda_decay_span_max")    # max, not sum
+
+
+def _stat_names(cfg: TransformerConfig) -> Tuple[str, ...]:
+    """The per-step counters the step returns beside its loss: the
+    routed experts' where the expert layers run the grouped, dropless
+    dispatch (`_experts_grouped`: gated experts), the KDA layers' where
+    the stack has them.  () for every other config, whose program's
+    outputs are as they were."""
+    names = ()
+    if cfg.n_experts and cfg.ffn == "swiglu":
+        names += MOE_STATS + (GROUP_STATS if cfg.n_group > 1 else ())
+    if any(_kind_parts(cfg, kind)[0] == "kda"
+           for _, kind, _, _ in _segments(cfg)):
+        names += KDA_STATS
+    return names
 
 
 # ---------------------------------------------------------------------------
@@ -285,10 +415,17 @@ def init_params(cfg: TransformerConfig, mesh, seed: int = 0):
     out = {}
     for i, (name, (shape, spec, fan_in)) in enumerate(
             sorted(leaves.items())):
+        k = ks[i] if i < 16 else jax.random.fold_in(key, i)
         if fan_in is None:                # norm scales start at one
             arr = jnp.ones(shape, dt)
+        elif fan_in == "A_log":           # decay rates in (1, 16)
+            arr = jnp.log(jax.random.uniform(k, shape, jnp.float32, 1.0,
+                                             16.0)).astype(dt)
+        elif fan_in == "dt_bias":
+            step = jnp.exp(jax.random.uniform(
+                k, shape, jnp.float32, jnp.log(1e-3), jnp.log(1e-1)))
+            arr = (step + jnp.log(-jnp.expm1(-step))).astype(dt)
         else:
-            k = ks[i] if i < 16 else jax.random.fold_in(key, i)
             arr = (jax.random.normal(k, shape, jnp.float32)
                    * (1.0 / fan_in) ** 0.5).astype(dt)
         out[name] = jax.device_put(arr, NamedSharding(mesh, spec))
@@ -407,10 +544,11 @@ def _kept(y):
     return checkpoint_name(y, REMAT_DOT)
 
 
-def _causal_attention(q, k, v):
+def _causal_attention(q, k, v, scale=None):
     """Causal attention over the ring-sharded sequence.  q, k, v: [B,
     T_loc, h, D], as the projections leave them; returns [B, T_loc, h *
-    D], what the out-projection reads.  On one sequence shard with the
+    D], what the out-projection reads.  `scale` multiplies the scores
+    (None: D ** -0.5).  On one sequence shard with the
     kernel available this is the flash kernels' entry that keeps this
     layout (its `custom_vjp` holds the merged output and the log-sums
     under names `remat="dots"` keeps); the sp > 1 ring, and a host
@@ -420,11 +558,37 @@ def _causal_attention(q, k, v):
     if jax.lax.axis_size(AXIS_SP) == 1 and _pallas_enabled():
         from ..ops.pallas_attention import flash_attention_bthd
 
-        return flash_attention_bthd(q, k, v, causal=True)
+        return flash_attention_bthd(q, k, v, sm_scale=scale, causal=True)
     B, T, h, D = v.shape
     o = ring_attention(*(a.transpose(0, 2, 1, 3) for a in (q, k, v)),
-                       axis_name=AXIS_SP, causal=True)
+                       axis_name=AXIS_SP, causal=True, scale=scale)
     return o.transpose(0, 2, 1, 3).reshape(B, T, h * D)
+
+
+def _padded_attention(q, k, v):
+    """`_causal_attention` where q.k and v differ in width (q, k: [B, T,
+    h, dqk]; v: [B, T, h, dv]; returns [B, T, h * dv]).  The flash
+    kernels take ONE width: q, k and v are padded with zero columns to
+    the next lane multiple that holds both and the output's first dv
+    columns are taken.  Exact: a zero column adds nothing to a score,
+    and a zero column of v gives a zero column of output; the scores'
+    scale stays dqk ** -0.5.  `mla_padded_width` (`mx.profiler` stats,
+    set when traced) holds the padded width."""
+    import jax.numpy as jnp
+
+    from .. import profiler
+
+    B, T, h, dqk = q.shape
+    dv = v.shape[-1]
+    wide = -(-max(dqk, dv) // 128) * 128
+    profiler.max_stat("mla_padded_width", wide)
+
+    def padded(a):
+        return jnp.pad(a, ((0, 0),) * 3 + ((0, wide - a.shape[-1]),))
+
+    o = _causal_attention(padded(q), padded(k), padded(v),
+                          scale=dqk ** -0.5)
+    return o.reshape(B, T, h, wide)[..., :dv].reshape(B, T, h * dv)
 
 
 def _attention(cfg, x, wq, wk, wv, wo, tp_size):
@@ -473,12 +637,18 @@ def _rotate(x, rope):
 def _mla(cfg, x, lw, tp_size, rope):
     """Latent attention in its decompressed (training) form.  x: [B,
     T_loc, E].  q goes through a rank-q_lora_rank bottleneck with a norm
-    in it; keys and values come from ONE compressed row per token
-    (kv_lora_rank wide, normed) plus one rotary key of qk_rope_dim that
-    all heads share.  Heads are column-sharded over tp in wq_b / wkv_b
-    and row-sharded in wo; the compressions are replicated.  q.k is
-    qk_nope_dim + qk_rope_dim wide, which the config holds equal to
-    v_head_dim, so the flash kernels serve as they are."""
+    in it, or with q_lora_rank = 0 comes from ONE matrix; keys and
+    values come from ONE compressed row per token (kv_lora_rank wide,
+    normed) plus one rotary key of qk_rope_dim that all heads share.
+    Heads are column-sharded over tp in wq_b / wkv_b and row-sharded in
+    wo; the compressions are replicated.  `qk_norm` RMS-norms q_nope and
+    k_nope per head; `head_gate` multiplies each head's output by a
+    sigmoid gate from x.
+
+    The flash kernels take ONE width for q.k and v.  Where qk_nope_dim +
+    qk_rope_dim == v_head_dim they serve as they are; where the widths
+    differ (192 and 128) q, k and v go to them padded
+    (`_padded_attention`)."""
     import jax
     import jax.numpy as jnp
 
@@ -491,19 +661,221 @@ def _mla(cfg, x, lw, tp_size, rope):
         return y.reshape(B, T, h, d)
 
     rope_h = tuple(r[:, None] for r in rope)    # [T, 1, dr / 2]: per head
-    c_q = _rms_norm(_kept(x @ lw["wq_a"]), lw["q_norm"], cfg.norm_eps)
-    # not `_kept`: as wide as the attention's merged output, which IS
-    # kept under "dots", and the cheapest product per byte to rebuild
-    q = heads(c_q @ lw["wq_b"], dn + dr)
-    q = jnp.concatenate([q[..., :dn], _rotate(q[..., dn:], rope_h)], -1)
+    # under "dots" each block gives back (does not `_kept`) the ONE
+    # product that is cheapest to rebuild per byte, which pays for the
+    # attention's merged output, which IS kept: with a q bottleneck that
+    # is `c_q @ wq_b`, without one `c_kv @ wkv_b`
+    if cfg.q_lora_rank:
+        c_q = _rms_norm(_kept(x @ lw["wq_a"]), lw["q_norm"], cfg.norm_eps)
+        q = heads(c_q @ lw["wq_b"], dn + dr)
+    else:
+        q = heads(_kept(x @ lw["wq"]), dn + dr)
+    q_nope = q[..., :dn]
+    if cfg.qk_norm:
+        q_nope = _rms_norm(q_nope, lw["q_nope_norm"], cfg.norm_eps)
+    q = jnp.concatenate([q_nope, _rotate(q[..., dn:], rope_h)], -1)
     ckv = _kept(x @ lw["wkv_a"])
     c_kv = _rms_norm(ckv[..., :kvl], lw["kv_norm"], cfg.norm_eps)
     k_pe = _rotate(ckv[..., kvl:], rope)[:, :, None]      # [B, T, 1, dr]
-    kv = heads(_kept(c_kv @ lw["wkv_b"]), dn + dv)
+    kv = c_kv @ lw["wkv_b"]
+    kv = heads(_kept(kv) if cfg.q_lora_rank else kv, dn + dv)
+    k_nope = kv[..., :dn]
+    if cfg.qk_norm:
+        k_nope = _rms_norm(k_nope, lw["k_nope_norm"], cfg.norm_eps)
     k = jnp.concatenate(
-        [kv[..., :dn], jnp.broadcast_to(k_pe, (B, T, h, dr))], -1)
-    o = _causal_attention(q, k, kv[..., dn:])
+        [k_nope, jnp.broadcast_to(k_pe, (B, T, h, dr))], -1)
+    v = kv[..., dn:]
+    o = _causal_attention(q, k, v) if dn + dr == dv \
+        else _padded_attention(q, k, v)
+    if cfg.head_gate:
+        gate = jax.nn.sigmoid(_kept(jnp.einsum(
+            "bte,eh->bth", x, lw["w_gate"],
+            preferred_element_type=jnp.float32)))
+        o = (heads(o, dv) * gate[..., None]).astype(x.dtype).reshape(
+            B, T, h * dv)
     return jax.lax.psum(_kept(o @ lw["wo"]), AXIS_TP)
+
+
+def _short_conv(x, taps):
+    """Causal depthwise convolution over the sequence, no bias.  x: [B,
+    T, C]; taps: [K, C], the LAST tap on the current token: y_t = sum_j
+    taps[j] * x[t - (K - 1) + j], nought before the sequence's start.
+    Float32 out."""
+    import jax.numpy as jnp
+
+    K, T = taps.shape[0], x.shape[1]
+    xp = jnp.pad(x.astype(jnp.float32), ((0, 0), (K - 1, 0), (0, 0)))
+    taps = taps.astype(jnp.float32)
+    return sum(xp[:, j:j + T] * taps[j] for j in range(K))
+
+
+def _kda_chunked(q, k, v, g, beta, chunk, rebase, dtype):
+    """The gated delta rule with a per-channel decay (Kimi Delta
+    Attention, arXiv:2510.26692), in its CHUNKED form.  Per head, with
+    the state S [d_k, d_v] in float32 from nought:
+
+        S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1}
+              + beta_t k_t v_t^T,          o_t = S_t^T q_t.
+
+    q, k, v, g: [B, T, h, d] float32 (q and k as the recurrence takes
+    them, g the log-decay, < 0); beta: [B, T, h].  Returns (o [B, T, h,
+    d] float32, the largest |log-decay| summed over a re-based span).
+
+    A chunk is C = `chunk` tokens.  With G the cumulative log-decay
+    inside the chunk and S_0 the state the chunk is handed, the rows u_r
+    = beta_r (v_r - S_{r-1}^T Diag(exp(g_r)) k_r) solve the unit lower
+    triangular system (the WY form)
+
+        (I + A) U = beta (V - (exp(G) K) S_0),
+        A_ri = beta_r sum_c k_rc k_ic exp(G_rc - G_ic)   (i < r),
+
+    so U = Ut - W S_0 with [W | Ut] = (I + A)^-1 [beta exp(G) K | beta
+    V], and with B_ri = sum_c q_rc k_ic exp(G_rc - G_ic) (i <= r):
+
+        O = (exp(G) Q) S_0 + B U,
+        S_C = Diag(exp(G_C)) S_0 + (exp(G_C - G) K)^T U.
+
+    Everything before S_0 is made for all chunks at once (scope
+    `chunk`); a `lax.scan` hands the state from chunk to chunk (scope
+    `state`).  exp(G_r - G_i) is a product of a row factor and a column
+    factor, and exp(-G_i) alone passes float32 within a chunk (e^320 at
+    64 steps of -5), so the cumulative decay is RE-BASED every `rebase`
+    steps: rows of sub-block a take exp(G_r - R_a) and columns exp(R_a -
+    G_i), R_a being G at the middle of the span the sub-block covers:
+    with a span of at most 80 nats both lie within e^-40 .. e^40 inside
+    the sub-block, and a column before it under e^-40.  The
+    decays, the solve and the state are float32; the operands of the
+    products are `dtype`, accumulated in float32."""
+    import jax
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    B, T, h, d = q.shape
+    C, R = chunk, rebase
+    n = C // R
+    pad = (-T) % C
+    N = (T + pad) // C
+
+    def blocks(a):          # [B, T, h, x] -> [N, B, h, C, x], zero tail
+        a = jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        return a.reshape(B, N, C, h, a.shape[-1]).transpose(1, 0, 3, 2, 4)
+
+    def einsum(spec, a, b):
+        return jnp.einsum(spec, a.astype(dtype), b.astype(dtype),
+                          preferred_element_type=f32)
+
+    q, k, v, g, beta = (blocks(a) for a in (q, k, v, g, beta[..., None]))
+    with jax.named_scope("chunk"):
+        G = jnp.cumsum(g, axis=3)                   # [N, B, h, C, d], <= 0
+        Gs = G.reshape(N, B, h, n, R, d)
+        # the base of sub-block a, [N, B, h, n, d]: the MIDDLE of the
+        # span its rows' G covers, so that a row factor and a column
+        # factor of the sub-block each lie within e^-40 .. e^40 and no
+        # product of one with a cotangent leaves float32's normal range
+        # (based at the span's start, a row factor of e^-80 times a
+        # cotangent flushed to nought in the backward pass).  No gradient
+        # through it: exp(G_r - R_a) exp(R_a - G_i) does not depend on R_a
+        start = jnp.concatenate(
+            [jnp.zeros_like(Gs[..., :1, 0, :]), Gs[..., :-1, R - 1, :]], -2)
+        span = jax.lax.stop_gradient(start - Gs[..., R - 1, :])
+        base = jax.lax.stop_gradient(start) - 0.5 * span
+        span = span.max()
+        rows = jnp.exp(Gs - base[..., None, :])     # exp(G_r - R_a)
+        # columns against sub-block a's base, up to a's own end; a later
+        # column is never under a row of a: its factor is nought
+        seen = jnp.arange(C)[None] < (jnp.arange(n)[:, None] + 1) * R
+        cols = jnp.exp(jnp.where(
+            seen[..., None], base[..., None, :] - G[..., None, :, :],
+            -jnp.inf))                              # [N, B, h, n, C, d]
+        left = jnp.stack([k.reshape(Gs.shape) * rows,
+                          q.reshape(Gs.shape) * rows], -3)
+        scores = einsum("zbhasrd,zbhacd->zbhsarc", left,
+                        k[..., None, :, :] * cols).reshape(N, B, h, 2, C, C)
+        below = jnp.arange(C)[:, None] - jnp.arange(C)[None]
+        A = jnp.where(below > 0, scores[..., 0, :, :], 0.0) * beta
+        Bm = jnp.where(below >= 0, scores[..., 1, :, :], 0.0)
+        decay = jnp.exp(G)
+        sol = jax.scipy.linalg.solve_triangular(
+            A + jnp.eye(C, dtype=f32),
+            jnp.concatenate([k * decay * beta, v * beta], -1),
+            lower=True, unit_diagonal=True)
+        # the products' operands, in `dtype` once for the whole scan
+        W, Qd, Bm, Ke = (a.astype(dtype) for a in (
+            sol[..., :d], q * decay, Bm,
+            k * jnp.exp(G[..., -1:, :] - G)))       # k decayed to the end
+        Ut = sol[..., d:]
+        end = decay[..., -1, :]                     # [N, B, h, d]
+
+    with jax.named_scope("state"):
+        def step(S, xs):
+            W, Ut, Qd, Bm, Ke, end = xs
+            U = Ut - einsum("bhck,bhkv->bhcv", W, S)
+            o = einsum("bhck,bhkv->bhcv", Qd, S) \
+                + einsum("bhcj,bhjv->bhcv", Bm, U)
+            S = end[..., None] * S + einsum("bhck,bhcv->bhkv", Ke, U)
+            return S, o
+
+        _, o = jax.lax.scan(step, _pvary_all(jnp.zeros((B, h, d, d), f32)),
+                            (W, Ut, Qd, Bm, Ke, end))
+    o = o.transpose(1, 0, 3, 2, 4).reshape(B, N * C, h, d)[:, :T]
+    return o, span
+
+
+def _kda(cfg, x, lw):
+    """Kimi Delta Attention (arXiv:2510.26692) as one mixer.  x: [B, T,
+    E] (sp = tp = 1).  q, k, v each through a product, a causal
+    depthwise convolution of `kda_conv` taps and SiLU; q and k
+    L2-normed per head (q times d ** -0.5 besides); a log-decay per
+    head AND channel from one full matrix, kda_gate_floor * sigmoid(
+    exp(A_log) * (x Wa + dt_bias)), so one step's decay lies in (e^
+    floor, 1); a write strength per head, sigmoid(x Wb); the gated delta
+    rule in its chunked form (`_kda_chunked`); then per head an RMSNorm
+    over its d values and a sigmoid gate from x, and the out product.
+    Device scopes `conv`, `gate`, `chunk`, `state`, `out`.  Returns (y
+    [B, T, E], stats: `KDA_STATS`)."""
+    import jax
+    import jax.numpy as jnp
+
+    from .. import profiler
+
+    profiler.inc_stat("kda_traced")
+    f32 = jnp.float32
+    B, T, E = x.shape
+    h = cfg.n_heads
+    d = cfg.kda_head_dim or E // h
+
+    def heads(y):
+        return y.reshape(B, T, h, d)
+
+    def per_head(w):        # a [B, T, h] gate's logits, float32
+        return _kept(jnp.einsum("bte,eh->bth", x, lw[w],
+                                preferred_element_type=f32))
+
+    with jax.named_scope("conv"):
+        q, k, v = (heads(jax.nn.silu(_short_conv(_kept(x @ lw["w" + n]),
+                                                 lw["conv_" + n])))
+                   for n in ("q", "k", "v"))
+        q, k = (a * jax.lax.rsqrt((a * a).sum(-1, keepdims=True) + 1e-6)
+                for a in (q, k))
+        q = q * d ** -0.5
+    with jax.named_scope("gate"):
+        a = _kept(jnp.einsum("bte,ef->btf", x, lw["wa"],
+                             preferred_element_type=f32))
+        rate = jnp.exp(lw["A_log"].astype(f32))[:, None]      # [h, 1]
+        g = cfg.kda_gate_floor * jax.nn.sigmoid(
+            rate * heads(a + lw["dt_bias"].astype(f32)))
+        beta = jax.nn.sigmoid(per_head("wb"))
+    o, span = _kda_chunked(q, k, v, g, beta, cfg.kda_chunk, cfg.kda_rebase,
+                           x.dtype)
+    with jax.named_scope("out"):
+        o = _rms_norm(o, lw["o_norm"].astype(f32), cfg.norm_eps) \
+            * jax.nn.sigmoid(per_head("w_gate"))[..., None]
+        y = _kept(o.astype(x.dtype).reshape(B, T, h * d) @ lw["wo"])
+    stats = {"kda_tokens": _pvary_all(jnp.asarray(B * T, f32)),
+             "kda_chunks": _pvary_all(jnp.asarray(
+                 B * -(-T // cfg.kda_chunk), f32)),
+             "kda_decay_span_max": span}
+    return jax.lax.psum(y, AXIS_TP), stats
 
 
 def _dense_ffn(x, w1, w2):
@@ -539,7 +911,12 @@ def _route(cfg, flat, router, bias=None):
     added for the selection alone: the weights are the unbiased scores
     of the selected, divided by their sum over all top_k (held here or
     not) under `moe_norm_topk`, times `moe_scale`.  Gradients reach the
-    router through the weights only."""
+    router through the weights only.  With `n_group` > 1 the selection
+    is group-limited: the experts lie in n_group groups of consecutive
+    ids, a group's score is the sum of its two largest selection
+    scores, and the top_k are taken inside the `topk_group` best groups;
+    a third value is then returned, the kept groups [n_tok, n_group]
+    bool."""
     import jax
     import jax.numpy as jnp
 
@@ -549,12 +926,25 @@ def _route(cfg, flat, router, bias=None):
         if cfg.moe_score == "softmax" else jax.nn.sigmoid(logits)
     select = scores if bias is None else \
         scores + jax.lax.stop_gradient(bias.astype(jnp.float32))
-    _, idx = jax.lax.top_k(jax.lax.stop_gradient(select), cfg.top_k)
+    select = jax.lax.stop_gradient(select)
+    if cfg.n_group > 1:
+        with jax.named_scope("groups"):
+            n, per = select.shape[0], cfg.n_experts // cfg.n_group
+            by_group = select.reshape(n, cfg.n_group, per)
+            _, best = jax.lax.top_k(
+                jax.lax.top_k(by_group, 2)[0].sum(-1), cfg.topk_group)
+            kept = jnp.zeros((n, cfg.n_group), bool).at[
+                jnp.arange(n)[:, None], best].set(True)
+            select = jnp.where(kept[..., None], by_group,
+                               -jnp.inf).reshape(select.shape)
+    _, idx = jax.lax.top_k(select, cfg.top_k)
     w = jnp.take_along_axis(scores, idx, axis=1)
     if cfg.moe_norm_topk:
         w = w / (w.sum(-1, keepdims=True) + 1e-20)
     if cfg.moe_scale != 1.0:
         w = w * cfg.moe_scale
+    if cfg.n_group > 1:
+        return idx.astype(jnp.int32), w, kept
     return idx.astype(jnp.int32), w
 
 
@@ -689,9 +1079,14 @@ def _moe_ffn(cfg, x, lw, ep_size):
     B, T, E = x.shape
     flat = x.reshape(B * T, E)
     with jax.named_scope("router"):
-        idx, w = _route(cfg, flat, lw["router"], lw.get("router_bias"))
+        idx, w, *kept = _route(cfg, flat, lw["router"],
+                               lw.get("router_bias"))
     if cfg.ffn == "swiglu":
         f, stats = _experts_grouped(cfg, flat, idx, w, lw)
+        if kept:        # tokens whose kept groups hold the experts here
+            here = cfg.expert_first // (cfg.n_experts // cfg.n_group)
+            stats["moe_groups_kept_here"] = \
+                kept[0][:, here].sum().astype("float32")
     else:
         f, stats = _experts_bucketed(cfg, flat, idx[:, 0], w[:, 0],
                                      lw["we1"], lw["we2"], ep_size), {}
@@ -719,27 +1114,38 @@ def _pvary_all(x):
 
 
 def _merge_stats(a, b):
-    """Two sets of `MOE_STATS` as one: counts add, the fullest expert is
-    the fuller."""
+    """Two sets of per-step counters as one: counts add, a watermark is
+    the higher; a counter only one side has is kept."""
     import jax.numpy as jnp
 
-    if not a or not b:
-        return a or b
-    return {k: (jnp.maximum(a[k], b[k]) if k == "moe_load_max"
-                else a[k] + b[k]) for k in a}
+    out = dict(a)
+    for k, v in b.items():
+        out[k] = v if k not in out else (
+            jnp.maximum(out[k], v) if k in _WATERMARKS else out[k] + v)
+    return out
 
 
 def _layer_fn(cfg, kind, tp_size, ep_size, rope=None):
     """One layer of a segment as the scan's body, (x, lw) -> (x, stats),
-    under the config's remat policy.  `kind` is "dense" or "moe"."""
+    under the config's remat policy.  `kind` is a segment's (see
+    `_segments`): the feed-forward "dense" or "moe", "kda+" in front
+    where the mixer is Kimi Delta Attention."""
     import jax
 
-    # the named scopes (here, `router` .. `combine` in the expert layer,
-    # and `embed` / `mtp` / `loss` / `adam` below) are metadata on the
-    # device program's instructions: a trace's device time can be summed
-    # by them (PERF.md)
+    mixer, ffn = _kind_parts(cfg, kind)
+
+    # the named scopes (here, `conv` .. `out` in `kda`, `router` ..
+    # `combine` in the expert layer, and `embed` / `mtp` / `loss` /
+    # `adam` below) are metadata on the device program's instructions: a
+    # trace's device time can be summed by them (PERF.md)
     def layer(x, lw):
-        if cfg.attention == "mla":
+        stats = {}
+        if mixer == "kda":
+            with jax.named_scope("kda"):
+                m, stats = _kda(cfg, _rms_norm(x, lw["ln1"], cfg.norm_eps),
+                                lw)
+                h = x + m
+        elif mixer == "mla":
             with jax.named_scope("mla"):
                 h = x + _mla(cfg, _rms_norm(x, lw["ln1"], cfg.norm_eps),
                              lw, tp_size, rope)
@@ -751,12 +1157,13 @@ def _layer_fn(cfg, kind, tp_size, ep_size, rope=None):
                                    tp_size)
         with jax.named_scope("ffn"):
             z = _rms_norm(h, lw["ln2"], cfg.norm_eps)
-            if kind == "moe":
-                f, stats = _moe_ffn(cfg, z, lw, ep_size)
+            if ffn == "moe":
+                f, st = _moe_ffn(cfg, z, lw, ep_size)
+                stats = dict(stats, **st)
             elif cfg.ffn == "gelu":
-                f, stats = _dense_ffn(z, lw["w1"], lw["w2"]), {}
+                f = _dense_ffn(z, lw["w1"], lw["w2"])
             else:
-                f, stats = _gated_ffn(z, lw["wg"], lw["wu"], lw["wd"]), {}
+                f = _gated_ffn(z, lw["wg"], lw["wu"], lw["wd"])
             return h + f, stats
 
     if cfg.remat == "none":
@@ -770,8 +1177,8 @@ def _layer_fn(cfg, kind, tp_size, ep_size, rope=None):
 def _stage_fn(cfg, kind, params_stage, x, tp_size, ep_size, rope=None):
     """Run one segment's layers (this pipeline stage's share of them)
     over x via lax.scan (weights stacked on the layer axis).  `kind` is
-    "dense" or "moe"; `rope` the rotary table where positions are
-    rotary.  Returns (x, stats of the segment's expert layers or {})."""
+    the segment's; `rope` the rotary table where positions are rotary.
+    Returns (x, the segment's per-step counters or {})."""
     import jax
 
     x = _pvary_all(x)
@@ -779,14 +1186,19 @@ def _stage_fn(cfg, kind, params_stage, x, tp_size, ep_size, rope=None):
     out, per_layer = jax.lax.scan(layer, x, params_stage)
     if not per_layer:
         return out, {}
-    return out, {k: (v.max() if k == "moe_load_max" else v.sum())
+    return out, {k: (v.max() if k in _WATERMARKS else v.sum())
                  for k, v in per_layer.items()}
 
 
-def _segment_params(cfg, params, prefix, kind):
+def _segment_params(cfg, params, segment):
     """One segment's stacked layer weights, [layers, ...] each: the
-    leading pp axis is sharded, so inside shard_map it has extent 1."""
-    return {leaf: params[prefix + leaf][0]
+    leading pp axis is sharded, so inside shard_map it has extent 1;
+    where the segment's prefix has more segments (a stack of two mixer
+    kinds) the segment's rows of the stack."""
+    prefix, kind, first, n = segment
+    whole = _stacks(cfg)[prefix][1] == n
+    return {leaf: (params[prefix + leaf][0] if whole
+                   else params[prefix + leaf][0, first:first + n])
             for leaf in _layer_leaves(cfg, kind)}
 
 
@@ -794,10 +1206,10 @@ def _run_stack(cfg, params, x, tp_size, ep_size, rope):
     """The model's layers in order, segment by segment (the multi-token-
     prediction block is not one of them).  Returns (x, stats)."""
     stats = {}
-    for prefix, kind, _ in _segments(cfg):
-        if prefix != "mtp.":
-            x, st = _stage_fn(cfg, kind,
-                              _segment_params(cfg, params, prefix, kind),
+    for segment in _segments(cfg):
+        if segment[0] != "mtp.":
+            x, st = _stage_fn(cfg, segment[1],
+                              _segment_params(cfg, params, segment),
                               x, tp_size, ep_size, rope)
             stats = _merge_stats(stats, st)
     return x, stats
@@ -882,8 +1294,7 @@ def _build_loss_fn(cfg: TransformerConfig, mesh, n_micro: int):
 
     def loss_fn(params, tokens, labels):
         """tokens/labels: local shard [B_loc, T_loc] (dp × sp).  Returns
-        (loss, stats): the routed experts' counters of `MOE_STATS` where
-        `_has_stats(cfg)`, else {}."""
+        (loss, stats): the step's counters (`_stat_names(cfg)`), or {}."""
         pp_idx = jax.lax.axis_index(AXIS_PP)
         sp_idx = jax.lax.axis_index(AXIS_SP)
         tp_idx = jax.lax.axis_index(AXIS_TP)
@@ -908,9 +1319,10 @@ def _build_loss_fn(cfg: TransformerConfig, mesh, n_micro: int):
             # one trip (the docstring above says what that costs)
             h, stats = run_stage(x)
         else:
-            if _has_stats(cfg):
-                raise MXNetError("the routed experts' counters need one "
-                                 "stage and one microbatch")
+            if _stat_names(cfg):
+                raise MXNetError("the step's counters (routed experts, "
+                                 "KDA layers) need one stage and one "
+                                 "microbatch")
             mb, E = B // n_micro, cfg.d_model
             x_mb = x.reshape(n_micro, mb, T, E)
             perm_fwd = [(i, (i + 1) % pp) for i in range(pp)]
@@ -954,9 +1366,9 @@ def _build_loss_fn(cfg: TransformerConfig, mesh, n_micro: int):
                     [_rms_norm(nxt, params["mtp.ln_e"], eps),
                      _rms_norm(h, params["mtp.ln_h"], eps)], -1) \
                     @ params["mtp.eh"]
-                kind = _segments(cfg)[-1][1]
+                segment = _segments(cfg)[-1]
                 u, st = _stage_fn(
-                    cfg, kind, _segment_params(cfg, params, "mtp.", kind),
+                    cfg, segment[1], _segment_params(cfg, params, segment),
                     u, tp, ep, rope)
                 stats = _merge_stats(stats, st)
 
@@ -999,7 +1411,7 @@ def _build_loss_fn(cfg: TransformerConfig, mesh, n_micro: int):
             # shards (dp, sp); the other axes hold copies
             every = (AXIS_DP, AXIS_PP, AXIS_TP, AXIS_SP, AXIS_EP)
             stats = jax.lax.stop_gradient({
-                k: (jax.lax.pmax(v, every) if k == "moe_load_max" else
+                k: (jax.lax.pmax(v, every) if k in _WATERMARKS else
                     jax.lax.psum(v, every) / float(pp * tp * ep))
                 for k, v in stats.items()})
         return loss, stats
@@ -1114,11 +1526,16 @@ def _build_adam_zero1_step(cfg: TransformerConfig, mesh, n_micro: int,
 
 
 def _check_mesh(cfg, mesh):
-    if _has_stats(cfg) and mesh.shape[AXIS_EP] > 1:
+    if cfg.n_experts and cfg.ffn == "swiglu" and mesh.shape[AXIS_EP] > 1:
         raise MXNetError(
             "gated (swiglu) experts run the held experts' grouped "
             "products on ep = 1; the exchange over ep > 1 is the "
             "capacity-bucketed one (GELU experts), which drops")
+    if KDA_STATS[0] in _stat_names(cfg) and (mesh.shape[AXIS_SP] > 1
+                                             or mesh.shape[AXIS_TP] > 1):
+        raise MXNetError(
+            "Kimi Delta Attention carries a state along the sequence and "
+            "convolves over it: sp = 1; its heads are not sharded: tp = 1")
 
 
 def _make_step_common(cfg, mesh, n_micro, lr, optimizer, betas, eps,
@@ -1144,9 +1561,10 @@ def _make_step_common(cfg, mesh, n_micro, lr, optimizer, betas, eps,
         raise MXNetError("optimizer must be 'sgd' or 'adam' (got %r)"
                          % (optimizer,))
     _check_mesh(cfg, mesh)
-    # the routed experts' counters ride beside the loss where the config
-    # has them (`_has_stats`); no other program's outputs change
-    extra = ({k: P() for k in MOE_STATS},) if _has_stats(cfg) else ()
+    # the step's counters ride beside the loss where the config has them
+    # (`_stat_names`); no other program's outputs change
+    names = _stat_names(cfg)
+    extra = ({k: P() for k in names},) if names else ()
     if optimizer == "sgd":
         device_step = _build_device_step(cfg, mesh, n_micro, lr)
         if k_steps is None:
@@ -1238,12 +1656,14 @@ def make_fused_train_steps(cfg: TransformerConfig, mesh, k_steps: int,
 
 
 def publish_moe_stats(stats) -> Dict[str, float]:
-    """Add the routed experts' counters a step (or a fused program: [K]
-    arrays) returned to `mx.profiler`'s stats: `moe_pairs` and
-    `moe_tokens` add up, `moe_load_max` is a watermark
-    (docs/observability.md).  One host read of three small arrays: call
-    it where the host may wait for the program (a sampled step, the end
-    of a window), not after every step.  Returns what it added."""
+    """Add the per-step counters a step (or a fused program: [K] arrays)
+    returned to `mx.profiler`'s stats, the routed experts' (`MOE_STATS`,
+    `GROUP_STATS`) and the KDA layers' (`KDA_STATS`) alike: counts add
+    up, `moe_load_max` and `kda_decay_span_max` are watermarks (whole
+    numbers: the span's nats to the nearest) (docs/observability.md).
+    One host read of a few small arrays: call it where the host may
+    wait for the program (a sampled step, the end of a window), not
+    after every step.  Returns what it added."""
     import jax
     import numpy as np
 
@@ -1251,10 +1671,12 @@ def publish_moe_stats(stats) -> Dict[str, float]:
 
     host = {k: np.asarray(v) for k, v in jax.device_get(stats).items()}
     out = {}
-    for k in MOE_STATS:
-        if k == "moe_load_max":
+    for k in MOE_STATS + GROUP_STATS + KDA_STATS:
+        if k not in host:
+            continue
+        if k in _WATERMARKS:
             out[k] = float(host[k].max())
-            profiler.max_stat(k, int(out[k]))
+            profiler.max_stat(k, int(round(out[k])))
         else:
             out[k] = float(host[k].sum())
             profiler.inc_stat(k, int(out[k]))
