@@ -85,9 +85,11 @@ class _Sizes(object):
             self.attn_ragged, self.attn_ragged_block = (2, 200, 64), 128
             # ((bh, T, d), heads): the entry on the activations' layout
             # (`flash_attention_bthd`, [B, T, heads, d]) at the two LM
-            # cells' head widths, so a layout slip reads here before a
-            # cell's `correct` does
-            self.attn_bthd = [((128, 1024, 64), 16), ((4, 4096, 256), 2)]
+            # cells' own shapes, [8, 1024, 16, 64] (two heads to a lane
+            # block) and [2, 4096, 20, 256] (a head a lane block), read
+            # in place: a layout slip reads here before a cell's
+            # `correct` does, and the kernels are timed at them
+            self.attn_bthd = [((128, 1024, 64), 16), ((40, 4096, 256), 20)]
             # (batch, T, heads, q.k width, v width): latent attention
             # whose q.k is wider than v, padded to the kernels' one width
             self.attn_unequal = (2, 2048, 4, 192, 128)
@@ -389,6 +391,51 @@ def _attention_check(shape, block=512, heads=None):
                         "flash at (bh, T, d)=%s" % (shape,), 3e-2)
 
 
+# ms a call of the kernels alone at `attn_bthd`'s shapes before they read
+# the activations' layout in place (PERF.md section 5: [128, 1024, 64],
+# PR 33's chip runs; [40, 4096, 256], PR 31's)
+_PARENT_KERNEL_MS = {(128, 1024, 64): (0.794, 0.629, 0.759),
+                     (40, 4096, 256): (3.98, 4.04, 4.43)}
+
+
+def _kernel_times(shape, heads, calls=20):
+    """ms a call of each flash kernel alone, on [B, T, heads * d] read
+    in place as the LM's blocks hand it over, beside the parent's on
+    (bh, T, d): {"fwd": [ms, parent's], ...}.  With `calls` 0 (the
+    rehearsal: a CPU time is no kernel time) each kernel runs once and
+    no time is taken.  Set-up information, as every time here."""
+    bh, t, d = shape
+    b = bh // heads
+    per_block = pa._lane_plan(heads, d)     # in place at both shapes
+    rng = np.random.RandomState(t + d)
+    q, k, v, g, out = (jnp.asarray(rng.normal(0, 1, (b, t, heads * d)),
+                                   jnp.bfloat16) for _ in range(5))
+    lse = jnp.zeros((bh, t), jnp.float32) + 6.0
+    args = (float(d) ** -0.5, True, min(512, t), min(512, t))
+    lanes = (heads // per_block, per_block)
+
+    def bwd(q, k, v):
+        return pa._flash_backward_pallas(q, k, v, g, out, lse, *args, *lanes)
+
+    kernels = {
+        "fwd": lambda q, k, v: pa._flash_forward_pallas(
+            q, k, v, *args, True, *lanes),
+        "dq": lambda q, k, v: bwd(q, k, v)[0],
+        "dkv": lambda q, k, v: bwd(q, k, v)[1:]}
+    times = {}
+    for (name, fn), was in zip(kernels.items(),
+                               _PARENT_KERNEL_MS.get(shape, (None,) * 3)):
+        fn = jax.jit(fn)
+        r = jax.block_until_ready(fn(q, k, v))
+        t0 = time.perf_counter()
+        for _ in range(calls):
+            r = fn(q, k, v)
+        jax.block_until_ready(r)
+        times[name] = [round((time.perf_counter() - t0) / calls * 1e3, 3)
+                       if calls else None, was]
+    return times
+
+
 def _on_one_device_mesh(fn, *args):
     """fn(*args) inside shard_map on a one-device mesh: the LM's layer
     functions name the mesh's axes."""
@@ -524,6 +571,12 @@ def phase3_lm(sizes, meter):
     errs = [_attention_check(s) for s in sizes.attn_shapes] + \
         [_attention_check(s, heads=h) for s, h in sizes.attn_bthd]
     info["attn_max_err"] = round(max(errs), 5)
+    info["flash_ms_per_call_now_and_parent"] = {
+        "x".join(map(str, s)): _kernel_times(s, h, 0 if sizes.rehearse
+                                             else 20)
+        for s, h in sizes.attn_bthd}
+    print("flash kernels alone, ms a call [now, parent]: %s"
+          % info["flash_ms_per_call_now_and_parent"], flush=True)
     info["attn_unequal_widths_err"] = round(
         _unequal_width_check(sizes.attn_unequal), 5)
     err, span = _kda_check(sizes.kda)

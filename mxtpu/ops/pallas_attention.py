@@ -4,10 +4,14 @@ The framework's hottest non-conv op.  XLA's generic softmax-attention
 materializes the (T, T) score matrix in HBM; this kernel streams K/V
 blocks through VMEM with the online log-sum-exp rescaling of flash
 attention (Dao et al. 2022), so HBM traffic is O(T·d) instead of
-O(T²).  The grid is (batch·heads, q_blocks, k_blocks) with the k axis
-innermost — TPU grids execute sequentially, so VMEM scratch
+O(T²).  The grid is (batch·lane blocks, q_blocks, k_blocks) with the k
+axis innermost — TPU grids execute sequentially, so VMEM scratch
 (accumulator + running max/sum) carries state across the k sweep and
-the output block is written once on the last k step.
+the output block is written once on the last k step.  A lane block is
+one head, or the heads that share a 128-lane vreg (`_lane_plan`): the
+kernels read q, k, v and write their results in [batch, seq, heads *
+head_dim], the layout the projections leave and read, and pick heads
+by the lane block of their index maps.
 
 `flash_attention` is the public entry: it pads ragged query lengths
 to the block size and runs the kernel compiled on a TPU, or in
@@ -35,13 +39,17 @@ Registered as `_contrib_flash_attention` (q, k, v of shape
 ring attention routes its local-chunk compute here automatically
 wherever the kernel backend exists (see `_use_pallas`).
 
-`flash_attention_bthd` is the same on the activations' own layout
+`flash_attention_bthd` is the entry on the activations' own layout
 ([batch, seq, heads, head_dim] in, [batch, seq, heads * head_dim]
-out): the head split, the kernels and the merge are inside ONE
-`custom_vjp`, whose residuals are q, k, v as given, the MERGED output
-and the log-sums, the last two under the names `FLASH_OUT` /
-`FLASH_LSE` so that a remat policy can keep them (`executor.
-apply_remat`'s "dots" does; the LM's blocks call this entry).
+out): no head split or merge copy in either pass (a shape whose lane
+blocks are not whole heads takes one; `profiler.stats()` counts
+`flash_calls_in_place` / `flash_calls_split` and the watermark
+`flash_heads_per_block`).  ONE `custom_vjp`, whose residuals are q, k,
+v as given, the output and the log-sums, the last two under the names
+`FLASH_OUT` / `FLASH_LSE` so that a remat policy can keep them
+(`executor.apply_remat`'s "dots" does; the LM's blocks call this
+entry).  `delta = rowsum(out * g)` is taken inside the two backward
+kernels from the tiles they hold.
 """
 from __future__ import annotations
 
@@ -253,6 +261,26 @@ def _causal_walk(step, i, j, block_q, block_k, by_rows):
                      lambda s: _mask_corner(s, tri, 0))
 
 
+def _visited(i, j, block_q, block_k, causal):
+    """Whether a sweep computes anything of the (i, j) score block:
+    always, or under `causal` unless it lies above the diagonal.  A
+    traced value either way: what a kernel does to its refs it does
+    under `pl.when`, where the interpreter does not hold it against the
+    varying axes of a `shard_map`."""
+    return j * block_k <= (i + 1) * block_q - 1 if causal else j >= 0
+
+
+def _walk(step, i, j, block_q, block_k, causal, by_rows):
+    """One (i, j) score block of a sweep: the causal walk, or ONE
+    unmasked step over the whole block."""
+    from jax.experimental import pallas as pl
+
+    if causal:
+        return _causal_walk(step, i, j, block_q, block_k, by_rows)
+    pl.when(_visited(i, j, block_q, block_k, causal))(
+        lambda: step(slice(None), slice(None), None))
+
+
 def _count_tiles(tq, tk, block_q, block_k, causal):
     """Per traced kernel call and per head, in tiles of `_sub_tile`:
     how many the score matrix has (`flash_tiles_total`), how many the
@@ -273,30 +301,62 @@ def _count_tiles(tq, tk, block_q, block_k, causal):
     _prof.inc_stat("flash_tiles_masked", int(masked.sum()))
 
 
-def _k_index_map(causal, block_q, block_k):
+def _lane_block(b, heads):
+    """(batch row, lane block) of grid step `b` over [N, T, heads * w]:
+    the grid's first axis walks the `heads` lane blocks of a row
+    innermost."""
+    if heads == 1:
+        return b, 0
+    return b // heads, b % heads
+
+
+def _k_index_map(causal, block_q, block_k, heads=1):
     """Index map of a k or v tile in a k-innermost sweep (grid b, i,
-    j): block j, but a causal step above the diagonal (skipped in the
-    kernel) names the last visited block again, so no tile is fetched
-    for it."""
+    j): block j of lane block `b % heads`, but a causal step above the
+    diagonal (skipped in the kernel) names the last visited block
+    again, so no tile is fetched for it."""
     import jax.numpy as jnp
 
-    if not causal:
-        return lambda b, i, j: (b, j, 0)
-    return lambda b, i, j: (
-        b, jnp.minimum(j, ((i + 1) * block_q - 1) // block_k), 0)
+    def index(b, i, j):
+        n, lane = _lane_block(b, heads)
+        if causal:
+            j = jnp.minimum(j, ((i + 1) * block_q - 1) // block_k)
+        return n, j, lane
+
+    return index
 
 
-def _q_index_map(causal, block_q, block_k, nq):
+def _q_index_map(causal, block_q, block_k, nq, heads=1):
     """The same for a q-side tile in the q-innermost dkv sweep (grid
     b, j, i): a skipped step names the first q block that sees k
     block j."""
     import jax.numpy as jnp
 
-    if not causal:
-        return lambda b, j, i: (b, i, 0)
-    return lambda b, j, i: (
-        b, jnp.maximum(i, jnp.minimum((j * block_k) // block_q, nq - 1)),
-        0)
+    def index(b, j, i):
+        n, lane = _lane_block(b, heads)
+        if causal:
+            i = jnp.maximum(i, jnp.minimum((j * block_k) // block_q,
+                                           nq - 1))
+        return n, i, lane
+
+    return index
+
+
+def _outer_index_map(heads=1):
+    """Index map of the tile a sweep holds fixed (grid b, outer, inner):
+    block `outer` of lane block `b % heads`."""
+    def index(b, outer, inner):
+        n, lane = _lane_block(b, heads)
+        return n, outer, lane
+
+    return index
+
+
+def _row_index_map(qmap):
+    """Index map of the (1, rows, block_q) tile of per-row numbers
+    (`_ROWS` sublanes of [N * heads, _ROWS, T]) that goes with the q
+    tile `qmap` names: the same q block of grid step b's own row."""
+    return lambda b, x, y: (b, 0, qmap(b, x, y)[1])
 
 
 # ---------------------------------------------------------------------------
@@ -324,188 +384,320 @@ def _dot_f32(a, b, contract=((1,), (1,))):
                            preferred_element_type=jnp.float32)
 
 
+# Sublanes of the log-sums' tile, a lane block's heads in the first of
+# them: one float32 vreg row group, so the tile is whole vregs and T lies
+# along the LANES -- 8 x T x 4 bytes a lane block, where a (T, 1) column
+# a head is padded to 128 x T x 4 on the chip.
+_ROWS = 8
+# VMEM the dkv sweep may spend on keeping every q block's columns
+# (log-sums and deltas, 512 bytes a row and quantity) over its k blocks:
+# T = 4096 at one head to a lane block, 2730 at two
+_Q_SIDE_BYTES = 4 * 2 ** 20
+
+
+def _head_lanes(per_block, width):
+    """One (1, width) lane mask per head of a lane block that holds
+    `per_block` heads side by side; [None] where a block is one head."""
+    from jax import lax
+    import jax.numpy as jnp
+
+    if per_block == 1:
+        return [None]
+    d = width // per_block
+    lane = lax.broadcasted_iota(jnp.int32, (1, width), 1)
+    return [jnp.logical_and(lane >= r * d, lane < (r + 1) * d)
+            for r in range(per_block)]
+
+
+def _own_lanes(lane, new, old):
+    """`new` in the lanes of the head `lane` masks, `old` elsewhere (a
+    product against a tile that holds several heads is right in the
+    lanes of the head whose scores went in, and another head's
+    elsewhere)."""
+    import jax.numpy as jnp
+
+    return new if lane is None else jnp.where(lane, new, old)
+
+
+def _keep_head_tiles(dst_ref, src_ref, lanes):
+    """dst_ref[r] = the tile src_ref[0] with every head's lanes but head
+    r's zeroed: a product that contracts over the block's whole lane
+    width then adds nothing from the other heads.  Once per outer block,
+    into VMEM; no lane moves."""
+    import jax.numpy as jnp
+
+    tile = src_ref[0]
+    for r, lane in enumerate(lanes):
+        dst_ref[r] = jnp.where(lane, tile, jnp.zeros_like(tile))
+
+
+def _columns_to_rows(cols):
+    """Per-row numbers from column form -- a list of (block_q, 1), one
+    per head -- to the (`_ROWS`, block_q) tile the log-sums leave the
+    forward kernel in: row r (and r + heads, ...) is head r's."""
+    from jax import lax
+    import jax.numpy as jnp
+
+    n = cols[0].shape[0]
+    lane = lax.broadcasted_iota(jnp.int32, (1, _LANES), 1) % len(cols)
+    wide = jnp.broadcast_to(cols[-1], (n, _LANES))
+    for r, col in enumerate(cols[:-1]):
+        wide = jnp.where(lane == r, col, wide)
+    return wide.T[:_ROWS]
+
+
+def _rows_to_columns(rows):
+    """The (`_ROWS`, block_q) tile of per-row numbers to (block_q,
+    128): lane c holds the tile's row c % `_ROWS` as a column, the form
+    that subtracts from a (block_q, block_k) score tile."""
+    import jax.numpy as jnp
+
+    return jnp.tile(rows, (_LANES // _ROWS, 1)).T
+
+
+def _keep_q_side(lse_ref, dlt_ref, slot, row_ref, o_ref, g_ref, lanes):
+    """What a backward sweep needs of a q block beside its tiles, into
+    slot `slot` of VMEM scratch: lse_ref[slot] = the log-sums' rows as
+    columns (`_rows_to_columns`), dlt_ref[r, slot] = head r's `delta =
+    rowsum(out * g)`, float32, as a lane-replicated column (the running
+    max's form).  The output and its cotangent are in VMEM as the
+    sweeps' q-side tiles, so the row sum costs one pass over them and
+    no array of its own."""
+    import jax.numpy as jnp
+
+    lse_ref[slot] = _rows_to_columns(row_ref[0])
+    prod = o_ref[0].astype(jnp.float32) * g_ref[0].astype(jnp.float32)
+    for r, lane in enumerate(lanes):
+        own = prod if lane is None else jnp.where(lane, prod, 0.0)
+        dlt_ref[r, slot] = jnp.broadcast_to(
+            jnp.sum(own, axis=1, keepdims=True), dlt_ref.shape[2:])
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, sm_scale, causal,
-                  block_q, block_k, want_lse):
-    if want_lse:
-        lse_ref, acc_ref, m_ref, l_ref = rest
-    else:
-        (acc_ref, m_ref, l_ref), lse_ref = rest, None
+                  block_q, block_k, want_lse, per_block):
+    rest = list(rest)
+    lse_ref = rest.pop(0) if want_lse else None
+    acc_ref, m_ref, l_ref = rest[:3]
+    qh_ref = rest[3] if per_block > 1 else None
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
     i = pl.program_id(1)          # q block
     j = pl.program_id(2)          # k block (innermost, sequential)
+    lanes = _head_lanes(per_block, q_ref.shape[2])
 
     @pl.when(j == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
+        if qh_ref is not None:
+            _keep_head_tiles(qh_ref, q_ref, lanes)
 
     def _step(rows, cols, mask):
-        # native-dtype operands on the MXU, f32 accumulate; the
-        # softmax scale applies to the f32 scores (not the bf16 q,
-        # which would round it into the inputs)
-        s = _dot_f32(q_ref[0, rows], k_ref[0, cols]) * sm_scale
-        if mask is not None:
-            s = mask(s)
-        m_prev = m_ref[rows, 0:1]                     # (rows, 1)
-        l_prev = l_ref[rows, 0:1]
-        m_cur = jnp.max(s, axis=1, keepdims=True)     # (rows, 1)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)                        # (rows, cols)
-        alpha = jnp.exp(m_prev - m_new)               # rescale old state
-        l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[rows] = acc_ref[rows] * alpha + _dot_f32(
-            p, v_ref[0, cols], ((1,), (0,)))
-        lanes = (m_new.shape[0], m_ref.shape[1])
-        m_ref[rows] = jnp.broadcast_to(m_new, lanes)
-        l_ref[rows] = jnp.broadcast_to(l_new, lanes)
+        for r, lane in enumerate(lanes):
+            # native-dtype operands on the MXU, f32 accumulate; the
+            # softmax scale applies to the f32 scores (not the bf16 q,
+            # which would round it into the inputs)
+            q = q_ref[0, rows] if qh_ref is None else qh_ref[r, rows]
+            s = _dot_f32(q, k_ref[0, cols]) * sm_scale
+            if mask is not None:
+                s = mask(s)
+            m_prev = m_ref[r, rows, 0:1]                  # (rows, 1)
+            l_prev = l_ref[r, rows, 0:1]
+            m_cur = jnp.max(s, axis=1, keepdims=True)     # (rows, 1)
+            m_new = jnp.maximum(m_prev, m_cur)
+            p = jnp.exp(s - m_new)                        # (rows, cols)
+            alpha = jnp.exp(m_prev - m_new)               # rescale old state
+            l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+            acc = acc_ref[rows]
+            acc_ref[rows] = _own_lanes(
+                lane, acc * alpha + _dot_f32(p, v_ref[0, cols],
+                                             ((1,), (0,))), acc)
+            wide = (m_new.shape[0], m_ref.shape[2])
+            m_ref[r, rows] = jnp.broadcast_to(m_new, wide)
+            l_ref[r, rows] = jnp.broadcast_to(l_new, wide)
 
-    if causal:
-        _causal_walk(_step, i, j, block_q, block_k, by_rows=True)
-    else:
-        _step(slice(None), slice(None), None)
+    _walk(_step, i, j, block_q, block_k, causal, by_rows=True)
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _finish():
-        l = jnp.maximum(l_ref[:, 0:1], 1e-30)
-        o_ref[0] = (acc_ref[:] / l).astype(o_ref.dtype)
+        out, lses = None, []
+        for r, lane in enumerate(lanes):
+            l = jnp.maximum(l_ref[r, :, 0:1], 1e-30)
+            out = acc_ref[:] / l if out is None \
+                else jnp.where(lane, acc_ref[:] / l, out)
+            lses.append(m_ref[r, :, 0:1] + jnp.log(l))
+        o_ref[0] = out.astype(o_ref.dtype)
         if lse_ref is not None:
-            # per-row log-sum-exp, saved for the backward (lane-
-            # replicated to keep the 128-wide tile shape)
-            lse_ref[0] = jnp.broadcast_to(m_ref[:, 0:1] + jnp.log(l),
-                                          lse_ref.shape[1:])
+            # per-row log-sum-exp, saved for the backward
+            lse_ref[0] = _columns_to_rows(lses)
 
 
 def _flash_forward_pallas(q, k, v, sm_scale, causal, block_q, block_k,
-                          want_lse):
-    """Runs the kernel; returns (out, lse or None).  The LSE output is
-    built only when requested — pallas_call is an opaque custom call,
-    so an unused output would still be written to HBM."""
+                          want_lse, heads=1, per_block=1):
+    """Runs the kernel on q, k, v of [N, T, heads * w], a lane block of
+    width w holding `per_block` heads side by side; returns (out in the
+    same layout, log-sums (N * heads * per_block, T) or None).  The LSE
+    output is built only when requested — pallas_call is an opaque
+    custom call, so an unused output would still be written to HBM."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    bh, tq, d = q.shape
+    n, tq, width = q.shape
+    w = width // heads
     tk = k.shape[1]
-    grid = (bh, pl.cdiv(tq, block_q), pl.cdiv(tk, block_k))
-    kmap = _k_index_map(causal, block_q, block_k)
+    grid = (n * heads, pl.cdiv(tq, block_q), pl.cdiv(tk, block_k))
+    kmap = _k_index_map(causal, block_q, block_k, heads)
+    qmap = _outer_index_map(heads)
     kernel = functools.partial(_flash_kernel, sm_scale=sm_scale,
                                causal=causal, block_q=block_q,
-                               block_k=block_k, want_lse=want_lse)
-    out_shape = [_sds((bh, tq, d), q.dtype, q, k, v)]
-    out_specs = [pl.BlockSpec((1, block_q, d),
-                              lambda b, i, j: (b, i, 0))]
+                               block_k=block_k, want_lse=want_lse,
+                               per_block=per_block)
+    out_shape = [_sds((n, tq, width), q.dtype, q, k, v)]
+    out_specs = [pl.BlockSpec((1, block_q, w), qmap)]
     if want_lse:
         out_shape.append(
-            _sds((bh, tq, 128), jnp.float32, q, k, v))
-        out_specs.append(pl.BlockSpec((1, block_q, 128),
-                                      lambda b, i, j: (b, i, 0)))
+            _sds((n * heads, _ROWS, tq), jnp.float32, q, k, v))
+        out_specs.append(pl.BlockSpec((1, _ROWS, block_q),
+                                      _row_index_map(qmap)))
+    scratch = [
+        pltpu.VMEM((block_q, w), jnp.float32),                 # acc
+        pltpu.VMEM((per_block, block_q, 128), jnp.float32),    # running max
+        pltpu.VMEM((per_block, block_q, 128), jnp.float32),    # running sum
+    ]
+    if per_block > 1:
+        scratch.append(pltpu.VMEM((per_block, block_q, w), q.dtype))
     outs = pl.pallas_call(
         kernel,
         out_shape=tuple(out_shape),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), kmap),
-            pl.BlockSpec((1, block_k, d), kmap),
+            pl.BlockSpec((1, block_q, w), qmap),
+            pl.BlockSpec((1, block_k, w), kmap),
+            pl.BlockSpec((1, block_k, w), kmap),
         ],
         out_specs=tuple(out_specs),
-        scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),     # acc
-            pltpu.VMEM((block_q, 128), jnp.float32),   # running max
-            pltpu.VMEM((block_q, 128), jnp.float32),   # running sum
-        ],
+        scratch_shapes=scratch,
         interpret=_interpret(),
         name="mx_flash_fwd",
     )(q, k, v)
     if want_lse:
-        return outs[0], outs[1][:, :, 0]
+        return outs[0], outs[1][:, :per_block].reshape(-1, tq)
     return outs[0], None
 
 
-def _bwd_p_ds(q_ref, k_ref, v_ref, g_ref, lse_ref, dlt_ref, rows, cols,
-              mask, sm_scale):
-    """Shared backward math of q rows `rows` against k rows `cols` of
-    the block: rebuild the scores against the saved LSE and return
-    (p, ds, q, k, g) -- ONE copy of the ds formula for both sweeps;
-    `mask` is the causal walk's (None below the diagonal)."""
+def _bwd_p_ds(q, k, v, g, lse, dlt, mask, sm_scale):
+    """Shared backward math of one head's q rows (q, its cotangent g,
+    its log-sums and delta as (rows, 1)) against k rows (k, v): rebuild
+    the scores against the saved LSE and return (p, ds) -- ONE copy of
+    the ds formula for both sweeps; `mask` is the causal walk's (None
+    below the diagonal)."""
     import jax.numpy as jnp
 
-    q = q_ref[0, rows]                 # native dtype (see _dot_f32)
-    k = k_ref[0, cols]
-    v = v_ref[0, cols]
-    g = g_ref[0, rows]
-    lse = lse_ref[rows]                # (rows, 1) -- bh dim is squeezed
-    dlt = dlt_ref[rows]                # by the None in its BlockSpec
     s = _dot_f32(q, k) * sm_scale
     if mask is not None:
         s = mask(s)
     p = jnp.exp(s - lse)
     dp = _dot_f32(g, v)
     ds = p * (dp - dlt) * sm_scale
-    return p, ds, q, k, g
+    return p, ds
 
 
-def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, dlt_ref,
-                         dq_ref, acc_ref, *, sm_scale, causal, block_q,
-                         block_k):
-    """dq sweep: grid (bh, nq, nk), k innermost; accumulates
-    ds·K into VMEM scratch and writes the q block's dq once."""
+def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, o_ref, row_ref,
+                         dq_ref, acc_ref, lse_ref, dlt_ref, *kept,
+                         sm_scale, causal, block_q, block_k, per_block):
+    """dq sweep: grid (n * heads, nq, nk), k innermost; accumulates
+    ds·K into VMEM scratch and writes the q block's dq once.  The q
+    side is fixed over the sweep: its log-sums are turned to columns,
+    its delta is taken, and with several heads to a lane block q and g
+    are cut to each head's lanes, once per q block."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
     i = pl.program_id(1)
     j = pl.program_id(2)
+    lanes = _head_lanes(per_block, q_ref.shape[2])
 
     @pl.when(j == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
+        _keep_q_side(lse_ref, dlt_ref, 0, row_ref, o_ref, g_ref, lanes)
+        if kept:
+            _keep_head_tiles(kept[0], q_ref, lanes)
+            _keep_head_tiles(kept[1], g_ref, lanes)
 
     def _step(rows, cols, mask):
-        _, ds, _, k, _ = _bwd_p_ds(
-            q_ref, k_ref, v_ref, g_ref, lse_ref, dlt_ref, rows, cols,
-            mask, sm_scale)
-        acc_ref[rows] = acc_ref[rows] + _dot_f32(ds, k, ((1,), (0,)))
+        k = k_ref[0, cols]                 # native dtype (see _dot_f32)
+        for r, lane in enumerate(lanes):
+            q, g = (kept[0][r, rows], kept[1][r, rows]) if kept \
+                else (q_ref[0, rows], g_ref[0, rows])
+            _, ds = _bwd_p_ds(q, k, v_ref[0, cols], g,
+                              lse_ref[0, rows, r:r + 1],
+                              dlt_ref[r, 0, rows, 0:1], mask, sm_scale)
+            acc = acc_ref[rows]
+            acc_ref[rows] = _own_lanes(
+                lane, acc + _dot_f32(ds, k, ((1,), (0,))), acc)
 
-    if causal:
-        _causal_walk(_step, i, j, block_q, block_k, by_rows=True)
-    else:
-        _step(slice(None), slice(None), None)
+    _walk(_step, i, j, block_q, block_k, causal, by_rows=True)
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _finish():
         dq_ref[0] = acc_ref[:].astype(dq_ref.dtype)
 
 
-def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, dlt_ref,
-                          dk_ref, dv_ref, dk_acc, dv_acc, *, sm_scale,
-                          causal, block_q, block_k):
-    """dk/dv sweep: grid (bh, nk, nq), q innermost."""
+def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, o_ref, row_ref,
+                          dk_ref, dv_ref, dk_acc, dv_acc, lse_ref,
+                          dlt_ref, *kept, sm_scale, causal, block_q,
+                          block_k, per_block):
+    """dk/dv sweep: grid (n * heads, nk, nq), q innermost.  The k side
+    is fixed over the sweep (with several heads to a lane block k and v
+    are cut to each head's lanes once per k block).  What the sweep
+    needs of a q block beside its tiles (`_keep_q_side`) is taken while
+    the first k block is swept, which every q block sees, and kept for
+    the later ones in a slot per q block; where T is too long for that
+    (`lse_ref` has one slot) it is taken every step."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
     j = pl.program_id(1)
     i = pl.program_id(2)
+    lanes = _head_lanes(per_block, q_ref.shape[2])
 
     @pl.when(i == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
+        if kept:
+            _keep_head_tiles(kept[0], k_ref, lanes)
+            _keep_head_tiles(kept[1], v_ref, lanes)
+
+    slots = lse_ref.shape[0]
+    slot = i if slots > 1 else 0
+
+    @pl.when(j == 0 if slots > 1
+             else _visited(i, j, block_q, block_k, causal))
+    def _q_side():
+        _keep_q_side(lse_ref, dlt_ref, slot, row_ref, o_ref, g_ref, lanes)
 
     def _step(rows, cols, mask):
-        p, ds, q, _, g = _bwd_p_ds(
-            q_ref, k_ref, v_ref, g_ref, lse_ref, dlt_ref, rows, cols,
-            mask, sm_scale)
-        dv_acc[cols] = dv_acc[cols] + _dot_f32(p, g, ((0,), (0,)))
-        dk_acc[cols] = dk_acc[cols] + _dot_f32(ds, q, ((0,), (0,)))
+        q = q_ref[0, rows]
+        g = g_ref[0, rows]
+        for r, lane in enumerate(lanes):
+            k, v = (kept[0][r, cols], kept[1][r, cols]) if kept \
+                else (k_ref[0, cols], v_ref[0, cols])
+            p, ds = _bwd_p_ds(q, k, v, g, lse_ref[slot, rows, r:r + 1],
+                              dlt_ref[r, slot, rows, 0:1], mask,
+                              sm_scale)
+            dv = dv_acc[cols]
+            dv_acc[cols] = _own_lanes(
+                lane, dv + _dot_f32(p, g, ((0,), (0,))), dv)
+            dk = dk_acc[cols]
+            dk_acc[cols] = _own_lanes(
+                lane, dk + _dot_f32(ds, q, ((0,), (0,))), dk)
 
-    if causal:
-        # q blocks strictly above this k block's diagonal see none of it
-        _causal_walk(_step, i, j, block_q, block_k, by_rows=False)
-    else:
-        _step(slice(None), slice(None), None)
+    _walk(_step, i, j, block_q, block_k, causal, by_rows=False)
 
     @pl.when(i == pl.num_programs(2) - 1)
     def _finish():
@@ -513,64 +705,82 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, dlt_ref,
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _flash_backward_pallas(q, k, v, g, delta, lse, sm_scale, causal,
-                           block_q, block_k):
+def _flash_backward_pallas(q, k, v, g, out, lse, sm_scale, causal,
+                           block_q, block_k, heads=1, per_block=1):
     """Pallas backward: two kernel launches (dq; dk/dv) over the saved
-    LSE and `delta = rowsum(out * g)` — the TPU-kernel analog of the jnp
-    blocked sweeps below."""
+    output and LSE — the TPU-kernel analog of the jnp blocked sweeps
+    below.  q, k, v, the cotangent g, `out` and the results: [N, T,
+    heads * w], a lane block of width w holding `per_block` heads;
+    `lse`: (N * heads * per_block, T).  `delta = rowsum(out * g)` is
+    taken inside both sweeps from the tiles they hold (`_keep_q_side`):
+    as an XLA reduction it cost two whole-array transposing copies a
+    call, whichever layout it was asked in (PERF.md, PR 35)."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    bh, tq, d = q.shape
+    n, tq, width = q.shape
+    w = width // heads
     tk = k.shape[1]
-    # per-row residuals travel as (bh, tq, 1) columns: the bh dim is a
-    # squeezed (None) block dim, so Mosaic's (8,128) tiling check sees
-    # (block_q, 1) — sublanes divisible by 8, lane dim equal to the
-    # array's.  A (1, block_q) rank-2 block would fail that check
-    # whenever bh is neither 1 nor a multiple of 8.
-    lse3 = lse[..., None]
-    delta3 = delta[..., None]
     nq = tq // block_q
     nk = tk // block_k
+    # the log-sums travel with T along the LANES, a lane block's heads
+    # in the first of `_ROWS` sublanes (a (T, 1) column per head is
+    # padded 128-fold on the chip)
+    rows = jnp.pad(lse.reshape(n * heads, per_block, tq),
+                   ((0, 0), (0, _ROWS - per_block), (0, 0)))
 
-    qspec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
-    kspec = pl.BlockSpec((1, block_k, d),
-                         _k_index_map(causal, block_q, block_k))
-    rspec = pl.BlockSpec((None, block_q, 1), lambda b, i, j: (b, i, 0))
+    qmap = _outer_index_map(heads)
+    qspec = pl.BlockSpec((1, block_q, w), qmap)
+    kspec = pl.BlockSpec((1, block_k, w),
+                         _k_index_map(causal, block_q, block_k, heads))
+    rspec = pl.BlockSpec((1, _ROWS, block_q), _row_index_map(qmap))
+
+    def q_side(slots):      # `_keep_q_side`'s scratch: log-sums, delta
+        return [pltpu.VMEM((slots, block_q, _LANES), jnp.float32),
+                pltpu.VMEM((per_block, slots, block_q, _LANES),
+                           jnp.float32)]
+
+    def kept(block, dtype):
+        return [pltpu.VMEM((per_block, block, w), dtype)] * 2 \
+            if per_block > 1 else []
 
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, sm_scale=sm_scale,
                           causal=causal, block_q=block_q,
-                          block_k=block_k),
-        out_shape=_sds((bh, tq, d), q.dtype, q, k, v, g),
-        grid=(bh, nq, nk),
-        in_specs=[qspec, kspec, kspec, qspec, rspec, rspec],
+                          block_k=block_k, per_block=per_block),
+        out_shape=_sds((n, tq, width), q.dtype, q, k, v, g),
+        grid=(n * heads, nq, nk),
+        in_specs=[qspec, kspec, kspec, qspec, qspec, rspec],
         out_specs=qspec,
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_q, w), jnp.float32)] + q_side(1)
+        + kept(block_q, q.dtype),
         interpret=_interpret(),
         name="mx_flash_dq",
-    )(q, k, v, g, lse3, delta3)
+    )(q, k, v, g, out, rows)
 
-    # dkv grid: (bh, nk, nq) — q innermost; index maps swap (i, j)
-    qmap = _q_index_map(causal, block_q, block_k, nq)
-    qspec2 = pl.BlockSpec((1, block_q, d), qmap)
-    kspec2 = pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0))
-    rspec2 = pl.BlockSpec((None, block_q, 1), qmap)
+    # dkv grid: (n * heads, nk, nq) — q innermost; index maps swap (i, j)
+    qmap2 = _q_index_map(causal, block_q, block_k, nq, heads)
+    qspec2 = pl.BlockSpec((1, block_q, w), qmap2)
+    kspec2 = pl.BlockSpec((1, block_k, w), _outer_index_map(heads))
+    rspec2 = pl.BlockSpec((1, _ROWS, block_q), _row_index_map(qmap2))
+    # a slot per q block where the whole sequence's columns fit
+    slots = nq if (1 + per_block) * tq * _LANES * 4 <= _Q_SIDE_BYTES else 1
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, sm_scale=sm_scale,
                           causal=causal, block_q=block_q,
-                          block_k=block_k),
-        out_shape=(_sds((bh, tk, d), k.dtype, q, k, v, g),
-                   _sds((bh, tk, d), v.dtype, q, k, v, g)),
-        grid=(bh, nk, nq),
-        in_specs=[qspec2, kspec2, kspec2, qspec2, rspec2, rspec2],
+                          block_k=block_k, per_block=per_block),
+        out_shape=(_sds((n, tk, width), k.dtype, q, k, v, g),
+                   _sds((n, tk, width), v.dtype, q, k, v, g)),
+        grid=(n * heads, nk, nq),
+        in_specs=[qspec2, kspec2, kspec2, qspec2, qspec2, rspec2],
         out_specs=(kspec2, kspec2),
-        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_k, w), jnp.float32),
+                        pltpu.VMEM((block_k, w), jnp.float32)]
+        + q_side(slots) + kept(block_k, k.dtype),
         interpret=_interpret(),
         name="mx_flash_dkv",
-    )(q, k, v, g, lse3, delta3)
+    )(q, k, v, g, out, rows)
     return dq, dk, dv
 
 
@@ -599,35 +809,6 @@ def _reference_attention(q, k, v, sm_scale, causal):
     return _reference_attention_lse(q, k, v, sm_scale, causal)[0]
 
 
-def _flash_impl(q, k, v, sm_scale, causal, block_q, block_k, want_lse):
-    """Returns (out, lse-or-None).  The LSE is produced only for the
-    differentiated path: the pallas kernel writes it as a real second
-    output (not prunable), while the jnp reference's unused copy is
-    ordinary dead code."""
-    tq, tk = q.shape[1], k.shape[1]
-    # INVARIANT: the kernel never sees padded KEY positions (a padded
-    # key would need per-position masking inside the kernel); ragged K
-    # lengths take the fused reference path.  Ragged Q is safe — padded
-    # query rows are sliced off.
-    if not _use_pallas() or tk % block_k \
-            or not _tiles(block_q, block_k, q.shape[2]):
-        _count_path("reference")
-        return _reference_attention_lse(q, k, v, sm_scale, causal)
-    _count_path("pallas")
-    _count_fwd(want_lse)
-    _count_tiles(tq, tk, block_q, block_k, causal)
-    pq = (-tq) % block_q
-    if pq:
-        import jax.numpy as jnp
-
-        qp = jnp.pad(q, ((0, 0), (0, pq), (0, 0)))
-        out, lse = _flash_forward_pallas(qp, k, v, sm_scale, causal,
-                                         block_q, block_k, want_lse)
-        return out[:, :tq], (lse[:, :tq] if want_lse else None)
-    return _flash_forward_pallas(q, k, v, sm_scale, causal, block_q,
-                                 block_k, want_lse)
-
-
 # The names the differentiated forward gives its two results.  A
 # `jax.checkpoint` policy that lists them (`executor.apply_remat`'s
 # "dots") keeps them, and the backward pass then does not run the
@@ -638,8 +819,9 @@ FLASH_LSE = "flash_lse"
 
 
 def _split_heads(x):
-    """[B, T, H, D], the activations' layout, -> (B*H, T, D), the
-    kernels'."""
+    """[B, T, H, D], the activations' layout, -> (B*H, T, D): what the
+    reference path computes on, and the kernels where [B, T, H * D] has
+    no lane block that is whole heads."""
     b, t, h, d = x.shape
     return x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
 
@@ -650,24 +832,101 @@ def _merge_heads(x, b):
     return x.reshape(b, bh // b, t, d).transpose(0, 2, 1, 3)
 
 
+def _lane_plan(h, d):
+    """How the kernels read [B, T, h * d]: (heads to a lane block, or 0
+    where no lane block is whole heads and the kernels take a split
+    copy).  A head that is whole 128-lane vregs wide is a block of its
+    own; narrower heads that fill one vreg share it (the kernels walk
+    them inside one grid step; `_ROWS` at most: their log-sums share
+    that tile's sublanes); one head is the whole last dimension,
+    whatever its width."""
+    if h == 1 or d % _LANES == 0:
+        return 1
+    per_block = _LANES // d
+    if _LANES % d == 0 and h % per_block == 0 and per_block <= _ROWS:
+        return per_block
+    return 0
+
+
+def _kernel_layout(xs, launches):
+    """([B, T, H, D] arrays in the kernels' [N, T, heads * w], heads,
+    heads to a lane block): a free reshape in place, `_split_heads`
+    where `_lane_plan` finds no lane block of whole heads.  Counts the
+    `launches` that will read them (`flash_calls_in_place` /
+    `flash_calls_split`) and the heads to a block
+    (`flash_heads_per_block`, a watermark)."""
+    from .. import profiler as _prof
+
+    b, t, h, d = xs[0].shape
+    per_block = _lane_plan(h, d)
+    _prof.inc_stat("flash_calls_in_place" if per_block
+                   else "flash_calls_split", launches)
+    _prof.max_stat("flash_heads_per_block", max(per_block, 1))
+    if per_block:
+        return [x.reshape(x.shape[0], x.shape[1], h * d) for x in xs], \
+            h // per_block, per_block
+    return [_split_heads(x) for x in xs], 1, 1
+
+
+def _from_kernel_layout(x, shape):
+    """A kernel's result back in [B, T, H, D]: the reshape again, or
+    the merge of a split copy (whose rows are B * H)."""
+    return x.reshape(shape) if x.shape[0] == shape[0] \
+        else _merge_heads(x, shape[0])
+
+
+def _takes_kernel(tq, tk, block_q, block_k, d, ragged_q):
+    """Whether these lengths go to the kernels.  INVARIANT: the kernel
+    never sees padded KEY positions (a padded key would need
+    per-position masking inside the kernel); ragged K lengths take the
+    fused reference path.  Ragged Q is safe in the forward pass —
+    padded query rows are sliced off — and takes the jnp sweeps in the
+    backward pass."""
+    return _use_pallas() and tk % block_k == 0 \
+        and (ragged_q or tq % block_q == 0) \
+        and _tiles(block_q, block_k, d)
+
+
 def _flash_merged(q, k, v, sm_scale, causal, block_q, block_k, want_lse):
-    """`_flash_impl` on [B, T, H, D]: split, the forward, merge.
-    Returns ([B, T, H * D], log-sums (B*H, T) or None)."""
-    b, t, h, d = q.shape
-    out, lse = _flash_impl(_split_heads(q), _split_heads(k),
-                           _split_heads(v), sm_scale, causal, block_q,
-                           block_k, want_lse)
-    return _merge_heads(out, b).reshape(b, t, h * d), lse
+    """The forward on [B, T, H, D].  Returns ([B, T, H * D], log-sums
+    (B*H, T) or None).  The LSE is produced only for the differentiated
+    path: the pallas kernel writes it as a real second output (not
+    prunable), while the jnp reference's unused copy is ordinary dead
+    code."""
+    b, tq, h, d = q.shape
+    if not _takes_kernel(tq, k.shape[1], block_q, block_k, d, True):
+        _count_path("reference")
+        out, lse = _reference_attention_lse(
+            _split_heads(q), _split_heads(k), _split_heads(v), sm_scale,
+            causal)
+        return _merge_heads(out, b).reshape(b, tq, h * d), lse
+    _count_path("pallas")
+    _count_fwd(want_lse)
+    _count_tiles(tq, k.shape[1], block_q, block_k, causal)
+    (q, k, v), heads, per_block = _kernel_layout([q, k, v], 1)
+    pq = (-tq) % block_q
+    if pq:
+        import jax.numpy as jnp
+
+        q = jnp.pad(q, ((0, 0), (0, pq), (0, 0)))
+    out, lse = _flash_forward_pallas(q, k, v, sm_scale, causal, block_q,
+                                     block_k, want_lse, heads, per_block)
+    if pq:
+        out, lse = out[:, :tq], (lse[:, :tq] if want_lse else None)
+    return _from_kernel_layout(out, (b, tq, h, d)).reshape(
+        b, tq, h * d), lse
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def _flash(q, k, v, sm_scale, causal, block_q, block_k):
-    """q, k, v: [B, T, H, D]; returns [B, T, H * D].  The head split,
-    the kernels and the merge are all inside the `custom_vjp`, so what
-    it keeps for the backward pass is in the activations' layout: q, k,
-    v as given, the MERGED output and the log-sums.  (A `[B*H, T, 64]`
-    bf16 array is padded to 128 lanes on the chip; `[B, T, H * 64]` is
-    not.)"""
+    """q, k, v: [B, T, H, D]; returns [B, T, H * D].  The kernels read
+    q, k, v (and in the backward pass the cotangent) as [B, T, H * D], a
+    free reshape, and pick a head, or the heads that share a 128-lane
+    vreg, by the lane block of their index maps; they write the output
+    and dq, dk, dv the same way (`_lane_plan`; a shape whose lane blocks
+    are not whole heads takes a split copy, `_split_heads`).  What the
+    `custom_vjp` keeps for the backward pass is q, k, v as given, the
+    output as returned and the log-sums, (B*H, T) float32."""
     return _flash_merged(q, k, v, sm_scale, causal, block_q, block_k,
                          want_lse=False)[0]
 
@@ -693,32 +952,44 @@ def _block_mask(causal, q0, k0, bq, bk):
 
 
 def _flash_bwd(sm_scale, causal, block_q, block_k, res, g):
-    """The backward rule, on the activations' layout: `delta = rowsum(out
-    * g)` is taken from the merged output and the merged cotangent, so
-    the output is never needed in (B*H, T, D) again; q, k, v and the
-    cotangent are split for the sweeps and dq, dk, dv merged back."""
+    """The backward rule, on the activations' layout: the two sweeps
+    read q, k, v, the cotangent and the saved output and write dq, dk,
+    dv in the layout the forward kernel read (`_kernel_layout`)."""
     import jax.numpy as jnp
 
     q, k, v, out, lse = res
     b, tq, h, d = q.shape
     g = g.reshape(q.shape)
-    delta = (out.reshape(q.shape).astype(jnp.float32)
-             * g.astype(jnp.float32)).sum(axis=-1)          # [B, T, H]
-    delta = delta.transpose(0, 2, 1).reshape(b * h, tq)
-    grads = _flash_bwd_sweeps(
-        _split_heads(q), _split_heads(k), _split_heads(v),
-        _split_heads(g), delta, lse, sm_scale, causal, block_q, block_k)
-    return tuple(_merge_heads(x, b) for x in grads)
+    out = out.reshape(q.shape)
+    if not _takes_kernel(tq, k.shape[1], block_q, block_k, d, False):
+        _count_path("reference")
+        delta = (out.astype(jnp.float32) * g.astype(jnp.float32)) \
+            .sum(axis=-1).transpose(0, 2, 1).reshape(b * h, tq)
+        grads = _flash_bwd_sweeps(
+            _split_heads(q), _split_heads(k), _split_heads(v),
+            _split_heads(g), delta, lse, sm_scale, causal, block_q,
+            block_k)
+        return tuple(_merge_heads(x, b) for x in grads)
+    # kernel path (same math as the jnp sweeps, on the MXU)
+    _count_path("pallas")
+    _count_tiles(tq, k.shape[1], block_q, block_k, causal)
+    (q3, k3, v3, g3, o3), heads, per_block = _kernel_layout(
+        [q, k, v, g, out], 2)
+    grads = _flash_backward_pallas(q3, k3, v3, g3, o3, lse, sm_scale,
+                                   causal, block_q, block_k, heads,
+                                   per_block)
+    return tuple(_from_kernel_layout(x, a.shape)
+                 for x, a in zip(grads, (q, k, v)))
 
 
 def _flash_bwd_sweeps(q, k, v, g, delta, lse_saved, sm_scale, causal,
                       block_q, block_k):
-    """Blocked recompute backward (flash attention paper §3.1): scores
-    are rebuilt block by block against the LSE saved by the forward, so
-    backward memory stays O(T·d + block²) — the T×T matrix is never
-    materialized.  Two sweeps (dq; dk/dv), with fully-masked causal
-    blocks skipped via loop bounds.  All of (B*H, T, D); `delta` and
-    `lse_saved` (B*H, T)."""
+    """Blocked recompute backward (flash attention paper §3.1) in jnp,
+    where the kernels do not serve: scores are rebuilt block by block
+    against the LSE saved by the forward, so backward memory stays
+    O(T·d + block²) — the T×T matrix is never materialized.  Two sweeps
+    (dq; dk/dv), with fully-masked causal blocks skipped via loop
+    bounds.  All of (B*H, T, D); `delta` and `lse_saved` (B*H, T)."""
     import jax.numpy as jnp
     from jax import lax
 
@@ -726,14 +997,6 @@ def _flash_bwd_sweeps(q, k, v, g, delta, lse_saved, sm_scale, causal,
     Tk = k.shape[1]
     # blocks arrive pre-clamped by flash_attention_bthd (the only entry)
     bq, bk = block_q, block_k
-    if _use_pallas() and Tq % bq == 0 and Tk % bk == 0 \
-            and _tiles(bq, bk, D):
-        # kernel path (same math as the jnp sweeps below, on the MXU)
-        _count_path("pallas")
-        _count_tiles(Tq, Tk, bq, bk, causal)
-        return _flash_backward_pallas(q, k, v, g, delta, lse_saved,
-                                      sm_scale, causal, bq, bk)
-    _count_path("reference")
     # pad to block multiples; padded K columns are masked by giving
     # them -inf scores via the padded-position test below.  Padded Q
     # rows get lse 0 (finite): their head-gradient rows are zero, so
@@ -831,16 +1094,18 @@ def flash_attention(q, k, v, sm_scale=None, causal=False, block_q=512,
     head_dim).  Returns the same layout as the input.  A caller that
     holds q, k, v as its products leave them, [batch, seq, heads,
     head_dim], takes `flash_attention_bthd`: the same kernels behind
-    the same `custom_vjp`, which then keeps its residuals in that
-    layout (this entry is that one with every (batch, head) pair as a
-    batch row of one head).
+    the same `custom_vjp`, reading that layout in place (this entry is
+    that one with every (batch, head) pair as a batch row of one head,
+    whose lane block is the whole head width: no copy either).
 
     Default 512x512 blocks, walked by class when `causal` (see
     `_causal_walk`).  Measured on a v5e (PERF.md section 5, PR 31;
     bf16, causal, the kernels alone, ms a call, parent -> walk):
     [128, 1024, 64] fwd 0.974 -> 0.796, dq 0.709 -> 0.628, dkv 0.841
     -> 0.759; [40, 4096, 256] fwd 4.377 -> 3.977, dq 4.651 -> 4.015,
-    dkv 5.866 -> 4.433.  Half the forward's time at d=64 is its two
+    dkv 5.866 -> 4.433; read in place (PR 35, in the cells' traces) [8,
+    1024, 16 x 64] 0.825 / 0.544 / 0.694, [2, 4096, 20 x 256] 4.13 /
+    4.20 / 4.74.  Half the forward's time at d=64 is its two
     row reductions; the mask, the scale and the cast are free, and
     the matrix unit is not what binds.  No block sweep per (T, d) is
     on record.  Blocks
@@ -862,10 +1127,12 @@ def flash_attention_bthd(q, k, v, sm_scale=None, causal=False,
     """`flash_attention` on the activations' own layout: q, k, v of
     [batch, seq, heads, head_dim] (a reshape of the projections'
     [batch, seq, heads * head_dim]); returns [batch, seq, heads *
-    head_dim], what the out-projection reads.  The split to (batch *
-    heads, seq, head_dim), the three kernels and the merge sit inside
+    head_dim], what the out-projection reads.  The three kernels read
+    and write that layout in place (`_lane_plan`: a head that is whole
+    128-lane vregs wide is a lane block of its own, narrower heads that
+    fill a vreg share one; any other shape takes a split copy), inside
     ONE `custom_vjp` (`_flash`), whose residuals are q, k, v as given,
-    the merged output and the log-sums; the last two carry the names
+    the output and the log-sums; the last two carry the names
     `FLASH_OUT` / `FLASH_LSE` (`jax.ad_checkpoint.checkpoint_name`), so
     a remat policy that lists them keeps the forward kernel from
     running twice."""

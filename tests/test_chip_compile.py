@@ -100,10 +100,10 @@ _FLASH_CALL = re.compile(
 
 def _flash_kernels(text):
     """The flash kernels in a compiled program's text, by name, with
-    the [bh, t, d] each returns first."""
+    the [b, t, h * d] each returns first."""
     kernels = {}
-    for name, bh, t, d in _FLASH_CALL.findall(text):
-        kernels.setdefault(name, set()).add((int(bh), int(t), int(d)))
+    for name, b, t, hd in _FLASH_CALL.findall(text):
+        kernels.setdefault(name, set()).add((int(b), int(t), int(hd)))
     return kernels
 
 
@@ -122,15 +122,17 @@ def _described_bytes(compiled):
 
 _COMPUTATION = re.compile(r"^(ENTRY )?%?([\w.\-]+) \(.*\{\s*$")
 _WHILE = re.compile(r"\bwhile\(.*\bbody=%?([\w.\-]+)")
-_COPY = re.compile(r"= \w+\[([\d,]*)\][^ ]* copy\(")
+_COPY = re.compile(r"= \w+\[([\d,]*)\][^ ]* (?:copy|transpose)\(")
 
 
-def _whiles_and_stack_copies(text, n_layers):
+def _whiles_and_stack_copies(text, n_layers, split=()):
     """What the compiled text says of its loops: a list of (body's name,
     whether the `while` sits in the ENTRY computation), one per `while`,
-    and by computation name the `copy` instructions in it whose result
-    has `n_layers` as its leading dimension (a leading 1 set aside)."""
-    whiles, copies, name, entry = [], {}, None, False
+    and by computation name the `copy` / `transpose` instructions in it
+    whose result has `n_layers` as its leading dimension (a leading 1
+    set aside); and the same for those whose result is one of the
+    shapes `split`."""
+    whiles, copies, splits, name, entry = [], {}, {}, None, False
     for line in text.splitlines():
         m = _COMPUTATION.match(line)
         if m:
@@ -144,9 +146,19 @@ def _whiles_and_stack_copies(text, n_layers):
             dims = [int(d) for d in m.group(1).split(",") if d]
             while dims[:1] == [1]:
                 dims = dims[1:]
-            if dims[:1] == [n_layers]:
-                copies.setdefault(name, []).append(line.strip()[:120])
-    return whiles, copies
+            for found, hit in ((copies, dims[:1] == [n_layers]),
+                               (splits, tuple(dims) in split)):
+                if hit:
+                    found.setdefault(name, []).append(line.strip()[:120])
+    return whiles, copies, splits
+
+
+def _split_shapes(b, t, h, d):
+    """The shapes a head split or merge copy of [b, t, h * d] results
+    in: the kernels read that layout in place since PR 35, and before
+    it every attention call made eleven such copies (seventeen of them
+    in `gpt2m_fused_k8`'s two layer loops)."""
+    return {(b * h, t, d), (b, h, t, d), (b, t, h, d), (b * h, t, 1)}
 
 
 @pytest.mark.parametrize("k_steps,remat", [
@@ -167,8 +179,17 @@ def test_lm_step_keeps_layer_stacks_in_place(one_chip_mesh, monkeypatch,
     # the program asks jax.devices() whether it is on a TPU, and here
     # that is the CPU: steer it in the test, not by an option of the
     # program, so that the Pallas kernels go through Mosaic
+    from mxtpu import profiler
+
     monkeypatch.setattr(pa, "_on_tpu", lambda: True)
+    split = profiler.get_stat("flash_calls_split")
+    profiler.set_stat("flash_heads_per_block", 0)
     compiled = _lm_program(one_chip_mesh, remat, k_steps)
+    # every launch traced reads [8, 1024, 16 x 64] in place, two heads
+    # to a 128-lane block (the ling cell's thread traces beside this
+    # one: it adds no split either, and one head a block)
+    assert profiler.get_stat("flash_calls_split") == split
+    assert profiler.get_stat("flash_heads_per_block") == 2
     text = compiled.as_text()
     # remat="dots" keeps the forward kernel's merged output and log-sums
     # (PR 33), so the backward pass holds dq and dkv and NO second call
@@ -181,15 +202,20 @@ def test_lm_step_keeps_layer_stacks_in_place(one_chip_mesh, monkeypatch,
         # GiB) fails here before `peak_hbm_gib` refuses it on the chip
         assert _described_bytes(compiled) <= (13.2965 + 0.08) * 2 ** 30, \
             _described_bytes(compiled)
-    # the three flash kernels, by name, at the cell's [128, 1024, 64]
-    # (16 heads x 8 sequences): Mosaic lowered the causal walk's
-    # sub-tiled diagonal at d=64 for the described chip
-    want = {(WIDTHS["n_heads"] * BATCH, SEQ,
-             WIDTHS["d_model"] // WIDTHS["n_heads"])}
+    # the three flash kernels, by name, on the activations' own [8,
+    # 1024, 16 x 64] (two 64-wide heads to a 128-lane block): Mosaic
+    # lowered the causal walk's sub-tiled diagonal, the lane-block index
+    # maps and the in-kernel transposes of the log-sums for the
+    # described chip
+    want = {(BATCH, SEQ, WIDTHS["d_model"])}
     kernels = _flash_kernels(text)
     assert kernels == {"mx_flash_fwd": want, "mx_flash_dq": want,
                        "mx_flash_dkv": want}, kernels
-    whiles, copies = _whiles_and_stack_copies(text, WIDTHS["n_layers"])
+    # ... and no head split / merge copy is left in a layer loop
+    whiles, copies, splits = _whiles_and_stack_copies(
+        text, WIDTHS["n_layers"],
+        _split_shapes(BATCH, SEQ, WIDTHS["n_heads"],
+                      WIDTHS["d_model"] // WIDTHS["n_heads"]))
     # the loops that run once per layer: every `while` of the per-step
     # program; in the fused one, those inside the K loop, which is the
     # `while` of the ENTRY computation (a copy in ITS body runs once per
@@ -198,6 +224,9 @@ def test_lm_step_keeps_layer_stacks_in_place(one_chip_mesh, monkeypatch,
                    if k_steps is None or not in_entry]
     found = [c for body in layer_loops for c in copies.get(body, [])]
     assert not found, ("whole-stack copies once per layer:\n  "
+                       + "\n  ".join(found))
+    found = [c for body in layer_loops for c in splits.get(body, [])]
+    assert not found, ("head split / merge copies once per layer:\n  "
                        + "\n  ".join(found))
     assert len(layer_loops) == 2, whiles
     assert len(whiles) == (2 if k_steps is None else 3), whiles
@@ -234,8 +263,9 @@ def test_glm_cell_program_fits_one_chip_and_keeps_its_kernels(
         one_chip_mesh, monkeypatch):
     """The cell's K=4 program at 2 x 4096 tokens a step, compiled for a
     described v5e: its arguments and temporaries fit the chip; the three
-    flash kernels are in it at [40, 4096, 256] (20 heads x 2 sequences,
-    q.k 192 + 64 = v 256); the grouped expert products went to XLA's own
+    flash kernels are in it on [2, 4096, 20 x 256] (a 256-wide head is
+    a lane block of its own; q.k 192 + 64 = v 256), and no head split
+    or merge copy anywhere; the grouped expert products went to XLA's own
     Mosaic kernel; no loop over the layers copies a whole weight stack
     in its body; and the loops are the K loop and the forward and
     backward scan of the four expert layers (the one-layer segments, the
@@ -246,7 +276,11 @@ def test_glm_cell_program_fits_one_chip_and_keeps_its_kernels(
     from mxtpu.ops import pallas_attention as pa
     from mxtpu.parallel import transformer as tf
 
+    from mxtpu import profiler
+
     monkeypatch.setattr(pa, "_on_tpu", lambda: True)
+    split = profiler.get_stat("flash_calls_split")
+    profiler.set_stat("flash_heads_per_block", 0)
     cfg, config, traffic = _glm_cell()
     k, b = traffic["steps_per_program"], traffic["batch"]
     step, sh = tf.make_fused_train_steps(cfg, one_chip_mesh, k, lr=3e-4,
@@ -266,6 +300,10 @@ def test_glm_cell_program_fits_one_chip_and_keeps_its_kernels(
     data = jax.ShapeDtypeStruct((k, b, config["input"]["length"]),
                                 jnp.int32, sharding=sh["data"])
     compiled = step.lower(params, opt, data, data).compile()
+    # in place, a 256-wide head a lane block (the ling cell's too)
+    assert profiler.get_stat("flash_calls_split") == split
+    assert profiler.get_stat("flash_heads_per_block") == 1
+    assert pa._lane_plan(32, 256) == 1
     need = _described_bytes(compiled)
     assert need < _CHIP_BYTES, "arguments + temporaries %.3e bytes" % need
     # a full chip, as a training job's is (PERF.md: 16.0e9 of 16.9e9)
@@ -275,8 +313,9 @@ def test_glm_cell_program_fits_one_chip_and_keeps_its_kernels(
     assert need <= 16027537920 + 0.05 * 2 ** 30, need
 
     text = compiled.as_text()
-    heads = config["num_attention_heads"] * b
-    want = {(heads, config["input"]["length"], config["v_head_dim"])}
+    heads, width = config["num_attention_heads"], config["v_head_dim"]
+    length = config["input"]["length"]
+    want = {(b, length, heads * width)}
     kernels = _flash_kernels(text)
     assert kernels == {"mx_flash_fwd": want, "mx_flash_dq": want,
                        "mx_flash_dkv": want}, kernels
@@ -286,10 +325,16 @@ def test_glm_cell_program_fits_one_chip_and_keeps_its_kernels(
     assert _mosaic_call_sites(text) == 35
 
     layers = config["num_hidden_layers"] - config["first_k_dense_replace"]
-    whiles, copies = _whiles_and_stack_copies(text, layers)
+    whiles, copies, splits = _whiles_and_stack_copies(
+        text, layers, _split_shapes(b, length, heads, width))
     layer_loops = [body for body, in_entry in whiles if not in_entry]
     found = [c for body in layer_loops for c in copies.get(body, [])]
     assert not found, ("whole-stack copies once per layer:\n  "
+                       + "\n  ".join(found))
+    # the K loop's body holds the one-layer segments (the dense layer,
+    # the MTP block): head split / merge copies are looked for there too
+    found = [c for body, _ in whiles for c in splits.get(body, [])]
+    assert not found, ("head split / merge copies once per layer:\n  "
                        + "\n  ".join(found))
     # recorded: 3 whiles (PR 30)
     assert len(layer_loops) == 2 and len(whiles) == 3, whiles
@@ -381,9 +426,9 @@ def ling_compiled(one_chip_mesh):
 def test_ling_cell_program_compiles_for_one_chip_and_keeps_its_kernels(
         ling_compiled):
     """The cell's K=4 program at 2 x 2048 tokens a step, compiled for a
-    described v5e: the three flash kernels are in it at [64, 2048, 256]
-    (32 heads x 2 sequences; q.k 192 and v 128 padded to the kernels'
-    one width); the grouped expert products went to XLA's own Mosaic
+    described v5e: the three flash kernels are in it on [2, 2048, 32 x
+    256] (q.k 192 and v 128 padded to the kernels' one width, a lane
+    block a head); the grouped expert products went to XLA's own Mosaic
     kernel; the described bytes are bounded.  The chip reserves about
     three quarters of the described temporaries (PERF.md section 7), so
     the bound here is above the chip's 16.9e9."""
@@ -396,8 +441,8 @@ def test_ling_cell_program_compiles_for_one_chip_and_keeps_its_kernels(
     assert 0.75 * _CHIP_BYTES < need <= LING_DESCRIBED + 0.05 * 2 ** 30, need
 
     text = compiled.as_text()
-    heads = config["num_attention_heads"] * traffic["batch"]
-    want = {(heads, config["input"]["length"], 256)}
+    want = {(traffic["batch"], config["input"]["length"],
+             config["num_attention_heads"] * 256)}
     kernels = _flash_kernels(text)
     assert kernels == {"mx_flash_fwd": want, "mx_flash_dq": want,
                        "mx_flash_dkv": want}, kernels

@@ -215,7 +215,7 @@ def test_pallas_backward_kernels_match_jnp_sweeps(causal, monkeypatch):
     scale = 1.0 / np.sqrt(32)
     out, lse = fa._reference_attention_lse(q, k, v, scale, causal)
     delta = (out * g).sum(-1)
-    got = fa._flash_backward_pallas(q, k, v, g, delta, lse, scale,
+    got = fa._flash_backward_pallas(q, k, v, g, out, lse, scale,
                                     causal, 64, 64)
     # jnp sweeps: disable the pallas route for the direct comparison
     monkeypatch.setenv("MXTPU_NO_PALLAS", "1")
@@ -386,3 +386,125 @@ def test_flash_fwd_stats(grad):
     named = {e.params["name"] for e in jaxpr.eqns
              if e.primitive.name == "name"}
     assert named == ({fa.FLASH_OUT, fa.FLASH_LSE} if grad else set())
+
+
+# (heads, head width) -> (heads to a lane block, or 0: a split copy):
+# how `_lane_plan` has the kernels read [B, T, H * D]; None stands for
+# the (bh, t, d) entry, which arrives as one head
+_LAYOUT_CASES = {
+    "two_heads_to_a_block": ((16, 64), 2),
+    "head_is_a_block_128": ((4, 128), 1),
+    "head_is_a_block_256": ((20, 256), 1),
+    "does_not_tile_split": ((3, 64), 0),
+    "bh_t_d_entry": (None, 1),
+}
+
+
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("case", list(_LAYOUT_CASES), ids=list(_LAYOUT_CASES))
+def test_kernels_read_the_activations_layout_in_place(case, causal):
+    """The kernels on [B, T, H * D] as it is (a head, or the heads that
+    share a 128-lane vreg, picked by the lane block of the index maps):
+    forward and all three gradients against `_reference_attention`, and
+    against the same numbers through the (bh, t, d) entry -- today's
+    program before PR 35 -- to float32 rounding; and what the three
+    stats of the layout read for each case."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxtpu import profiler
+    from mxtpu.ops import pallas_attention as fa
+
+    heads_width, per_block = _LAYOUT_CASES[case]
+    h, d = heads_width or (2, 64)
+    b, t, block = 1, 512, 256       # 2 x 2 blocks of 2 x 2 sub-tiles
+    rng = np.random.RandomState(h * d)
+    q, k, v, w = (jnp.asarray(rng.normal(0, 1, (b, t, h, d))
+                              .astype(np.float32)) for _ in range(4))
+    scale = 1.0 / np.sqrt(d)
+
+    def split(x):
+        return x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
+
+    def merge(x):
+        return x.reshape(b, h, t, d).transpose(0, 2, 1, 3)
+
+    def by_entry(q, k, v):          # one head a batch row
+        return merge(fa.flash_attention(split(q), split(k), split(v),
+                                        causal=causal, block_q=block,
+                                        block_k=block))
+
+    def in_place(q, k, v):
+        return fa.flash_attention_bthd(
+            q, k, v, causal=causal, block_q=block,
+            block_k=block).reshape(b, t, h, d)
+
+    def ref(q, k, v):
+        return merge(fa._reference_attention(split(q), split(k), split(v),
+                                             scale, causal))
+
+    names = ("flash_calls_in_place", "flash_calls_split")
+    before = [profiler.get_stat(n) for n in names]
+    profiler.set_stat("flash_heads_per_block", 0)
+    got, vjp = jax.vjp(by_entry if heads_width is None else in_place,
+                       q, k, v)
+    grads = vjp(w)
+    calls = tuple(profiler.get_stat(n) - x for n, x in zip(names, before))
+    # a forward launch and the backward pass's two
+    assert calls == ((3, 0) if per_block else (0, 3)), calls
+    assert profiler.get_stat("flash_heads_per_block") == max(per_block, 1)
+
+    gold, vjp_ref = jax.vjp(ref, q, k, v)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(gold),
+                               rtol=2e-4, atol=2e-5)
+    for a, g, name in zip(grads, vjp_ref(w), "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(g),
+                                   rtol=2e-3, atol=2e-4,
+                                   err_msg="d%s" % name)
+    if heads_width is None:
+        return
+    same, vjp_same = jax.vjp(by_entry, q, k, v)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(same),
+                               rtol=1e-5, atol=1e-6)
+    for a, g, name in zip(grads, vjp_same(w), "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(g),
+                                   rtol=1e-5, atol=2e-5,
+                                   err_msg="d%s against the entry" % name)
+
+
+@pytest.mark.parametrize("fits", [True, False], ids=["a_slot_a_q_block",
+                                                     "taken_every_step"])
+def test_dkv_sweep_keeps_the_q_side_where_it_fits(fits, monkeypatch):
+    """The dkv sweep takes a q block's columns (log-sums, delta) while it
+    sweeps the first k block and keeps them in a slot per q block; where
+    the sequence is too long for that it takes them every step.  Both
+    ways give the reference's dk and dv (two heads to a lane block, 4 x
+    4 blocks, causal)."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxtpu.ops import pallas_attention as fa
+
+    if not fits:
+        monkeypatch.setattr(fa, "_Q_SIDE_BYTES", 0)
+    b, t, h, d = 1, 512, 2, 64
+    rng = np.random.RandomState(35)
+    q, k, v, w = (jnp.asarray(rng.normal(0, 1, (b, t, h, d))
+                              .astype(np.float32)) for _ in range(4))
+
+    def split(x):
+        return x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
+
+    def ref(q, k, v):
+        out = fa._reference_attention(split(q), split(k), split(v),
+                                      1.0 / np.sqrt(d), True)
+        return out.reshape(b, h, t, d).transpose(0, 2, 1, 3)
+
+    _, vjp = jax.vjp(lambda q, k, v: fa.flash_attention_bthd(
+        q, k, v, causal=True, block_q=128, block_k=128)
+        .reshape(b, t, h, d), q, k, v)
+    _, vjp_ref = jax.vjp(ref, q, k, v)
+    for a, g, name in zip(vjp(w), vjp_ref(w), "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(g),
+                                   rtol=2e-3, atol=2e-4,
+                                   err_msg="d%s" % name)
