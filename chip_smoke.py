@@ -93,6 +93,11 @@ class _Sizes(object):
             # (batch, T, heads, q.k width, v width): latent attention
             # whose q.k is wider than v, padded to the kernels' one width
             self.attn_unequal = (2, 2048, 4, 192, 128)
+            # (batch, T, q heads, kv heads, d, window): grouped kv heads
+            # read in place under a window and under none, at
+            # `trinitym_ep16_fused_k4`'s own shape (four windows a
+            # sequence), against attention in blocks of query rows
+            self.attn_grouped = (2, 8192, 32, 4, 128, 2048)
             # (batch, T, heads, d, chunk, re-basing): the chunked KDA
             # core against the recurrence, at the published head
             self.kda = (1, 2048, 4, 128, 64, 16)
@@ -107,6 +112,7 @@ class _Sizes(object):
             self.attn_ragged, self.attn_ragged_block = (2, 50, 16), 32
             self.attn_bthd = [((4, 128, 64), 2), ((2, 128, 256), 2)]
             self.attn_unequal = (1, 128, 2, 24, 16)
+            self.attn_grouped = (1, 256, 4, 2, 128, 100)
             self.kda = (1, 40, 2, 16, 16, 4)
             self.lm = dict(vocab=64, d_model=32, n_heads=2, n_layers=2,
                            d_ff=64, max_len=64)
@@ -398,6 +404,21 @@ _PARENT_KERNEL_MS = {(128, 1024, 64): (0.794, 0.629, 0.759),
                      (40, 4096, 256): (3.98, 4.04, 4.43)}
 
 
+def _ms_a_call(fn, args, calls):
+    """ms a call of jitted `fn(*args)` over `calls` back-to-back calls
+    after a warm one; None with `calls` 0 (the rehearsal: a CPU time is
+    no kernel time), where it runs once."""
+    fn = jax.jit(fn)
+    r = jax.block_until_ready(fn(*args))
+    if not calls:
+        return None
+    t0 = time.perf_counter()
+    for _ in range(calls):
+        r = fn(*args)
+    jax.block_until_ready(r)
+    return round((time.perf_counter() - t0) / calls * 1e3, 3)
+
+
 def _kernel_times(shape, heads, calls=20):
     """ms a call of each flash kernel alone, on [B, T, heads * d] read
     in place as the LM's blocks hand it over, beside the parent's on
@@ -422,18 +443,80 @@ def _kernel_times(shape, heads, calls=20):
             q, k, v, *args, True, *lanes),
         "dq": lambda q, k, v: bwd(q, k, v)[0],
         "dkv": lambda q, k, v: bwd(q, k, v)[1:]}
-    times = {}
-    for (name, fn), was in zip(kernels.items(),
-                               _PARENT_KERNEL_MS.get(shape, (None,) * 3)):
-        fn = jax.jit(fn)
-        r = jax.block_until_ready(fn(q, k, v))
-        t0 = time.perf_counter()
-        for _ in range(calls):
-            r = fn(q, k, v)
-        jax.block_until_ready(r)
-        times[name] = [round((time.perf_counter() - t0) / calls * 1e3, 3)
-                       if calls else None, was]
-    return times
+    return {name: [_ms_a_call(fn, (q, k, v), calls), was]
+            for (name, fn), was in zip(
+                kernels.items(), _PARENT_KERNEL_MS.get(shape, (None,) * 3))}
+
+
+def _grouped_band_check(shape, window, calls=20):
+    """The three kernels on q [B, T, H, d] and k, v [B, T, Hkv, d] read
+    in place, under `window` (None: the causal prefix): output and all
+    three gradients against attention computed a head and a block of
+    query rows at a time (a plain `repeat` of the kv heads, an index
+    compare for the mask); returns (worst normalized error, ms a call
+    of [fwd, dq, dkv] alone, or None with `calls` 0)."""
+    b, t, h, hkv, d, _ = shape
+    rng = np.random.RandomState(t + hkv)
+    q, cot = (jnp.asarray(rng.normal(0, 1, (b, t, h, d)), jnp.bfloat16)
+              for _ in range(2))
+    k, v = (jnp.asarray(rng.normal(0, 1, (b, t, hkv, d)), jnp.bfloat16)
+            for _ in range(2))
+    rows = min(1024, t)
+
+    def loss(fn):
+        return lambda q, k, v: (fn(q, k, v).astype(jnp.float32)
+                                * cot.astype(jnp.float32)).sum()
+
+    def flash(q, k, v):
+        return pa.flash_attention_bthd(q, k, v, causal=True,
+                                       window=window).reshape(b, t, h, d)
+
+    def ref(q, k, v):
+        def head(args):                 # [B, T, d] each
+            qh, kh, vh = (a.astype(jnp.float32) for a in args)
+
+            def block(r):
+                qr = jax.lax.dynamic_slice_in_dim(qh, r * rows, rows, 1)
+                s = jnp.einsum("bqd,bkd->bqk", qr, kh) / np.sqrt(d)
+                qi = r * rows + jnp.arange(rows)[:, None]
+                ki = jnp.arange(t)[None]
+                seen = ki <= qi
+                if window is not None:
+                    seen &= qi - ki < window
+                p = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
+                return jnp.einsum("bqk,bkd->bqd", p, vh)
+
+            o = jax.lax.map(jax.checkpoint(block), jnp.arange(t // rows))
+            return o.transpose(1, 0, 2, 3).reshape(b, t, d)
+
+        k, v = (jnp.repeat(a, h // hkv, axis=2) for a in (k, v))
+        o = jax.lax.map(jax.checkpoint(head), tuple(
+            a.transpose(2, 0, 1, 3) for a in (q, k, v)))
+        return o.transpose(1, 2, 0, 3).astype(q.dtype)
+
+    got = (jax.jit(flash)(q, k, v),) + \
+        jax.jit(jax.grad(loss(flash), argnums=(0, 1, 2)))(q, k, v)
+    want = (jax.jit(ref)(q, k, v),) + \
+        jax.jit(jax.grad(loss(ref), argnums=(0, 1, 2)))(q, k, v)
+    err = _worst_error(got, want, ("out", "dq", "dk", "dv"),
+                       "flash at [B, T, H, Hkv, d, window]=%s under %s"
+                       % (shape, window), 3e-2)
+    if not calls:
+        return err, None
+    q3, g3, o3 = (a.reshape(b, t, h * d) for a in (q, cot, got[0]))
+    k3, v3 = (a.reshape(b, t, hkv * d) for a in (k, v))
+    lse = jnp.zeros((b * h, t), jnp.float32) + 6.0
+    args = (float(d) ** -0.5, True, min(512, t), min(512, t))
+    lanes = (h, 1, h // hkv, window)
+
+    def bwd(q, k, v):
+        return pa._flash_backward_pallas(q, k, v, g3, o3, lse, *args, *lanes)
+
+    return err, [_ms_a_call(fn, (q3, k3, v3), calls) for fn in (
+        lambda q, k, v: pa._flash_forward_pallas(q, k, v, *args, True,
+                                                 *lanes),
+        lambda q, k, v: bwd(q, k, v)[0],
+        lambda q, k, v: bwd(q, k, v)[1:])]
 
 
 def _on_one_device_mesh(fn, *args):
@@ -582,6 +665,21 @@ def phase3_lm(sizes, meter):
     err, span = _kda_check(sizes.kda)
     info["kda_chunked_err"], info["kda_span_nats"] = round(err, 5), \
         round(span, 2)
+    expanded = stats0.get("flash_kv_expanded", 0)
+    for window in (sizes.attn_grouped[-1], None):
+        err, ms = _grouped_band_check(sizes.attn_grouped, window,
+                                      0 if sizes.rehearse else 20)
+        name = "window_%d" % window if window else "causal"
+        info["attn_grouped_%s_err" % name] = round(err, 5)
+        info["attn_grouped_%s_ms_fwd_dq_dkv" % name] = ms
+        print("grouped kv heads %s, %s: worst error %.2e, ms a call "
+              "[fwd, dq, dkv] %s" % (sizes.attn_grouped[:5], name, err, ms),
+              flush=True)
+    _require(profiler.get_stat("flash_kv_expanded") == expanded
+             and profiler.get_stat("flash_kv_group")
+             == sizes.attn_grouped[2] // sizes.attn_grouped[3],
+             "the grouped kv heads were not read in place: %s"
+             % profiler.stats())
     stats1 = dict(profiler.stats())
     _require(stats1.get("flash_attention_pallas", 0)
              > stats0.get("flash_attention_pallas", 0)

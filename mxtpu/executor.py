@@ -87,7 +87,12 @@ def apply_remat(fn, policy_name, prevent_cse=True, named=False):
       ninth of the kernel call it saves at gpt2-medium's widths), the
       up-projection of q out of its latent `c_q @ wq_b` in `_mla`
       (as wide as the kept output, a tenth of the kernel call at
-      GLM-4.7-Flash's widths; `o @ wo` is narrower and dearer there).
+      GLM-4.7-Flash's widths; `o @ wo` is narrower and dearer there),
+      the output gate's product `x @ w_gate` in `_gqa` (n_heads *
+      head_dim wide, as q's is and the kept output: 8 KB a token at
+      Trinity-Mini's widths where `o @ wo`'s is 4 and k's and v's 1
+      each, all for the same [E, 4096] product or less; q's where the
+      block has no gate).
 
     Pass prevent_cse=False when fn is a `lax.scan` body: the CSE
     barriers are unnecessary under scan (per the jax.checkpoint docs)

@@ -170,17 +170,24 @@ def _count_fwd(named):
         _prof.inc_stat("flash_fwd_named")
 
 
-def _causal_mask(i, j, block_q, block_k):
+def _causal_mask(i, j, block_q, block_k, window=None):
     """(block_q, block_k) mask of the (i, j) score block: the whole-block
     form, for a block on the diagonal where `block_q != block_k`; a
-    2-D iota, the form the Pallas TPU guide asks for."""
+    2-D iota, the form the Pallas TPU guide asks for.  With a `window`
+    also the key no more than `window` - 1 behind the query (`k > q -
+    window`), for a block the band's LEFT edge crosses: the edge's
+    offset inside a block follows `i - j` (two values where the window
+    is no multiple of the block), so no static sub-tiling serves every
+    window."""
     from jax import lax
     import jax.numpy as jnp
 
     shape = (block_q, block_k)
     q_idx = lax.broadcasted_iota(jnp.int32, shape, 0) + i * block_q
     k_idx = lax.broadcasted_iota(jnp.int32, shape, 1) + j * block_k
-    return q_idx >= k_idx
+    if window is None:
+        return q_idx >= k_idx
+    return jnp.logical_and(q_idx >= k_idx, k_idx > q_idx - window)
 
 
 def _sub_tile(block_q, block_k):
@@ -211,7 +218,29 @@ def _mask_corner(s, tri, axis):
         [jnp.where(tri, s[:c], _NEG_INF), s[c:]], axis=0)
 
 
-def _causal_walk(step, i, j, block_q, block_k, by_rows):
+def _block_classes(i, j, block_q, block_k, window=None, inside=None):
+    """(visited, below, edge) of the (i, j) score block under the causal
+    mask and, with `window`, the band `q - window < k <= q`; traced
+    booleans (`edge` None without a window).  `visited`: not above the
+    diagonal, not wholly left of the band, and `inside` the score matrix
+    (a band sweep's last steps can stand past its end).  `below`: the
+    diagonal does not cross it.  `edge`: the band's left edge does."""
+    import jax.numpy as jnp
+
+    visited = j * block_k <= (i + 1) * block_q - 1
+    below = (j + 1) * block_k - 1 <= i * block_q
+    if inside is not None:
+        visited = jnp.logical_and(visited, inside)
+    if window is None:
+        return visited, below, None
+    left = (j + 1) * block_k - 1 <= i * block_q - window
+    visited = jnp.logical_and(visited, jnp.logical_not(left))
+    edge = j * block_k <= (i + 1) * block_q - 1 - window
+    return visited, below, edge
+
+
+def _causal_walk(step, i, j, block_q, block_k, by_rows, window=None,
+                 inside=None):
     """THE causal walk, one copy for the forward and both backward
     sweeps: which class the (i, j) block is follows from the block
     indices and the static block shape alone, and only that class's
@@ -219,30 +248,48 @@ def _causal_walk(step, i, j, block_q, block_k, by_rows):
     against k rows `cols` of the block (static slices); `mask` maps
     the f32 scores to masked scores, or is None.
 
-    1. above the diagonal: skipped;
-    2. strictly below it: ONE unmasked step over the whole block (no
-       iota, no compare, no select);
-    3. crossed by it: with equal blocks, static `_SUB`-wide chunks --
-       per q row chunk one step over its k column prefix (`by_rows`:
-       forward and dq, whose state is per q row), or per k column
-       chunk one step over its q row suffix (dkv, whose state is per k
-       row); sub-tiles above the diagonal are never emitted, and the
-       one sub-tile on it takes a LOCAL triangular mask with no
+    1. above the diagonal, or with a static `window` wholly left of the
+       band (`_block_classes`): skipped;
+    2. strictly below the diagonal (and right of the band's left edge):
+       ONE unmasked step over the whole block (no iota, no compare, no
+       select);
+    3. crossed by the diagonal: with equal blocks, static `_SUB`-wide
+       chunks -- per q row chunk one step over its k column prefix
+       (`by_rows`: forward and dq, whose state is per q row), or per k
+       column chunk one step over its q row suffix (dkv, whose state is
+       per k row); sub-tiles above the diagonal are never emitted, and
+       the one sub-tile on it takes a LOCAL triangular mask with no
        `program_id` in it.  With unequal blocks, the whole block under
-       `_causal_mask`."""
+       `_causal_mask`;
+    4. crossed by the band's left edge (the diagonal may cross it too,
+       where the window is narrower than a block): the whole block
+       under `_causal_mask`'s band form.  A row of it that sees none of its keys
+       leaves the forward's state as a later block finds it: its
+       running max stays at the floor, so the next block's rescale is
+       by `exp(floor - max) = 0`."""
     from jax import lax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
     full = slice(None)
-    visited = j * block_k <= (i + 1) * block_q - 1
-    below = (j + 1) * block_k - 1 <= i * block_q
+    visited, below, edge = _block_classes(i, j, block_q, block_k, window,
+                                          inside)
+    if edge is not None:
+        off_edge = jnp.logical_and(visited, jnp.logical_not(edge))
 
-    @pl.when(below)
+        @pl.when(jnp.logical_and(visited, edge))
+        def _edge():
+            mask = _causal_mask(i, j, block_q, block_k, window)
+            step(full, full, lambda s: jnp.where(mask, s, _NEG_INF))
+    else:
+        off_edge = visited
+
+    @pl.when(below if edge is None and inside is None
+             else jnp.logical_and(off_edge, below))
     def _unmasked():
         step(full, full, None)
 
-    @pl.when(jnp.logical_and(visited, jnp.logical_not(below)))
+    @pl.when(jnp.logical_and(off_edge, jnp.logical_not(below)))
     def _diagonal():
         if block_q != block_k:
             mask = _causal_mask(i, j, block_q, block_k)
@@ -261,31 +308,41 @@ def _causal_walk(step, i, j, block_q, block_k, by_rows):
                      lambda s: _mask_corner(s, tri, 0))
 
 
-def _visited(i, j, block_q, block_k, causal):
+def _visited(i, j, block_q, block_k, causal, window=None, inside=None):
     """Whether a sweep computes anything of the (i, j) score block:
-    always, or under `causal` unless it lies above the diagonal.  A
+    always, or under `causal` unless it lies above the diagonal (or
+    left of the band, or past the matrix's end: `_block_classes`).  A
     traced value either way: what a kernel does to its refs it does
     under `pl.when`, where the interpreter does not hold it against the
     varying axes of a `shard_map`."""
-    return j * block_k <= (i + 1) * block_q - 1 if causal else j >= 0
+    if not causal:
+        return j >= 0 if inside is None else inside
+    if window is None and inside is None:
+        return j * block_k <= (i + 1) * block_q - 1
+    return _block_classes(i, j, block_q, block_k, window, inside)[0]
 
 
-def _walk(step, i, j, block_q, block_k, causal, by_rows):
+def _walk(step, i, j, block_q, block_k, causal, by_rows, window=None,
+          inside=None):
     """One (i, j) score block of a sweep: the causal walk, or ONE
     unmasked step over the whole block."""
     from jax.experimental import pallas as pl
 
     if causal:
-        return _causal_walk(step, i, j, block_q, block_k, by_rows)
-    pl.when(_visited(i, j, block_q, block_k, causal))(
+        return _causal_walk(step, i, j, block_q, block_k, by_rows, window,
+                            inside)
+    pl.when(_visited(i, j, block_q, block_k, causal, None, inside))(
         lambda: step(slice(None), slice(None), None))
 
 
-def _count_tiles(tq, tk, block_q, block_k, causal):
+def _count_tiles(tq, tk, block_q, block_k, causal, window=None):
     """Per traced kernel call and per head, in tiles of `_sub_tile`:
     how many the score matrix has (`flash_tiles_total`), how many the
     kernels compute (`flash_tiles_visited`) and how many of those they
-    mask (`flash_tiles_masked`), so a caller can see the walk engaged."""
+    mask (`flash_tiles_masked`), so a caller can see the walk engaged.
+    With a `window` a block wholly left of the band is not visited, and
+    every tile of a block the band's left edge crosses is computed
+    under the whole block's mask."""
     from .. import profiler as _prof
 
     cq, ck = _sub_tile(block_q, block_k)
@@ -296,9 +353,56 @@ def _count_tiles(tq, tk, block_q, block_k, causal):
     if causal:      # the same two tests as _causal_walk's, per tile
         visited = b * ck <= (a + 1) * cq - 1
         masked = visited & ((b + 1) * ck - 1 > a * cq)
+    if window is not None:      # ... and its two of the band, per BLOCK
+        i, j = a * cq // block_q, b * ck // block_k
+        left = (j + 1) * block_k - 1 <= i * block_q - window
+        edge = ~left & (j * block_k <= (i + 1) * block_q - 1 - window)
+        visited = ~left & (edge | visited)
+        masked = ~left & (edge | masked)
     _prof.inc_stat("flash_tiles_total", int(visited.size))
     _prof.inc_stat("flash_tiles_visited", int(visited.sum()))
     _prof.inc_stat("flash_tiles_masked", int(masked.sum()))
+
+
+def _band_steps(nq, nk, block_q, block_k, causal, window):
+    """(k blocks the longest sweep of a q block visits, q blocks the
+    longest sweep of a k block visits): the lengths of the sweeps'
+    inner grid axes in the band form, where a sweep starts at its first
+    visited block (`_first_k_block`, `_first_q_block`) and not at block
+    0, so a window layer at T = 8192 in blocks of 512 walks 5 steps a q
+    block and not 16."""
+    i, j = np.arange(nq), np.arange(nk)
+    j_hi = np.minimum(((i + 1) * block_q - 1) // block_k, nk - 1) \
+        if causal else np.full(nq, nk - 1)
+    j_lo = 0 if window is None else \
+        _first_k_block(i, block_q, block_k, window)
+    i_lo = np.minimum(_first_q_block(j, block_q, block_k, causal), nq - 1)
+    i_hi = np.minimum(_last_q_block(j, block_q, block_k, window, nq), nq - 1)
+    return int((j_hi - j_lo + 1).max()), int((i_hi - i_lo + 1).max())
+
+
+def _first_k_block(i, block_q, block_k, window):
+    """The first k block q block `i` sees under `window`: the one that
+    holds key `i * block_q - window + 1`.  `i`: a traced index, or
+    numpy's (`_band_steps`: static, inside a trace)."""
+    import jax.numpy as jnp
+
+    xp = np if isinstance(i, np.ndarray) else jnp
+    return xp.maximum(i * block_q - window + 1, 0) // block_k
+
+
+def _first_q_block(j, block_q, block_k, causal):
+    """The first q block that sees k block `j`: the diagonal's."""
+    return (j * block_k) // block_q if causal else 0
+
+
+def _last_q_block(j, block_q, block_k, window, nq):
+    """The last q block that sees k block `j` under `window` (the one
+    that holds query `(j + 1) * block_k + window - 2`), not held to the
+    `nq` blocks there are; without a window the last there is."""
+    if window is None:
+        return nq - 1
+    return ((j + 1) * block_k + window - 2) // block_q
 
 
 def _lane_block(b, heads):
@@ -310,17 +414,28 @@ def _lane_block(b, heads):
     return b // heads, b % heads
 
 
-def _k_index_map(causal, block_q, block_k, heads=1):
+def _k_index_map(causal, block_q, block_k, heads=1, group=1, window=None,
+                 nk=None):
     """Index map of a k or v tile in a k-innermost sweep (grid b, i,
     j): block j of lane block `b % heads`, but a causal step above the
     diagonal (skipped in the kernel) names the last visited block
-    again, so no tile is fetched for it."""
+    again, so no tile is fetched for it.  With `group` q heads to a kv
+    head the tile is lane block `(b % heads) // group` of k's own
+    narrower array; with a `window` step j stands on block
+    `_first_k_block(i) + j` (the band form: `nk` is the number of k
+    blocks there are)."""
     import jax.numpy as jnp
 
     def index(b, i, j):
         n, lane = _lane_block(b, heads)
-        if causal:
+        if window is not None:
+            j = jnp.minimum(
+                _first_k_block(i, block_q, block_k, window) + j,
+                jnp.minimum(((i + 1) * block_q - 1) // block_k, nk - 1))
+        elif causal:
             j = jnp.minimum(j, ((i + 1) * block_q - 1) // block_k)
+        if group > 1:
+            lane = lane // group
         return n, j, lane
 
     return index
@@ -342,6 +457,27 @@ def _q_index_map(causal, block_q, block_k, nq, heads=1):
     return index
 
 
+def _band_q_index_map(causal, block_q, block_k, nq, kv_heads, group, steps,
+                      window):
+    """A q-side tile in the BAND form of the dkv sweep: grid (b over
+    the kv lane blocks, j, x), where the inner axis runs over the
+    `group` q heads that share kv head b and, for each, over the
+    `steps` q blocks from the first that sees k block j
+    (`_first_q_block`): x = head * steps + block.  Steps past the last
+    q block that sees k block j (skipped in the kernel) name that block
+    again, so no tile is fetched for them."""
+    import jax.numpy as jnp
+
+    def index(b, j, x):
+        n, lane = _lane_block(b, kv_heads)
+        i = _first_q_block(j, block_q, block_k, causal) + x % steps
+        last = jnp.minimum(
+            _last_q_block(j, block_q, block_k, window, nq), nq - 1)
+        return n, jnp.minimum(i, last), lane * group + x // steps
+
+    return index
+
+
 def _outer_index_map(heads=1):
     """Index map of the tile a sweep holds fixed (grid b, outer, inner):
     block `outer` of lane block `b % heads`."""
@@ -352,11 +488,21 @@ def _outer_index_map(heads=1):
     return index
 
 
-def _row_index_map(qmap):
+def _row_index_map(qmap, heads=None):
     """Index map of the (1, rows, block_q) tile of per-row numbers
     (`_ROWS` sublanes of [N * heads, _ROWS, T]) that goes with the q
-    tile `qmap` names: the same q block of grid step b's own row."""
-    return lambda b, x, y: (b, 0, qmap(b, x, y)[1])
+    tile `qmap` names: the same q block of grid step b's own row, or
+    where the grid's b does not walk the q side's lane blocks (the band
+    form of the dkv sweep: give `heads`, the q side's) of the row of the
+    lane block `qmap` names."""
+    if heads is None:
+        return lambda b, x, y: (b, 0, qmap(b, x, y)[1])
+
+    def index(b, x, y):
+        n, i, lane = qmap(b, x, y)
+        return n * heads + lane, 0, i
+
+    return index
 
 
 # ---------------------------------------------------------------------------
@@ -473,8 +619,24 @@ def _keep_q_side(lse_ref, dlt_ref, slot, row_ref, o_ref, g_ref, lanes):
             jnp.sum(own, axis=1, keepdims=True), dlt_ref.shape[2:])
 
 
+def _k_sweep_step(block_q, block_k, window, nk):
+    """Where a k-innermost sweep (forward, dq) stands: (q block i, step
+    of the sweep, the k block j it is on, whether j is a block of the
+    matrix or None where it always is).  Without a `window` step and
+    block are one; with one the sweep starts at `_first_k_block(i)`."""
+    from jax.experimental import pallas as pl
+
+    i = pl.program_id(1)
+    step = pl.program_id(2)       # innermost, sequential
+    if window is None:
+        return i, step, step, None
+    j = _first_k_block(i, block_q, block_k, window) + step
+    return i, step, j, j < nk
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, sm_scale, causal,
-                  block_q, block_k, want_lse, per_block):
+                  block_q, block_k, want_lse, per_block, window=None,
+                  nk=None):
     rest = list(rest)
     lse_ref = rest.pop(0) if want_lse else None
     acc_ref, m_ref, l_ref = rest[:3]
@@ -482,11 +644,10 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, sm_scale, causal,
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
-    i = pl.program_id(1)          # q block
-    j = pl.program_id(2)          # k block (innermost, sequential)
+    i, at, j, inside = _k_sweep_step(block_q, block_k, window, nk)
     lanes = _head_lanes(per_block, q_ref.shape[2])
 
-    @pl.when(j == 0)
+    @pl.when(at == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
@@ -518,9 +679,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, sm_scale, causal,
             m_ref[r, rows] = jnp.broadcast_to(m_new, wide)
             l_ref[r, rows] = jnp.broadcast_to(l_new, wide)
 
-    _walk(_step, i, j, block_q, block_k, causal, by_rows=True)
+    _walk(_step, i, j, block_q, block_k, causal, True, window, inside)
 
-    @pl.when(j == pl.num_programs(2) - 1)
+    @pl.when(at == pl.num_programs(2) - 1)
     def _finish():
         out, lses = None, []
         for r, lane in enumerate(lanes):
@@ -535,10 +696,14 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, sm_scale, causal,
 
 
 def _flash_forward_pallas(q, k, v, sm_scale, causal, block_q, block_k,
-                          want_lse, heads=1, per_block=1):
-    """Runs the kernel on q, k, v of [N, T, heads * w], a lane block of
-    width w holding `per_block` heads side by side; returns (out in the
-    same layout, log-sums (N * heads * per_block, T) or None).  The LSE
+                          want_lse, heads=1, per_block=1, group=1,
+                          window=None):
+    """Runs the kernel on q of [N, T, heads * w], a lane block of width
+    w holding `per_block` heads side by side, and k, v of [N, T, heads
+    // group * w] (`group` q heads read one kv head, in place: the index
+    map picks its lane block); returns (out in q's layout, log-sums (N *
+    heads * per_block, T) or None).  With a `window` the k axis of the
+    grid is the band's length (`_band_steps`).  The LSE
     output is built only when requested — pallas_call is an opaque
     custom call, so an unused output would still be written to HBM."""
     import jax.numpy as jnp
@@ -548,13 +713,18 @@ def _flash_forward_pallas(q, k, v, sm_scale, causal, block_q, block_k,
     n, tq, width = q.shape
     w = width // heads
     tk = k.shape[1]
-    grid = (n * heads, pl.cdiv(tq, block_q), pl.cdiv(tk, block_k))
-    kmap = _k_index_map(causal, block_q, block_k, heads)
+    nq, nk = pl.cdiv(tq, block_q), pl.cdiv(tk, block_k)
+    band = {}
+    if window is not None:
+        band = dict(window=window, nk=nk)
+        nk = _band_steps(nq, nk, block_q, block_k, causal, window)[0]
+    grid = (n * heads, nq, nk)
+    kmap = _k_index_map(causal, block_q, block_k, heads, group, **band)
     qmap = _outer_index_map(heads)
     kernel = functools.partial(_flash_kernel, sm_scale=sm_scale,
                                causal=causal, block_q=block_q,
                                block_k=block_k, want_lse=want_lse,
-                               per_block=per_block)
+                               per_block=per_block, **band)
     out_shape = [_sds((n, tq, width), q.dtype, q, k, v)]
     out_specs = [pl.BlockSpec((1, block_q, w), qmap)]
     if want_lse:
@@ -607,7 +777,8 @@ def _bwd_p_ds(q, k, v, g, lse, dlt, mask, sm_scale):
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, o_ref, row_ref,
                          dq_ref, acc_ref, lse_ref, dlt_ref, *kept,
-                         sm_scale, causal, block_q, block_k, per_block):
+                         sm_scale, causal, block_q, block_k, per_block,
+                         window=None, nk=None):
     """dq sweep: grid (n * heads, nq, nk), k innermost; accumulates
     ds·K into VMEM scratch and writes the q block's dq once.  The q
     side is fixed over the sweep: its log-sums are turned to columns,
@@ -616,11 +787,10 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, o_ref, row_ref,
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
-    i = pl.program_id(1)
-    j = pl.program_id(2)
+    i, at, j, inside = _k_sweep_step(block_q, block_k, window, nk)
     lanes = _head_lanes(per_block, q_ref.shape[2])
 
-    @pl.when(j == 0)
+    @pl.when(at == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         _keep_q_side(lse_ref, dlt_ref, 0, row_ref, o_ref, g_ref, lanes)
@@ -640,9 +810,9 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, o_ref, row_ref,
             acc_ref[rows] = _own_lanes(
                 lane, acc + _dot_f32(ds, k, ((1,), (0,))), acc)
 
-    _walk(_step, i, j, block_q, block_k, causal, by_rows=True)
+    _walk(_step, i, j, block_q, block_k, causal, True, window, inside)
 
-    @pl.when(j == pl.num_programs(2) - 1)
+    @pl.when(at == pl.num_programs(2) - 1)
     def _finish():
         dq_ref[0] = acc_ref[:].astype(dq_ref.dtype)
 
@@ -650,22 +820,34 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, o_ref, row_ref,
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, o_ref, row_ref,
                           dk_ref, dv_ref, dk_acc, dv_acc, lse_ref,
                           dlt_ref, *kept, sm_scale, causal, block_q,
-                          block_k, per_block):
+                          block_k, per_block, window=None, band=None):
     """dk/dv sweep: grid (n * heads, nk, nq), q innermost.  The k side
     is fixed over the sweep (with several heads to a lane block k and v
     are cut to each head's lanes once per k block).  What the sweep
     needs of a q block beside its tiles (`_keep_q_side`) is taken while
     the first k block is swept, which every q block sees, and kept for
     the later ones in a slot per q block; where T is too long for that
-    (`lse_ref` has one slot) it is taken every step."""
+    (`lse_ref` has one slot) it is taken every step.
+
+    In the BAND form (`band` = (steps, nq): grouped kv heads, or a
+    `window`) the grid is (n * kv heads, nk, group * steps): the inner
+    axis runs over the q heads that share this kv head and, for each,
+    over the `steps` q blocks from the diagonal on (`_band_q_index_map`),
+    and dk, dv are summed over all of them in VMEM: no dk or dv a q head
+    is ever written.  The q side is taken every step there."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
     j = pl.program_id(1)
-    i = pl.program_id(2)
+    at = pl.program_id(2)
+    if band is None:
+        i, inside = at, None
+    else:
+        i = _first_q_block(j, block_q, block_k, causal) + at % band[0]
+        inside = i < band[1]
     lanes = _head_lanes(per_block, q_ref.shape[2])
 
-    @pl.when(i == 0)
+    @pl.when(at == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
@@ -677,7 +859,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, o_ref, row_ref,
     slot = i if slots > 1 else 0
 
     @pl.when(j == 0 if slots > 1
-             else _visited(i, j, block_q, block_k, causal))
+             else _visited(i, j, block_q, block_k, causal, window, inside))
     def _q_side():
         _keep_q_side(lse_ref, dlt_ref, slot, row_ref, o_ref, g_ref, lanes)
 
@@ -697,24 +879,29 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, o_ref, row_ref,
             dk_acc[cols] = _own_lanes(
                 lane, dk + _dot_f32(ds, q, ((0,), (0,))), dk)
 
-    _walk(_step, i, j, block_q, block_k, causal, by_rows=False)
+    _walk(_step, i, j, block_q, block_k, causal, False, window, inside)
 
-    @pl.when(i == pl.num_programs(2) - 1)
+    @pl.when(at == pl.num_programs(2) - 1)
     def _finish():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
 def _flash_backward_pallas(q, k, v, g, out, lse, sm_scale, causal,
-                           block_q, block_k, heads=1, per_block=1):
+                           block_q, block_k, heads=1, per_block=1, group=1,
+                           window=None):
     """Pallas backward: two kernel launches (dq; dk/dv) over the saved
     output and LSE — the TPU-kernel analog of the jnp blocked sweeps
-    below.  q, k, v, the cotangent g, `out` and the results: [N, T,
-    heads * w], a lane block of width w holding `per_block` heads;
-    `lse`: (N * heads * per_block, T).  `delta = rowsum(out * g)` is
-    taken inside both sweeps from the tiles they hold (`_keep_q_side`):
-    as an XLA reduction it cost two whole-array transposing copies a
-    call, whichever layout it was asked in (PERF.md, PR 35)."""
+    below.  q, the cotangent g, `out` and dq: [N, T, heads * w], a lane
+    block of width w holding `per_block` heads; k, v, dk and dv: [N, T,
+    heads // group * w] (`group` q heads to a kv head, read and written
+    in place: dk and dv are summed over the group's q heads inside the
+    dkv sweep); `lse`: (N * heads * per_block, T).  `delta = rowsum(out
+    * g)` is taken inside both sweeps from the tiles they hold
+    (`_keep_q_side`): as an XLA reduction it cost two whole-array
+    transposing copies a call, whichever layout it was asked in
+    (PERF.md, PR 35).  With a `window`, and for grouped kv heads, the
+    sweeps' inner grid axes take the band form (`_band_steps`)."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -729,11 +916,18 @@ def _flash_backward_pallas(q, k, v, g, out, lse, sm_scale, causal,
     # padded 128-fold on the chip)
     rows = jnp.pad(lse.reshape(n * heads, per_block, tq),
                    ((0, 0), (0, _ROWS - per_block), (0, 0)))
+    band = {}
+    k_steps, q_steps = nk, nq
+    if window is not None:
+        band = dict(window=window, nk=nk)
+        k_steps, q_steps = _band_steps(nq, nk, block_q, block_k, causal,
+                                       window)
 
     qmap = _outer_index_map(heads)
     qspec = pl.BlockSpec((1, block_q, w), qmap)
     kspec = pl.BlockSpec((1, block_k, w),
-                         _k_index_map(causal, block_q, block_k, heads))
+                         _k_index_map(causal, block_q, block_k, heads,
+                                      group, **band))
     rspec = pl.BlockSpec((1, _ROWS, block_q), _row_index_map(qmap))
 
     def q_side(slots):      # `_keep_q_side`'s scratch: log-sums, delta
@@ -748,9 +942,9 @@ def _flash_backward_pallas(q, k, v, g, out, lse, sm_scale, causal,
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, sm_scale=sm_scale,
                           causal=causal, block_q=block_q,
-                          block_k=block_k, per_block=per_block),
+                          block_k=block_k, per_block=per_block, **band),
         out_shape=_sds((n, tq, width), q.dtype, q, k, v, g),
-        grid=(n * heads, nq, nk),
+        grid=(n * heads, nq, k_steps),
         in_specs=[qspec, kspec, kspec, qspec, qspec, rspec],
         out_specs=qspec,
         scratch_shapes=[pltpu.VMEM((block_q, w), jnp.float32)] + q_side(1)
@@ -759,20 +953,36 @@ def _flash_backward_pallas(q, k, v, g, out, lse, sm_scale, causal,
         name="mx_flash_dq",
     )(q, k, v, g, out, rows)
 
-    # dkv grid: (n * heads, nk, nq) — q innermost; index maps swap (i, j)
-    qmap2 = _q_index_map(causal, block_q, block_k, nq, heads)
+    kv_heads = heads // group
+    if group == 1 and window is None:
+        # dkv grid: (n * heads, nk, nq) — q innermost; index maps swap
+        # (i, j)
+        qmap2 = _q_index_map(causal, block_q, block_k, nq, heads)
+        rmap2 = _row_index_map(qmap2)
+        # a slot per q block where the whole sequence's columns fit
+        slots = nq if (1 + per_block) * tq * _LANES * 4 <= _Q_SIDE_BYTES \
+            else 1
+        form = {}
+    else:
+        # the band form: (n * kv heads, nk, group * q_steps); the q side
+        # is taken every step (a slot per q head and block is group * T
+        # KiB of VMEM: 64 MiB at 8 heads a group and T = 8192)
+        qmap2 = _band_q_index_map(causal, block_q, block_k, nq, kv_heads,
+                                  group, q_steps, window)
+        rmap2 = _row_index_map(qmap2, heads)
+        slots = 1
+        form = dict(window=window, band=(q_steps, nq))
+        q_steps *= group
     qspec2 = pl.BlockSpec((1, block_q, w), qmap2)
-    kspec2 = pl.BlockSpec((1, block_k, w), _outer_index_map(heads))
-    rspec2 = pl.BlockSpec((1, _ROWS, block_q), _row_index_map(qmap2))
-    # a slot per q block where the whole sequence's columns fit
-    slots = nq if (1 + per_block) * tq * _LANES * 4 <= _Q_SIDE_BYTES else 1
+    kspec2 = pl.BlockSpec((1, block_k, w), _outer_index_map(kv_heads))
+    rspec2 = pl.BlockSpec((1, _ROWS, block_q), rmap2)
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, sm_scale=sm_scale,
                           causal=causal, block_q=block_q,
-                          block_k=block_k, per_block=per_block),
-        out_shape=(_sds((n, tk, width), k.dtype, q, k, v, g),
-                   _sds((n, tk, width), v.dtype, q, k, v, g)),
-        grid=(n * heads, nk, nq),
+                          block_k=block_k, per_block=per_block, **form),
+        out_shape=(_sds(k.shape, k.dtype, q, k, v, g),
+                   _sds(v.shape, v.dtype, q, k, v, g)),
+        grid=(n * kv_heads, nk, q_steps),
         in_specs=[qspec2, kspec2, kspec2, qspec2, qspec2, rspec2],
         out_specs=(kspec2, kspec2),
         scratch_shapes=[pltpu.VMEM((block_k, w), jnp.float32),
@@ -784,8 +994,9 @@ def _flash_backward_pallas(q, k, v, g, out, lse, sm_scale, causal,
     return dq, dk, dv
 
 
-def _reference_attention_lse(q, k, v, sm_scale, causal):
-    """Fused jnp reference; returns (out, per-row log-sum-exp)."""
+def _reference_attention_lse(q, k, v, sm_scale, causal, window=None):
+    """Fused jnp reference; returns (out, per-row log-sum-exp).  With a
+    `window` a query sees the keys no more than `window` - 1 behind it."""
     import jax.numpy as jnp
 
     # native-dtype operands + f32 accumulation (MXU single-pass for
@@ -795,6 +1006,8 @@ def _reference_attention_lse(q, k, v, sm_scale, causal):
     if causal:
         tq, tk = s.shape[-2:]
         mask = jnp.arange(tq)[:, None] >= jnp.arange(tk)[None, :]
+        if window is not None:
+            mask &= jnp.arange(tk)[None, :] > jnp.arange(tq)[:, None] - window
         s = jnp.where(mask, s, _NEG_INF)
     lse = jax.scipy.special.logsumexp(s, axis=-1)
     p = jnp.exp(s - lse[..., None])
@@ -804,9 +1017,9 @@ def _reference_attention_lse(q, k, v, sm_scale, causal):
     return out, lse
 
 
-def _reference_attention(q, k, v, sm_scale, causal):
+def _reference_attention(q, k, v, sm_scale, causal, window=None):
     """Fused jnp reference (also the CPU/GPU fallback path)."""
-    return _reference_attention_lse(q, k, v, sm_scale, causal)[0]
+    return _reference_attention_lse(q, k, v, sm_scale, causal, window)[0]
 
 
 # The names the differentiated forward gives its two results.  A
@@ -848,24 +1061,57 @@ def _lane_plan(h, d):
     return 0
 
 
-def _kernel_layout(xs, launches):
-    """([B, T, H, D] arrays in the kernels' [N, T, heads * w], heads,
-    heads to a lane block): a free reshape in place, `_split_heads`
-    where `_lane_plan` finds no lane block of whole heads.  Counts the
-    `launches` that will read them (`flash_calls_in_place` /
-    `flash_calls_split`) and the heads to a block
-    (`flash_heads_per_block`, a watermark)."""
+def _expand_kv(x, group):
+    """k or v [B, T, Hkv, D] with every head `group` times, at q's head
+    count: what the jnp paths compute on, and the kernels where they
+    cannot read the kv heads in place."""
+    import jax.numpy as jnp
+
+    return x if group == 1 else jnp.repeat(x, group, axis=2)
+
+
+def _sum_group(x, group):
+    """dk or dv at q's head count [B, T, H, D] summed over the q heads
+    of each kv head: [B, T, H // group, D]."""
+    if group == 1:
+        return x
+    b, t, h, d = x.shape
+    return x.reshape(b, t, h // group, group, d).sum(axis=3)
+
+
+def _kernel_layout(q_side, kv_side, launches, window=None):
+    """([B, T, H, D] arrays of q's side and [B, T, Hkv, D] arrays of
+    k's in the kernels' [N, T, lane blocks * w], q's lane blocks, heads
+    to a lane block, q heads to a kv head as the kernels will see it): a
+    free reshape in place, `_split_heads` where `_lane_plan` finds no
+    lane block of whole heads.  Fewer kv heads than q heads are read in
+    place where a head is a lane block of its own; elsewhere k and v go
+    in expanded to q's head count (`_expand_kv`) and the group comes
+    back as 1.  Counts the `launches` that will read them
+    (`flash_calls_in_place` / `flash_calls_split`, and
+    `flash_kv_expanded` those fed an expanded k / v), the heads to a
+    block (`flash_heads_per_block`) and, as watermarks too, the q heads
+    to a kv head (`flash_kv_group`) and the `window` (`flash_window`; 0:
+    none)."""
     from .. import profiler as _prof
 
-    b, t, h, d = xs[0].shape
+    b, t, h, d = q_side[0].shape
+    group = h // kv_side[0].shape[2]
     per_block = _lane_plan(h, d)
     _prof.inc_stat("flash_calls_in_place" if per_block
                    else "flash_calls_split", launches)
     _prof.max_stat("flash_heads_per_block", max(per_block, 1))
+    _prof.max_stat("flash_kv_group", group)
+    _prof.max_stat("flash_window", window or 0)
+    if group > 1 and per_block != 1:
+        _prof.inc_stat("flash_kv_expanded", launches)
+        kv_side = [_expand_kv(x, group) for x in kv_side]
+        group = 1
+    xs = list(q_side) + list(kv_side)
     if per_block:
-        return [x.reshape(x.shape[0], x.shape[1], h * d) for x in xs], \
-            h // per_block, per_block
-    return [_split_heads(x) for x in xs], 1, 1
+        return [x.reshape(x.shape[0], x.shape[1], -1) for x in xs], \
+            h // per_block, per_block, group
+    return [_split_heads(x) for x in xs], 1, 1, group
 
 
 def _from_kernel_layout(x, shape):
@@ -887,39 +1133,44 @@ def _takes_kernel(tq, tk, block_q, block_k, d, ragged_q):
         and _tiles(block_q, block_k, d)
 
 
-def _flash_merged(q, k, v, sm_scale, causal, block_q, block_k, want_lse):
-    """The forward on [B, T, H, D].  Returns ([B, T, H * D], log-sums
-    (B*H, T) or None).  The LSE is produced only for the differentiated
-    path: the pallas kernel writes it as a real second output (not
-    prunable), while the jnp reference's unused copy is ordinary dead
-    code."""
+def _flash_merged(q, k, v, sm_scale, causal, block_q, block_k, window,
+                  want_lse):
+    """The forward on q [B, T, H, D] and k, v [B, T, Hkv, D].  Returns
+    ([B, T, H * D], log-sums (B*H, T) or None).  The LSE is produced
+    only for the differentiated path: the pallas kernel writes it as a
+    real second output (not prunable), while the jnp reference's unused
+    copy is ordinary dead code."""
     b, tq, h, d = q.shape
+    group = h // k.shape[2]
     if not _takes_kernel(tq, k.shape[1], block_q, block_k, d, True):
         _count_path("reference")
         out, lse = _reference_attention_lse(
-            _split_heads(q), _split_heads(k), _split_heads(v), sm_scale,
-            causal)
+            _split_heads(q), _split_heads(_expand_kv(k, group)),
+            _split_heads(_expand_kv(v, group)), sm_scale, causal, window)
         return _merge_heads(out, b).reshape(b, tq, h * d), lse
     _count_path("pallas")
     _count_fwd(want_lse)
-    _count_tiles(tq, k.shape[1], block_q, block_k, causal)
-    (q, k, v), heads, per_block = _kernel_layout([q, k, v], 1)
+    _count_tiles(tq, k.shape[1], block_q, block_k, causal, window)
+    (q, k, v), heads, per_block, group = _kernel_layout([q], [k, v], 1,
+                                                        window)
     pq = (-tq) % block_q
     if pq:
         import jax.numpy as jnp
 
         q = jnp.pad(q, ((0, 0), (0, pq), (0, 0)))
     out, lse = _flash_forward_pallas(q, k, v, sm_scale, causal, block_q,
-                                     block_k, want_lse, heads, per_block)
+                                     block_k, want_lse, heads, per_block,
+                                     group, window)
     if pq:
         out, lse = out[:, :tq], (lse[:, :tq] if want_lse else None)
     return _from_kernel_layout(out, (b, tq, h, d)).reshape(
         b, tq, h * d), lse
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, sm_scale, causal, block_q, block_k):
-    """q, k, v: [B, T, H, D]; returns [B, T, H * D].  The kernels read
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash(q, k, v, sm_scale, causal, block_q, block_k, window):
+    """q: [B, T, H, D]; k, v: [B, T, Hkv, D], Hkv dividing H; returns [B,
+    T, H * D].  The kernels read
     q, k, v (and in the backward pass the cotangent) as [B, T, H * D], a
     free reshape, and pick a head, or the heads that share a 128-lane
     vreg, by the lane block of their index maps; they write the output
@@ -928,68 +1179,79 @@ def _flash(q, k, v, sm_scale, causal, block_q, block_k):
     `custom_vjp` keeps for the backward pass is q, k, v as given, the
     output as returned and the log-sums, (B*H, T) float32."""
     return _flash_merged(q, k, v, sm_scale, causal, block_q, block_k,
-                         want_lse=False)[0]
+                         window, want_lse=False)[0]
 
 
-def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k):
+def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, window):
     from jax.ad_checkpoint import checkpoint_name
 
     out, lse = _flash_merged(q, k, v, sm_scale, causal, block_q, block_k,
-                             want_lse=True)
+                             window, want_lse=True)
     out = checkpoint_name(out, FLASH_OUT)
     lse = checkpoint_name(lse, FLASH_LSE)
     return out, (q, k, v, out, lse)
 
 
-def _block_mask(causal, q0, k0, bq, bk):
+def _block_mask(causal, q0, k0, bq, bk, window=None):
     import jax.numpy as jnp
 
     if not causal:
         return None
     q_idx = q0 + jnp.arange(bq)[:, None]
     k_idx = k0 + jnp.arange(bk)[None, :]
-    return q_idx >= k_idx
+    if window is None:
+        return q_idx >= k_idx
+    return (q_idx >= k_idx) & (k_idx > q_idx - window)
 
 
-def _flash_bwd(sm_scale, causal, block_q, block_k, res, g):
+def _flash_bwd(sm_scale, causal, block_q, block_k, window, res, g):
     """The backward rule, on the activations' layout: the two sweeps
     read q, k, v, the cotangent and the saved output and write dq, dk,
-    dv in the layout the forward kernel read (`_kernel_layout`)."""
+    dv in the layout the forward kernel read (`_kernel_layout`); dk and
+    dv come out at k's own head count."""
     import jax.numpy as jnp
 
     q, k, v, out, lse = res
     b, tq, h, d = q.shape
+    group = h // k.shape[2]
     g = g.reshape(q.shape)
     out = out.reshape(q.shape)
     if not _takes_kernel(tq, k.shape[1], block_q, block_k, d, False):
         _count_path("reference")
         delta = (out.astype(jnp.float32) * g.astype(jnp.float32)) \
             .sum(axis=-1).transpose(0, 2, 1).reshape(b * h, tq)
-        grads = _flash_bwd_sweeps(
-            _split_heads(q), _split_heads(k), _split_heads(v),
-            _split_heads(g), delta, lse, sm_scale, causal, block_q,
-            block_k)
-        return tuple(_merge_heads(x, b) for x in grads)
+        dq, dk, dv = _flash_bwd_sweeps(
+            _split_heads(q), _split_heads(_expand_kv(k, group)),
+            _split_heads(_expand_kv(v, group)), _split_heads(g), delta,
+            lse, sm_scale, causal, block_q, block_k, window)
+        return (_merge_heads(dq, b),) + tuple(
+            _sum_group(_merge_heads(x, b), group) for x in (dk, dv))
     # kernel path (same math as the jnp sweeps, on the MXU)
     _count_path("pallas")
-    _count_tiles(tq, k.shape[1], block_q, block_k, causal)
-    (q3, k3, v3, g3, o3), heads, per_block = _kernel_layout(
-        [q, k, v, g, out], 2)
-    grads = _flash_backward_pallas(q3, k3, v3, g3, o3, lse, sm_scale,
-                                   causal, block_q, block_k, heads,
-                                   per_block)
-    return tuple(_from_kernel_layout(x, a.shape)
-                 for x, a in zip(grads, (q, k, v)))
+    _count_tiles(tq, k.shape[1], block_q, block_k, causal, window)
+    (q3, g3, o3, k3, v3), heads, per_block, read_as = _kernel_layout(
+        [q, g, out], [k, v], 2, window)
+    dq, dk, dv = _flash_backward_pallas(
+        q3, k3, v3, g3, o3, lse, sm_scale, causal, block_q, block_k,
+        heads, per_block, read_as, window)
+    if read_as == group:
+        return tuple(_from_kernel_layout(x, a.shape)
+                     for x, a in zip((dq, dk, dv), (q, k, v)))
+    # k and v went in expanded: dk, dv come back a q head, XLA sums
+    return (_from_kernel_layout(dq, q.shape),) + tuple(
+        _sum_group(_from_kernel_layout(x, q.shape), group)
+        for x in (dk, dv))
 
 
 def _flash_bwd_sweeps(q, k, v, g, delta, lse_saved, sm_scale, causal,
-                      block_q, block_k):
+                      block_q, block_k, window=None):
     """Blocked recompute backward (flash attention paper §3.1) in jnp,
     where the kernels do not serve: scores are rebuilt block by block
     against the LSE saved by the forward, so backward memory stays
     O(T·d + block²) — the T×T matrix is never materialized.  Two sweeps
     (dq; dk/dv), with fully-masked causal blocks skipped via loop
-    bounds.  All of (B*H, T, D); `delta` and `lse_saved` (B*H, T)."""
+    bounds (those left of a `window`'s band too).  All of (B*H, T, D);
+    `delta` and `lse_saved` (B*H, T)."""
     import jax.numpy as jnp
     from jax import lax
 
@@ -1016,7 +1278,7 @@ def _flash_bwd_sweeps(q, k, v, g, delta, lse_saved, sm_scale, causal,
     def scores(qi, i, j):
         kj = lax.dynamic_slice_in_dim(k32, j * bk, bk, 1)
         s = jnp.einsum("bqd,bkd->bqk", qi, kj) * sm_scale
-        mask = _block_mask(causal, i * bq, j * bk, bq, bk)
+        mask = _block_mask(causal, i * bq, j * bk, bq, bk, window)
         kv = lax.dynamic_slice_in_dim(k_valid, j * bk, bk, 0)
         s = jnp.where(kv[None, None, :], s, _NEG_INF)
         if mask is not None:
@@ -1047,7 +1309,9 @@ def _flash_bwd_sweeps(q, k, v, g, delta, lse_saved, sm_scale, causal,
         # parallel.ring_attention._match_vma)
         acc0 = _vma_like(jnp.zeros((B, bq, D), jnp.float32),
                          q32, k32, v32, g32)
-        return _, lax.fori_loop(0, nk_i, body, acc0)
+        first = 0 if window is None else jnp.minimum(
+            _first_k_block(i, bq, bk, window), nk_i)
+        return _, lax.fori_loop(first, nk_i, body, acc0)
 
     _, dq_blocks = lax.scan(dq_for_block, None, jnp.arange(nq))
     dq = dq_blocks.transpose(1, 0, 2, 3).reshape(B, nq * bq, D)[:, :Tq]
@@ -1074,7 +1338,9 @@ def _flash_bwd_sweeps(q, k, v, g, delta, lse_saved, sm_scale, causal,
         i0 = jnp.minimum((j * bk) // bq, nq) if causal else 0
         z = _vma_like(jnp.zeros((B, bk, D), jnp.float32),
                       q32, k32, v32, g32)
-        return _, lax.fori_loop(i0, nq, body, (z, z))
+        # ... and with a window those past the band's left edge
+        i1 = jnp.clip(_last_q_block(j, bq, bk, window, nq) + 1, i0, nq)
+        return _, lax.fori_loop(i0, i1, body, (z, z))
 
     _, (dk_blocks, dv_blocks) = lax.scan(dkv_for_block, None,
                                          jnp.arange(nk))
@@ -1123,11 +1389,20 @@ def flash_attention(q, k, v, sm_scale=None, causal=False, block_q=512,
 
 
 def flash_attention_bthd(q, k, v, sm_scale=None, causal=False,
-                         block_q=512, block_k=512):
-    """`flash_attention` on the activations' own layout: q, k, v of
-    [batch, seq, heads, head_dim] (a reshape of the projections'
-    [batch, seq, heads * head_dim]); returns [batch, seq, heads *
-    head_dim], what the out-projection reads.  The three kernels read
+                         block_q=512, block_k=512, window=None):
+    """`flash_attention` on the activations' own layout: q of [batch,
+    seq, heads, head_dim] (a reshape of the projections' [batch, seq,
+    heads * head_dim]), k and v the same or of FEWER heads (grouped kv
+    heads: their count divides q's, read from the shapes; q head h
+    meets kv head h // group, read in place by the lane block of the
+    index maps, and dk, dv are summed over a group's q heads inside the
+    dkv sweep); returns [batch, seq, heads * head_dim], what the
+    out-projection reads.  A static `window` (with `causal`) lets a
+    query see the keys no more than `window` - 1 behind it: a block
+    wholly left of that band is skipped as a block above the diagonal
+    is, the grid's inner axis is the band's length and not the
+    sequence's, and a `window` that reaches every key traces the causal
+    program.  The three kernels read
     and write that layout in place (`_lane_plan`: a head that is whole
     128-lane vregs wide is a lane block of its own, narrower heads that
     fill a vreg share one; any other shape takes a split copy), inside
@@ -1149,8 +1424,18 @@ def flash_attention_bthd(q, k, v, sm_scale=None, causal=False,
             b //= 2
         return b
 
+    if q.shape[2] % k.shape[2] or k.shape != v.shape:
+        raise MXNetError("flash_attention_bthd: k and v need one shape "
+                         "and a head count that divides q's (%r, %r, %r)"
+                         % (q.shape, k.shape, v.shape))
+    if window is not None:
+        if not causal or int(window) < 1:
+            raise MXNetError("flash_attention_bthd: a window is a causal "
+                             "mask's, and at least 1 (got %r)" % (window,))
+        window = None if window >= k.shape[1] else int(window)
     return _flash(q, k, v, float(sm_scale), bool(causal),
-                  _fit(block_q, q.shape[1]), _fit(block_k, k.shape[1]))
+                  _fit(block_q, q.shape[1]), _fit(block_k, k.shape[1]),
+                  window)
 
 
 @register("_contrib_flash_attention")

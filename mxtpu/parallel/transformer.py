@@ -63,7 +63,8 @@ class TransformerConfig:
     # and ONE product per attention block, the cheapest to rebuild
     # per byte, which pays for the kernel's output: `o @ wo` in
     # `_attention`, `c_q @ wq_b` in `_mla` (`c_kv @ wkv_b` where q has
-    # no bottleneck).  So the backward pass
+    # no bottleneck), `x @ w_gate` in `_gqa` (q's where it has no
+    # gate).  So the backward pass
     # runs no second forward kernel.  Analog of the reference's
     # MXNET_BACKWARD_DO_MIRROR (docs/faq/env_var.md) which this
     # repo's symbolic executor exposes as MXTPU_BACKWARD_DO_MIRROR;
@@ -74,7 +75,12 @@ class TransformerConfig:
     # before they existed.
     norm_eps: float = 1e-6
     attention: str = "mha"     # "mha": q / k / v of width d_model //
-    # n_heads, a learned position table.  "mla": latent attention —
+    # n_heads, a learned position table.  "gqa": n_heads q heads on
+    # n_kv_heads kv heads of head_dim (q head h meets kv head h //
+    # (n_heads / n_kv_heads)), rotary positions over the whole head
+    # (rope_theta, rotate-half), no position table; `qk_norm`,
+    # `out_gate`, `window` / `full_period` are its.  "mla": latent
+    # attention —
     # low-rank q with a norm between (q_lora_rank), one compressed kv
     # (kv_lora_rank, normed) plus one rotary key shared by the heads,
     # decompressed to per-head k_nope (qk_nope_dim) and v (v_head_dim);
@@ -142,9 +148,31 @@ class TransformerConfig:
     # cumulative log-decay is re-based: a span's rebase * -floor nats
     # (80) are split about its middle, so `exp` sees +-40 at most
     qk_norm: bool = False      # mla: q_nope and k_nope RMS-normed per
-    # head, one [qk_nope_dim] scale each
+    # head, one [qk_nope_dim] scale each; gqa: q and k, one [head_dim]
+    # scale each
     head_gate: bool = False    # mla: a sigmoid gate per head on the
     # attention's output, from x (KDA always has one)
+
+    # ---- grouped kv heads, and window and full layers mixed by a
+    # period ("gqa"): the layer of published index i attends over the
+    # whole causal prefix where (i + 1) % full_period == 0 and over the
+    # last `window` keys (the query's own among them) otherwise.  A full
+    # layer's leaves are "full.<leaf>": each run of layers of one kind is
+    # a segment, as with kda_period
+    n_kv_heads: int = 0        # 0 = n_heads
+    head_dim: int = 0          # a q / k / v head's width; 0 = d_model //
+    # n_heads
+    window: int = 0            # 0: every layer attends over the whole
+    # prefix
+    full_period: int = 0       # 0 with a window: every layer slides
+    rope_full: bool = True     # False: the full layers carry NO
+    # positions (the window layers' rotary ones give the order)
+    out_gate: bool = False     # gqa: the merged attention output times
+    # sigmoid(x W_gate), elementwise, before the out product
+    post_norms: bool = False   # an RMSNorm AFTER each sub-block too
+    # ("ln1_post", "ln2_post"), on what it adds to the residual: four
+    # norms a layer
+    embed_scale: float = 1.0   # the embedding's rows times this
 
     def __post_init__(self):
         from ..executor import _REMAT_POLICIES
@@ -154,8 +182,27 @@ class TransformerConfig:
                 "TransformerConfig.remat must be 'none' or one of %s "
                 "(got %r)" % (sorted(_REMAT_POLICIES), self.remat))
         bad = None
-        if self.attention not in ("mha", "mla"):
-            bad = "attention must be 'mha' or 'mla'"
+        if self.attention not in ("mha", "mla", "gqa"):
+            bad = "attention must be 'mha', 'mla' or 'gqa'"
+        elif self.attention != "gqa" and (
+                self.n_kv_heads or self.head_dim or self.window
+                or self.full_period or self.out_gate or self.post_norms
+                or not self.rope_full):
+            bad = ("n_kv_heads, head_dim, window, full_period, rope_full, "
+                   "out_gate and post_norms are attention='gqa's")
+        elif self.attention == "gqa" and (
+                self.n_kv_heads < 0
+                or self.n_heads % (self.n_kv_heads or self.n_heads)
+                or (self.head_dim or self.d_model // self.n_heads) % 2
+                or self.window < 0 or self.full_period < 0
+                or (self.full_period and not self.window)
+                or self.full_period == 1
+                or (not self.rope_full and not self.full_period)
+                or self.kda_period):
+            bad = ("attention='gqa' needs n_kv_heads dividing n_heads, an "
+                   "even head_dim, full_period >= 2 only with a window, "
+                   "rope_full=False only with a full_period, and no "
+                   "kda_period")
         elif self.attention == "mla" and (
                 min(self.kv_lora_rank, self.qk_nope_dim,
                     self.v_head_dim) < 1 or self.q_lora_rank < 0
@@ -163,8 +210,10 @@ class TransformerConfig:
             bad = ("attention='mla' needs kv_lora_rank, qk_nope_dim, "
                    "v_head_dim and an even qk_rope_dim (q_lora_rank 0 "
                    "is q from one matrix)")
-        elif self.attention != "mla" and (self.qk_norm or self.head_gate):
-            bad = "qk_norm and head_gate are latent attention's"
+        elif self.head_gate and self.attention != "mla" or (
+                self.qk_norm and self.attention == "mha"):
+            bad = ("head_gate is latent attention's, qk_norm latent "
+                   "attention's and 'gqa's")
         elif self.kda_period and (
                 self.kda_period < 2 or self.kda_chunk < 1
                 or self.kda_rebase < 1 or self.kda_chunk % self.kda_rebase
@@ -220,9 +269,17 @@ class TransformerConfig:
 
 def _kind_parts(cfg: TransformerConfig, kind: str) -> Tuple[str, str]:
     """(mixer, feed-forward) of a segment's kind (see `_segments`): the
-    mixer "kda", or the config's `attention`; "dense" or "moe"."""
-    kda, _, ffn = kind.rpartition("+")
-    return (kda or cfg.attention), ffn
+    mixer "kda", or the config's `attention` ("full+" in front marks its
+    full-attention layers in a stack that mixes them with window layers:
+    `_is_full`); "dense" or "moe"."""
+    tag, _, ffn = kind.rpartition("+")
+    return ("kda" if tag == "kda" else cfg.attention), ffn
+
+
+def _is_full(kind: str) -> bool:
+    """Whether a segment's kind is the FULL-attention layers' of a stack
+    that has window layers too (`full_period`)."""
+    return kind.startswith("full+")
 
 
 def _segments(cfg: TransformerConfig):
@@ -230,12 +287,15 @@ def _segments(cfg: TransformerConfig):
     `lax.scan` of its own, in the model's order: [(parameter-name
     prefix, kind, first, layers)].  `kind` is "dense" or "moe" (the
     feed-forward), with "kda+" in front where the mixer is Kimi Delta
-    Attention and not the config's `attention`.  Layers of one prefix
+    Attention and not the config's `attention`, or "full+" where the
+    layer is a full-attention one among window layers (`full_period`).
+    Layers of one prefix
     share stacked leaves and a segment holds rows [first, first +
     layers) of them (a stack of two mixer kinds comes back to a kind
     once per period).  The model's repeated layer kind keeps the bare
     leaf names; leading dense layers are "dense.<leaf>", KDA layers
-    "kda.<leaf>" behind that, the multi-token-prediction block's layer
+    "kda.<leaf>" and full-attention layers among window layers
+    "full.<leaf>" behind that, the multi-token-prediction block's layer
     "mtp.<leaf>"."""
     ffn = "moe" if cfg.n_experts else "dense"
     ids = cfg.layer_ids or range(cfg.n_layers)
@@ -243,8 +303,10 @@ def _segments(cfg: TransformerConfig):
     for j, i in enumerate(ids):
         lead = j < cfg.n_dense_layers
         kda = bool(cfg.kda_period) and (i + 1) % cfg.kda_period != 0
-        prefix = ("dense." if lead else "") + ("kda." if kda else "")
-        kind = ("kda+" if kda else "") + ("dense" if lead else ffn)
+        full = bool(cfg.full_period) and (i + 1) % cfg.full_period == 0
+        tag = "kda" if kda else "full" if full else ""
+        prefix = ("dense." if lead else "") + (tag and tag + ".")
+        kind = (tag and tag + "+") + ("dense" if lead else ffn)
         if segs and segs[-1][0] == prefix:
             segs[-1][3] += 1
         else:
@@ -274,6 +336,9 @@ def _layer_leaves(cfg: TransformerConfig, kind: str):
     E, H = cfg.d_model, cfg.n_heads
     col, row = (None, AXIS_TP), (AXIS_TP, None)
     out = {"ln1": ((E,), (None,), None), "ln2": ((E,), (None,), None)}
+    if cfg.post_norms:
+        out.update({"ln1_post": ((E,), (None,), None),
+                    "ln2_post": ((E,), (None,), None)})
     mixer, kind = _kind_parts(cfg, kind)
     if mixer == "kda":
         d = cfg.kda_head_dim or E // H
@@ -308,6 +373,17 @@ def _layer_leaves(cfg: TransformerConfig, kind: str):
             out["k_nope_norm"] = ((dn,), (None,), None)
         if cfg.head_gate:
             out["w_gate"] = ((E, H), col, E)
+    elif mixer == "gqa":
+        d, Hkv = cfg.head_dim or E // H, cfg.n_kv_heads or H
+        out.update({"wq": ((E, H * d), col, E),
+                    "wk": ((E, Hkv * d), col, E),
+                    "wv": ((E, Hkv * d), col, E),
+                    "wo": ((H * d, E), row, H * d)})
+        if cfg.qk_norm:
+            out["q_norm"] = ((d,), (None,), None)
+            out["k_norm"] = ((d,), (None,), None)
+        if cfg.out_gate:
+            out["w_gate"] = ((E, H * d), col, E)
     else:
         out.update({n: ((E, E), col, E) for n in ("wq", "wk", "wv")})
         out["wo"] = ((E, E), row, E)
@@ -544,22 +620,28 @@ def _kept(y):
     return checkpoint_name(y, REMAT_DOT)
 
 
-def _causal_attention(q, k, v, scale=None):
+def _causal_attention(q, k, v, scale=None, window=None):
     """Causal attention over the ring-sharded sequence.  q, k, v: [B,
-    T_loc, h, D], as the projections leave them; returns [B, T_loc, h *
-    D], what the out-projection reads.  `scale` multiplies the scores
-    (None: D ** -0.5).  On one sequence shard with the
+    T_loc, h, D], as the projections leave them (k and v may have fewer
+    heads than q: grouped kv heads); returns [B, T_loc, h * D], what
+    the out-projection reads.  `scale` multiplies the scores (None: D **
+    -0.5); with a `window` a query sees the last `window` keys, its own
+    among them.  Grouped kv heads and a window are the flash entry's
+    alone (its kernels, or off a TPU its jnp reference): the ring knows
+    neither, and `_check_mesh` refuses them with sp > 1.  On one sequence shard with the
     kernel available this is the flash kernels' entry that keeps this
     layout (its `custom_vjp` holds the merged output and the log-sums
     under names `remat="dots"` keeps); the sp > 1 ring, and a host
     without the kernel, take the ring's own path in [B, h, T, D]."""
     import jax
 
-    if jax.lax.axis_size(AXIS_SP) == 1 and _pallas_enabled():
+    if jax.lax.axis_size(AXIS_SP) == 1 and (
+            _pallas_enabled() or window or k.shape[2] != q.shape[2]):
         from ..ops.pallas_attention import flash_attention_bthd
 
-        return flash_attention_bthd(q, k, v, sm_scale=scale, causal=True)
-    B, T, h, D = v.shape
+        return flash_attention_bthd(q, k, v, sm_scale=scale, causal=True,
+                                    window=window)
+    B, T, h, D = q.shape
     o = ring_attention(*(a.transpose(0, 2, 1, 3) for a in (q, k, v)),
                        axis_name=AXIS_SP, causal=True, scale=scale)
     return o.transpose(0, 2, 1, 3).reshape(B, T, h * D)
@@ -608,12 +690,58 @@ def _attention(cfg, x, wq, wk, wv, wo, tp_size):
     return jax.lax.psum(out, AXIS_TP)
 
 
-def _rotary_table(cfg, positions):
-    """(cos, sin), float32 [T, qk_rope_dim / 2], of the global
-    `positions`: angle = position * rope_theta ** (-2i / qk_rope_dim)."""
+def _gqa(cfg, x, lw, tp_size, rope, window):
+    """Attention with grouped kv heads.  x: [B, T_loc, E].  q as
+    n_heads heads of head_dim, k and v as n_kv_heads (q head h meets kv
+    head h // group: the flash kernels read the kv heads in place);
+    `qk_norm` RMS-norms q and k over each head's values, one [head_dim]
+    scale each; `rope` (None: no positions) rotates q and k over the
+    whole head; `window` (None: the whole causal prefix) is the
+    kernels' band; `out_gate` multiplies the merged output by sigmoid(x
+    W_gate), elementwise.  Heads are column-sharded over tp in wq / wk /
+    wv / w_gate and row-sharded in wo.  Device scopes `qk_norm`, `rope`,
+    `core`, `gate`.
+
+    Under "dots" the block gives back (does not `_kept`) the gate's
+    product, or q's where there is no gate: with `o @ wo`'s the
+    cheapest to rebuild, and twice its bytes (head_dim * n_heads wide
+    against d_model); that pays for the attention's merged output,
+    which IS kept."""
+    import jax
     import jax.numpy as jnp
 
-    half = cfg.qk_rope_dim // 2
+    B, T, _ = x.shape
+    h = cfg.n_heads // tp_size
+    hkv = (cfg.n_kv_heads or cfg.n_heads) // tp_size
+    d = cfg.head_dim or cfg.d_model // cfg.n_heads
+    q = x @ lw["wq"]
+    q = (_kept(q) if cfg.out_gate else q).reshape(B, T, h, d)
+    k, v = (_kept(x @ lw[n]).reshape(B, T, hkv, d) for n in ("wk", "wv"))
+    if cfg.qk_norm:
+        with jax.named_scope("qk_norm"):
+            q = _rms_norm(q, lw["q_norm"], cfg.norm_eps)
+            k = _rms_norm(k, lw["k_norm"], cfg.norm_eps)
+    if rope is not None:
+        with jax.named_scope("rope"):
+            rope_h = tuple(r[:, None] for r in rope)    # [T, 1, d / 2]
+            q, k = _rotate(q, rope_h), _rotate(k, rope_h)
+    with jax.named_scope("core"):
+        o = _causal_attention(q, k, v, window=window)
+    if cfg.out_gate:
+        with jax.named_scope("gate"):
+            gate = jax.nn.sigmoid((x @ lw["w_gate"]).astype(jnp.float32))
+            o = (o * gate).astype(x.dtype)
+    return jax.lax.psum(_kept(o @ lw["wo"]), AXIS_TP)
+
+
+def _rotary_table(cfg, positions):
+    """(cos, sin), float32 [T, width / 2], of the global `positions`:
+    angle = position * rope_theta ** (-2i / width), the width being
+    latent attention's qk_rope_dim or "gqa"'s whole head."""
+    import jax.numpy as jnp
+
+    half = (cfg.qk_rope_dim if cfg.attention == "mla"
+            else cfg.head_dim or cfg.d_model // cfg.n_heads) // 2
     inv = cfg.rope_theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
     ang = positions.astype(jnp.float32)[:, None] * inv[None]
     return jnp.cos(ang), jnp.sin(ang)
@@ -1133,7 +1261,6 @@ def _layer_fn(cfg, kind, tp_size, ep_size, rope=None):
     import jax
 
     mixer, ffn = _kind_parts(cfg, kind)
-
     # the named scopes (here, `conv` .. `out` in `kda`, `router` ..
     # `combine` in the expert layer, and `embed` / `mtp` / `loss` /
     # `adam` below) are metadata on the device program's instructions: a
@@ -1149,6 +1276,19 @@ def _layer_fn(cfg, kind, tp_size, ep_size, rope=None):
             with jax.named_scope("mla"):
                 h = x + _mla(cfg, _rms_norm(x, lw["ln1"], cfg.norm_eps),
                              lw, tp_size, rope)
+        elif mixer == "gqa":
+            # mask and positions are the LAYER's: a full-attention layer
+            # among window layers has no window, and no positions where
+            # the model gives it none
+            full = _is_full(kind)
+            with jax.named_scope("attn"):
+                m = _gqa(cfg, _rms_norm(x, lw["ln1"], cfg.norm_eps), lw,
+                         tp_size,
+                         rope if cfg.rope_full or not full else None,
+                         None if full else cfg.window or None)
+                if cfg.post_norms:
+                    m = _rms_norm(m, lw["ln1_post"], cfg.norm_eps)
+                h = x + m
         else:
             with jax.named_scope("attn"):
                 h = x + _attention(cfg, _rms_norm(x, lw["ln1"],
@@ -1164,6 +1304,8 @@ def _layer_fn(cfg, kind, tp_size, ep_size, rope=None):
                 f = _dense_ffn(z, lw["w1"], lw["w2"])
             else:
                 f = _gated_ffn(z, lw["wg"], lw["wu"], lw["wd"])
+            if cfg.post_norms:
+                f = _rms_norm(f, lw["ln2_post"], cfg.norm_eps)
             return h + f, stats
 
     if cfg.remat == "none":
@@ -1216,8 +1358,9 @@ def _run_stack(cfg, params, x, tp_size, ep_size, rope):
 
 
 def _embed(cfg, params, tokens, tp_idx, V_loc, positions):
-    """Vocab-sharded embedding lookup (local rows + psum over tp), plus
-    the learned positions where the model has a table."""
+    """Vocab-sharded embedding lookup (local rows + psum over tp), times
+    `embed_scale`, plus the learned positions where the model has a
+    table."""
     import jax
     import jax.numpy as jnp
 
@@ -1230,6 +1373,8 @@ def _embed(cfg, params, tokens, tp_idx, V_loc, positions):
     # (vocab-sharded one-hot), so a native-dtype psum is exact
     # and halves the ICI bytes vs upcasting to f32 first
     emb = jax.lax.psum(emb, AXIS_TP)
+    if cfg.embed_scale != 1.0:
+        emb = emb.astype(jnp.float32) * cfg.embed_scale
     if "pos" in params:
         emb = emb + params["pos"][positions][None]
     return emb.astype(jnp.dtype(cfg.dtype))               # [B, T, E]
@@ -1304,7 +1449,7 @@ def _build_loss_fn(cfg: TransformerConfig, mesh, n_micro: int):
 
         pos_global = sp_idx * T + jnp.arange(T)
         rope = _rotary_table(cfg, pos_global) \
-            if cfg.attention == "mla" else None
+            if cfg.attention != "mha" else None
         with jax.named_scope("embed"):
             x = _embed(cfg, params, tokens, tp_idx, V_loc, pos_global)
 
@@ -1531,6 +1676,15 @@ def _check_mesh(cfg, mesh):
             "gated (swiglu) experts run the held experts' grouped "
             "products on ep = 1; the exchange over ep > 1 is the "
             "capacity-bucketed one (GELU experts), which drops")
+    if cfg.attention == "gqa":
+        kv = cfg.n_kv_heads or cfg.n_heads
+        if mesh.shape[AXIS_SP] > 1 and (cfg.window or kv != cfg.n_heads):
+            raise MXNetError(
+                "a window and grouped kv heads are the flash kernels': "
+                "the sp > 1 ring knows neither: sp = 1")
+        if kv % mesh.shape[AXIS_TP]:
+            raise MXNetError("n_kv_heads=%d not divisible by tp=%d"
+                             % (kv, mesh.shape[AXIS_TP]))
     if KDA_STATS[0] in _stat_names(cfg) and (mesh.shape[AXIS_SP] > 1
                                              or mesh.shape[AXIS_TP] > 1):
         raise MXNetError(
@@ -1702,7 +1856,7 @@ def make_forward(cfg: TransformerConfig, mesh):
         B, T = tokens.shape
         pos_global = sp_idx * T + jnp.arange(T)
         rope = _rotary_table(cfg, pos_global) \
-            if cfg.attention == "mla" else None
+            if cfg.attention != "mha" else None
         x = _embed(cfg, params, tokens, tp_idx, V_loc, pos_global)
         pp = mesh.shape[AXIS_PP]
         state = x

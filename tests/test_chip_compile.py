@@ -448,3 +448,117 @@ def test_ling_cell_program_compiles_for_one_chip_and_keeps_its_kernels(
                        "mx_flash_dkv": want}, kernels
     assert "ragged-dot" in text, "the grouped products left Mosaic"
     assert _mosaic_call_sites(text) == LING_MOSAIC_SITES
+
+
+# ---------------------------------------------------------------------------
+# `trinitym_ep16_fused_k4`: one chip's share of Trinity-Mini at published
+# widths (benchmark/onchip/configs/trinity_mini_ep16.json, traffic/
+# fused_k4_tokens_2x8k.json), through the config the cell's driver builds
+
+TRI_DESCRIBED = 18322000000     # arguments 5.042e9 + temporaries (PR 36)
+TRI_MOSAIC_SITES = 35       # 3 segments x 3 flash kernels + grouped products
+_FLASH_OPERANDS = re.compile(r"%(mx_flash_\w+?)[.\d]* = .*"
+                             r"operand_layout_constraints=\{(.*?)frontend")
+
+
+def _tri_cell():
+    import json
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    onchip = os.path.join(root, "benchmark", "onchip")
+    if onchip not in sys.path:
+        sys.path.insert(0, onchip)
+    from drivers.lm_trinity_fused import transformer_config
+
+    with open(os.path.join(onchip, "configs",
+                           "trinity_mini_ep16.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(onchip, "traffic",
+                           "fused_k4_tokens_2x8k.json")) as f:
+        traffic = json.load(f)
+    return transformer_config(config), config, traffic
+
+
+def test_trinity_cell_program_reads_its_kv_heads_in_place(one_chip_mesh,
+                                                          monkeypatch):
+    """The cell's K=4 program at 2 x 8192 tokens a step, compiled for a
+    described v5e: the three flash kernels are in it by name, the
+    forward and dq on q's `[2, 8192, 32 x 128]` and dk/dv on k's own
+    `[2, 8192, 4 x 128]`; EVERY flash call is handed k and v as `[2,
+    8192, 512]` (no array of k or v at q's 32 heads exists to hand it:
+    `flash_kv_expanded` stays 0), under a window of 2048 on the window
+    layers' segments; the grouped expert products went to XLA's own
+    Mosaic kernel; the described bytes are bounded (the chip reserves
+    about three quarters of the described temporaries, PERF.md section
+    7, so the bound is above the chip's 16.9e9)."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxtpu import profiler
+    from mxtpu.ops import pallas_attention as pa
+    from mxtpu.parallel import transformer as tf
+
+    monkeypatch.setattr(pa, "_on_tpu", lambda: True)
+    expanded = profiler.get_stat("flash_kv_expanded")
+    split = profiler.get_stat("flash_calls_split")
+    cfg, config, traffic = _tri_cell()
+    k, b = traffic["steps_per_program"], traffic["batch"]
+    step, sh = tf.make_fused_train_steps(cfg, one_chip_mesh, k, lr=3e-4,
+                                         optimizer="adam")
+    shapes = tf.param_shapes(cfg, 1)
+    assert sum(int(jnp.prod(jnp.array(s))) for s in shapes.values()) \
+        == 504147712            # the issue's count of what this chip holds
+    params = {n: jax.ShapeDtypeStruct(s, jnp.bfloat16,
+                                      sharding=sh["params"][n])
+              for n, s in shapes.items()}
+    moments = {n: jax.ShapeDtypeStruct(s, jnp.float32,
+                                       sharding=sh["opt_state"]["m"][n])
+               for n, s in shapes.items()}
+    opt = {"m": moments, "v": dict(moments),
+           "t": jax.ShapeDtypeStruct((), jnp.float32,
+                                     sharding=sh["opt_state"]["t"])}
+    length = config["input"]["length"]
+    data = jax.ShapeDtypeStruct((k, b, length), jnp.int32,
+                                sharding=sh["data"])
+    compiled = step.lower(params, opt, data, data).compile()
+    assert profiler.get_stat("flash_kv_expanded") == expanded
+    assert profiler.get_stat("flash_calls_split") == split
+    assert profiler.get_stat("flash_kv_group") == 8
+    assert profiler.get_stat("flash_window") == config["sliding_window"]
+    need = _described_bytes(compiled)
+    assert 0.75 * _CHIP_BYTES < need <= TRI_DESCRIBED + 0.05 * 2 ** 30, need
+
+    text = compiled.as_text()
+    heads, kv, width = (config["num_attention_heads"],
+                        config["num_key_value_heads"], config["head_dim"])
+    at_q, at_kv = (b, length, heads * width), (b, length, kv * width)
+    kernels = _flash_kernels(text)
+    assert kernels == {"mx_flash_fwd": {at_q}, "mx_flash_dq": {at_q},
+                       "mx_flash_dkv": {at_kv}}, kernels
+    calls = _FLASH_OPERANDS.findall(text)
+    assert len(calls) == 9      # 3 segments (dense., window, full.) x 3
+    for name, operands in calls:
+        operand_shapes = [tuple(int(x) for x in dims.split(","))
+                          for dims in re.findall(r"\[([\d,]+)\]", operands)]
+        assert operand_shapes[:3] == [at_q, at_kv, at_kv], (name,
+                                                            operand_shapes)
+    assert "ragged-dot" in text, "the grouped products left Mosaic"
+    assert _mosaic_call_sites(text) == TRI_MOSAIC_SITES
+
+    # the loops: the K loop and the forward and backward scan of the
+    # three window expert layers; the one-layer segments need none.  What
+    # is copied at q's size in them is q's own: XLA lays the 4-D `[2,
+    # 8192, 32, 128]` the rotary step works on out head-major, so q is
+    # re-laid out after it (forward, and rebuilt in the backward pass),
+    # and a saved stack element and dq once each: 4 copies recorded, none
+    # of them k's or v's, which the kernels' operands above show
+    whiles, copies, splits = _whiles_and_stack_copies(
+        text, 3, _split_shapes(b, length, heads, width) | {at_q})
+    layer_loops = [body for body, in_entry in whiles if not in_entry]
+    assert len(layer_loops) == 2 and len(whiles) == 3, whiles
+    found = [c for body in layer_loops for c in copies.get(body, [])]
+    assert not found, ("whole-stack copies once per layer:\n  "
+                       + "\n  ".join(found))
+    found = [c for body, _ in whiles for c in splits.get(body, [])]
+    assert len(found) <= 4, "\n  ".join(found)

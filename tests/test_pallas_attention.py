@@ -289,27 +289,41 @@ def test_causal_walk_matches_reference(case):
                                    err_msg="d%s" % name)
 
 
-@pytest.mark.parametrize("causal,blocks,want", [
-    (True, (512, 512), (64, 36, 8)),      # the walk: 128-wide sub-tiles
-    (True, (512, 256), (8, 6, 4)),        # whole blocks: the fallback
-    (False, (512, 512), (64, 64, 0)),
-], ids=["walk", "fallback", "not_causal"])
-def test_flash_tiles_stats(causal, blocks, want):
+@pytest.mark.parametrize("causal,blocks,window,want", [
+    (True, (512, 512), None, (64, 36, 8)),    # the walk: 128-wide sub-tiles
+    (True, (512, 256), None, (8, 6, 4)),      # whole blocks: the fallback
+    (False, (512, 512), None, (64, 64, 0)),
+    # T = 1024 in 2 x 2 blocks of 4 x 4 tiles under a window of 512: the
+    # two diagonal blocks in sub-tiles (10 visited, 4 masked each) and
+    # block (1, 0), which the band's left edge crosses (query 512 + r
+    # sees key c iff c > r), whole under the mask: 16 and 16
+    (True, (512, 512), 512, (64, 36, 24)),
+    # ... of 256 in 4 x 4 blocks of 2 x 2 tiles: four diagonal blocks (3
+    # visited, 2 masked), three edge blocks (i, i - 1) (4 and 4); the
+    # three blocks (i, i - 2) and (3, 0) lie wholly left of the band
+    (True, (256, 256), 256, (64, 24, 20)),
+    # ... of 100, narrower than a 128 block: the diagonal blocks are
+    # edge blocks too (whole, masked), and so are the 7 blocks (i, i - 1)
+    (True, (128, 128), 100, (64, 15, 15)),
+    (True, (512, 512), 1024, (64, 36, 8)),    # reaches every key: causal
+], ids=["walk", "fallback", "not_causal", "band_512", "band_256",
+        "band_narrower_than_a_block", "window_reaches_every_key"])
+def test_flash_tiles_stats(causal, blocks, window, want):
     """`flash_tiles_{total,visited,masked}`: what one traced call adds
-    per head at gpt2-medium's shape (T=1024, d=64).  The parent's
-    kernels visited and masked 48 of 64."""
+    per head at gpt2-medium's shape (T=1024, d=64), against a count by
+    hand.  The parent's kernels visited and masked 48 of 64."""
     import jax
     import jax.numpy as jnp
 
     from mxtpu import profiler
-    from mxtpu.ops.pallas_attention import flash_attention
+    from mxtpu.ops.pallas_attention import flash_attention_bthd
 
     names = ["flash_tiles_" + n for n in ("total", "visited", "masked")]
     x = jax.ShapeDtypeStruct((2, 1024, 64), jnp.float32)
     before = [profiler.get_stat(n) for n in names]
-    jax.eval_shape(lambda q, k, v: flash_attention(
-        q, k, v, causal=causal, block_q=blocks[0], block_k=blocks[1]),
-        x, x, x)
+    jax.eval_shape(lambda q, k, v: flash_attention_bthd(
+        q[:, :, None], k[:, :, None], v[:, :, None], causal=causal,
+        block_q=blocks[0], block_k=blocks[1], window=window), x, x, x)
     got = tuple(profiler.get_stat(n) - b for n, b in zip(names, before))
     assert got == want
 
@@ -354,7 +368,7 @@ def test_bthd_entry_matches_reference(d, causal):
         np.testing.assert_allclose(np.asarray(a), np.asarray(g),
                                    rtol=2e-3, atol=2e-4,
                                    err_msg="d%s" % name)
-    out, res = fa._flash_fwd(q, k, v, scale, causal, 128, 128)
+    out, res = fa._flash_fwd(q, k, v, scale, causal, 128, 128, None)
     assert res[0] is q and res[1] is k and res[2] is v
     assert res[3].shape == (b, t, h * d) and res[4].shape == (b * h, t)
     np.testing.assert_allclose(np.asarray(res[4]), np.asarray(gold_lse),
@@ -508,3 +522,126 @@ def test_dkv_sweep_keeps_the_q_side_where_it_fits(fits, monkeypatch):
         np.testing.assert_allclose(np.asarray(a), np.asarray(g),
                                    rtol=2e-3, atol=2e-4,
                                    err_msg="d%s" % name)
+
+
+# (q heads, kv heads, head width, T, block, window) -> launches fed an
+# expanded k / v (a forward and the backward pass's two, or none)
+_BAND_CASES = {
+    "group_2": ((4, 2, 128, 512, 128, None), 0),
+    "group_8_window_under_a_block": ((8, 1, 128, 512, 128, 100), 0),
+    "group_2_window_no_multiple_of_the_block": ((4, 2, 128, 512, 128, 200),
+                                                0),
+    "group_1_window_of_two_blocks": ((2, 2, 128, 512, 128, 256), 0),
+    "group_2_T_no_multiple_of_the_window": ((2, 1, 128, 640, 256, 300), 0),
+    "blocks_of_256_under_a_window_of_one": ((2, 1, 128, 1024, 256, 256), 0),
+    "two_heads_to_a_block_window": ((4, 4, 64, 512, 128, 200), 0),
+    "narrow_heads_are_expanded": ((4, 2, 64, 512, 128, 100), 3),
+}
+
+
+@pytest.mark.parametrize("case", list(_BAND_CASES), ids=list(_BAND_CASES))
+def test_grouped_kv_heads_and_the_band_match_reference(case):
+    """Fewer kv heads than q heads read in place (q head h meets kv head
+    h // group; dk and dv summed over a group's q heads inside the dkv
+    sweep) and a static window (blocks left of the band skipped, the
+    sweeps' inner axes the band's length): forward, dq, dk, dv against
+    attention written out with a plain `repeat` and an index compare;
+    and what the three stats read."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxtpu import profiler
+    from mxtpu.ops import pallas_attention as fa
+
+    (h, hkv, d, t, block, window), expanded = _BAND_CASES[case]
+    rng = np.random.RandomState(t + h)
+    q, w = (jnp.asarray(rng.normal(0, 1, (1, t, h, d)).astype(np.float32))
+            for _ in range(2))
+    k, v = (jnp.asarray(rng.normal(0, 1, (1, t, hkv, d)).astype(np.float32))
+            for _ in range(2))
+
+    def ref(q, k, v):
+        k, v = (jnp.repeat(a, h // hkv, axis=2) for a in (k, v))
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(d)
+        qi, ki = jnp.arange(t)[:, None], jnp.arange(t)[None]
+        seen = (ki <= qi) if window is None \
+            else (ki <= qi) & (qi - ki < window)
+        p = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
+        return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+    names = ("flash_kv_expanded", "flash_attention_pallas")
+    before = [profiler.get_stat(n) for n in names]
+    for n in ("flash_kv_group", "flash_window"):
+        profiler.set_stat(n, 0)
+    got, vjp = jax.vjp(lambda q, k, v: fa.flash_attention_bthd(
+        q, k, v, causal=True, block_q=block, block_k=block,
+        window=window).reshape(1, t, h, d), q, k, v)
+    grads = vjp(w)
+    assert [profiler.get_stat(n) - x for n, x in zip(names, before)] \
+        == [expanded, 2]
+    assert profiler.get_stat("flash_kv_group") == h // hkv
+    assert profiler.get_stat("flash_window") == (window or 0)
+    gold, vjp_ref = jax.vjp(ref, q, k, v)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(gold),
+                               rtol=2e-4, atol=2e-5)
+    for a, g, name in zip(grads, vjp_ref(w), "qkv"):
+        assert a.shape == g.shape       # dk, dv at k's own head count
+        np.testing.assert_allclose(np.asarray(a), np.asarray(g),
+                                   rtol=2e-3, atol=2e-4,
+                                   err_msg="d%s" % name)
+
+
+def _pallas_calls(jaxpr, out):
+    for e in jaxpr.eqns:
+        if e.primitive.name == "pallas_call":
+            out.append(str(e))
+        for v in e.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _pallas_calls(sub, out)
+    return out
+
+
+# sha256 of the three kernels' `pallas_call` equations as the tree
+# before grouped kv heads and windows (commit f5a4f79) traced them, for
+# [1, 512, heads, width] in blocks of 256, causal, under jax 0.9.0
+_PARENT_KERNELS = {
+    (2, 128): ["4d2bf2f11736fa88", "490dc42760557ec3", "19ea362712b96f69"],
+    (4, 64): ["a5a1723675d97074", "3354b9b9b46fd719", "1a080e4a4815c1c1"],
+}
+
+
+@pytest.mark.parametrize("heads,width", list(_PARENT_KERNELS))
+def test_equal_heads_and_a_window_that_reaches_every_key_trace_the_parent(
+        heads, width):
+    """With equal head counts, no window or one that reaches every key,
+    the forward, dq and dkv kernels trace equation for equation what
+    they traced before they knew grouped kv heads or a band: the
+    accepted cells' programs hold the kernels they held."""
+    import hashlib
+
+    import jax
+    import jax.numpy as jnp
+
+    from mxtpu.ops import pallas_attention as fa
+
+    x = jnp.ones((1, 512, heads, width), jnp.float32)
+
+    def traced(window):
+        def f(q, k, v):
+            return fa.flash_attention_bthd(
+                q, k, v, causal=True, block_q=256, block_k=256,
+                window=window).sum()
+
+        return _pallas_calls(
+            jax.make_jaxpr(jax.grad(f, argnums=(0, 1, 2)))(x, x, x).jaxpr,
+            [])
+
+    plain = traced(None)
+    assert len(plain) == 3
+    assert traced(512) == plain and traced(4096) == plain
+    assert traced(511) != plain
+    if jax.__version__ == "0.9.0":      # the record's own version
+        assert [hashlib.sha256(c.encode()).hexdigest()[:16]
+                for c in plain] == _PARENT_KERNELS[(heads, width)]
