@@ -99,7 +99,10 @@ class TransformerConfig:
     # GELU experts go through the capacity-bucketed top-1 exchange
     # (`_experts_bucketed`, any ep; DROPS over capacity_factor), gated
     # ones through grouped products over the held experts
-    # (`_experts_grouped`: no token dropped, no capacity_factor; ep = 1)
+    # (`_experts_grouped`: no token dropped, no capacity_factor; ep = 1:
+    # the sorted pairs are walked in blocks of a size derived from the
+    # stated load, as many as the step's own routing needs, up to all
+    # n_tok * top_k pairs)
     n_dense_layers: int = 0    # leading dense layers (width d_ff) in
     # front of the expert layers: a segment of the stack with its own
     # scan and its own parameter names ("dense.<leaf>")
@@ -444,8 +447,9 @@ def _leaves(cfg: TransformerConfig, pp: int):
 
 # per step, over every expert layer (the MTP block's too): token-expert
 # pairs the router put in the held range; tokens routed (tokens x expert
-# layers); the fullest held expert's pairs in any one layer
-MOE_STATS = ("moe_pairs", "moe_tokens", "moe_load_max")
+# layers); the fullest held expert's pairs in any one layer; rows the
+# dispatch walked (blocks x the block's rows: `_experts_grouped`)
+MOE_STATS = ("moe_pairs", "moe_tokens", "moe_load_max", "moe_rows_walked")
 # with group-limited selection: tokens x expert layers whose kept groups
 # include the group of the first expert held here
 GROUP_STATS = ("moe_groups_kept_here",)
@@ -1076,60 +1080,171 @@ def _route(cfg, flat, router, bias=None):
     return idx.astype(jnp.int32), w
 
 
-def _experts_grouped(cfg, flat, idx, w, lw):
-    """The held experts' part of the routed result, with no token
-    dropped (ep = 1).  The (token, expert) pairs that fall in the held
-    range [expert_first, expert_first + held) are sorted by expert and
-    the experts' products run as grouped products over them
-    (`jax.lax.ragged_dot`: rows of one group meet one expert's matrix),
-    then each row is scattered back onto its token, weighted.  The row
-    bound n_tok * min(top_k, held) is static and no routing can overflow
-    it (a token's top_k experts are distinct), which is what makes it
-    dropless by construction; what experts held elsewhere would add is
-    left out.  we_*: [held, E, F/tp].  Returns ([n_tok, E], stats)."""
-    # (silu of unwritten rows in between is harmless: those rows meet no
-    # expert's matrix in the next grouped product either)
+# A block of the dispatch's walk holds this many times the load the
+# configuration states for the held experts (n_tok * top_k * held /
+# n_experts pairs a layer).  Not an option: the walk takes as many blocks
+# as the step's pairs need, whatever this is
+_BLOCK_LOADS = 2
+
+
+def _dispatch_block(cfg, n):
+    """(rows, C): the static bound on a layer's held pairs over n tokens
+    and the rows one block of `_experts_grouped`'s walk holds, from the
+    configuration alone: `_BLOCK_LOADS` times the stated load, to the
+    next 512; `rows` where one chip holds every expert."""
+    held = cfg.experts_held or cfg.n_experts
+    rows = n * min(cfg.top_k, held)
+    loads = _BLOCK_LOADS * n * cfg.top_k * held
+    return rows, min(rows, -(-loads // (512 * cfg.n_experts)) * 512)
+
+
+def _walk_pairs(flat, wf, experts, order, sizes, blocks, k, C):
+    """The held experts' products over the sorted pairs, a block of C
+    rows at a time, `blocks` (a device scalar) of them.  flat [n, E];
+    wf [n * k] float32, a pair's weight; experts (we_g, we_u, we_d);
+    order: the pairs' indices into wf, held pairs first, by expert;
+    sizes [held]: pairs an expert got.  Returns [n, E] float32.
+
+    Reverse mode cannot cross a loop of traced length, so this is a
+    `jax.custom_vjp`: it keeps its own arguments and the backward rule
+    walks the same blocks, rebuilds a block's forward and takes its
+    `jax.vjp`.  Nothing of `rows` rows exists in either pass."""
     import jax
     import jax.numpy as jnp
 
-    n, E = flat.shape
-    k = cfg.top_k
+    f32 = jnp.float32
+
+    def zeros(x):
+        return _match_vma(jnp.zeros(x.shape, f32), x)
+
+    def gather(i, flat, wf, order, sizes):
+        """Block i: its rows' pairs and tokens, which rows are pairs at
+        all, the block's share of each expert's group, and the rows'
+        tokens' activations and pairs' weights."""
+        r0 = i * C
+        ends = jnp.cumsum(sizes)
+        share = jnp.clip(ends - r0, 0, C) - jnp.clip(ends - sizes - r0, 0, C)
+        with jax.named_scope("dispatch"):
+            pair = jax.lax.dynamic_slice(order, (r0,), (C,))
+            tok = pair // k
+            used = (r0 + jnp.arange(C) < ends[-1])[:, None]
+            return pair, tok, used, share, flat[tok], wf[pair]
+
+    def rows_of(xs, ws, experts, used, share):
+        """[C, E] float32: each row's expert applied to it, weighted."""
+        def grouped(a, b):
+            return jax.lax.ragged_dot(a, b, share,
+                                      preferred_element_type=f32)
+
+        # rows past the last group belong to no expert.  The TPU's
+        # grouped product does not WRITE them, in either pass: what it
+        # leaves there is whatever the memory held.  So a block masks
+        # what it feeds (the transpose of this `where` then masks the
+        # cotangent that comes back for those rows, before it is
+        # scattered onto tokens) and what it takes out
+        # (silu of unwritten rows in between is harmless: those rows
+        # meet no expert's matrix in the next grouped product either)
+        with jax.named_scope("experts"):
+            we_g, we_u, we_d = experts
+            xs = jnp.where(used, xs, 0)
+            hid = (jax.nn.silu(grouped(xs, we_g))
+                   * grouped(xs, we_u)).astype(xs.dtype)
+            y = jax.lax.psum(jnp.where(used, grouped(hid, we_d), 0.0),
+                             AXIS_TP)                       # row-parallel
+            return y * jnp.where(used, ws[:, None], 0.0)
+
+    @jax.custom_vjp
+    def walk(flat, wf, experts, order, sizes, blocks):
+        def block(i, out):
+            _, tok, used, share, xs, ws = gather(i, flat, wf, order, sizes)
+            y = rows_of(xs, ws, experts, used, share)
+            with jax.named_scope("combine"):
+                return out.at[tok].add(y)
+
+        return jax.lax.fori_loop(0, blocks, block, zeros(flat))
+
+    def walk_fwd(*args):
+        return walk(*args), args
+
+    def walk_bwd(args, g):
+        flat, wf, experts, order, sizes, blocks = args
+
+        def block(i, grads):
+            d_flat, d_wf, d_experts = grads
+            pair, tok, used, share, xs, ws = gather(i, flat, wf, order,
+                                                    sizes)
+            with jax.named_scope("combine"):
+                g_rows = g[tok]
+            _, pull = jax.vjp(
+                lambda xs, ws, experts: rows_of(xs, ws, experts, used,
+                                                share), xs, ws, experts)
+            d_xs, d_ws, d_block = pull(g_rows)
+            with jax.named_scope("dispatch"):
+                d_flat = d_flat.at[tok].add(d_xs.astype(f32))
+                d_wf = d_wf.at[pair].add(d_ws)
+            return d_flat, d_wf, jax.tree_util.tree_map(
+                lambda a, b: a + b.astype(f32), d_experts, d_block)
+
+        d_flat, d_wf, d_experts = jax.lax.fori_loop(
+            0, blocks, block,
+            (zeros(flat), zeros(wf), jax.tree_util.tree_map(zeros, experts)))
+        return (d_flat.astype(flat.dtype), d_wf.astype(wf.dtype),
+                jax.tree_util.tree_map(lambda d, a: d.astype(a.dtype),
+                                       d_experts, experts),
+                None, None, None)
+
+    walk.defvjp(walk_fwd, walk_bwd)
+    # ranks of one mesh walk different numbers of blocks (each its own
+    # tokens' pairs), so no collective over an axis the tokens are split
+    # on may run INSIDE a loop.  What the mesh replicates over such an
+    # axis (the experts' matrices over dp and sp) is cast to the tokens'
+    # axes here: the loops then carry each rank's own gradient, and the
+    # cast's transpose sums them over the replicas once, after the walk
+    wf, experts = jax.tree_util.tree_map(lambda a: _match_vma(a, flat),
+                                         (wf, experts))
+    return walk(flat, wf, experts, order, sizes, blocks)
+
+
+def _experts_grouped(cfg, flat, idx, w, lw):
+    """The held experts' part of the routed result, with no token
+    dropped (ep = 1).  The (token, expert) pairs that fall in the held
+    range [expert_first, expert_first + held) are sorted by expert, held
+    pairs first, and WALKED in blocks of C rows (`_dispatch_block`: about
+    twice the stated load, from the configuration alone), ceil(pairs / C)
+    of them, the count read on the device from the step's own routing: a
+    block gathers its tokens, runs the experts' products as grouped
+    products over its share of each expert's group (`jax.lax.ragged_dot`:
+    rows of one group meet one expert's matrix) and scatter-adds each
+    row onto its token, weighted (`_walk_pairs`).  The pairs are at most
+    n_tok * min(top_k, held) (a token's top_k experts are distinct), that
+    is rows / C blocks, and the walk takes as many as there are pairs:
+    that is what makes it dropless by construction, with no capacity to
+    set; what experts held elsewhere would add is left out.  we_*:
+    [held, E, F/tp].  Returns ([n_tok, E], stats)."""
+    import jax
+    import jax.numpy as jnp
+
+    n, k = flat.shape[0], cfg.top_k
     held = cfg.experts_held or cfg.n_experts
-    rows = n * min(k, held)
+    rows, C = _dispatch_block(cfg, n)
     with jax.named_scope("dispatch"):
         local = idx - cfg.expert_first
         here = (local >= 0) & (local < held)
         # pairs held elsewhere sort behind every group and get no weight
         key = jnp.where(here, local, held).reshape(n * k)
-        order = jnp.argsort(key, stable=True)[:rows]
-        key_s = key[order]
-        tok = order // k
+        # whole blocks: the rows past `rows` are no pair's (masked)
+        order = jnp.pad(jnp.argsort(key, stable=True)[:rows],
+                        (0, -rows % C))
         sizes = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
-        # rows past the last group belong to no expert.  The TPU's
-        # grouped product does not WRITE them, in either pass: what it
-        # leaves there is whatever the memory held.  So the layer masks
-        # what it feeds (the transpose of this `where` then masks the
-        # cotangent that comes back for those rows, before it is
-        # scattered onto tokens) and what it takes out
-        used = (key_s < held)[:, None]
-        xs = jnp.where(used, flat[tok], 0)                # [rows, E]
-        ws = jnp.where(used[:, 0], w.reshape(n * k)[order], 0.0)
-    with jax.named_scope("experts"):
-        def grouped(a, b):
-            return jax.lax.ragged_dot(a, b, sizes,
-                                      preferred_element_type=jnp.float32)
-
-        hid = (jax.nn.silu(grouped(xs, lw["we_g"]))
-               * grouped(xs, lw["we_u"])).astype(flat.dtype)
-        y = grouped(hid, lw["we_d"])
-        y = jax.lax.psum(jnp.where(used, y, 0.0), AXIS_TP)  # row-parallel
-    with jax.named_scope("combine"):
-        out = jnp.zeros((n, E), jnp.float32).at[tok].add(
-            y * ws[:, None]).astype(flat.dtype)
+        blocks = (sizes.sum() + C - 1) // C
+    out = _kept(_walk_pairs(flat, w.reshape(n * k),
+                            (lw["we_g"], lw["we_u"], lw["we_d"]), order,
+                            sizes, blocks, k, C).astype(flat.dtype))
     f32 = jnp.float32
     stats = {"moe_pairs": here.sum().astype(f32),
              "moe_tokens": _pvary_all(jnp.asarray(n, f32)),
-             "moe_load_max": sizes.max().astype(f32)}
+             "moe_load_max": sizes.max().astype(f32),
+             "moe_rows_walked": (blocks * C).astype(f32)}
     return out, stats
 
 

@@ -153,6 +153,17 @@ def _whiles_and_stack_copies(text, n_layers, split=()):
     return whiles, copies, splits
 
 
+def _row_bound_arrays(text, rows, widths):
+    """The arrays of the compiled text with `rows` leading and one of
+    `widths` behind it: what the expert dispatch gathered, multiplied
+    and scattered a layer while it worked on the static bound of n_tok
+    * top_k rows.  Since PR 37 it walks the step's pairs in blocks
+    (`transformer._walk_pairs`) and no such array is left."""
+    return sorted(set(re.findall(
+        r"\w+\[%d,(?:%s)\]" % (rows, "|".join(str(w) for w in widths)),
+        text)))
+
+
 def _split_shapes(b, t, h, d):
     """The shapes a head split or merge copy of [b, t, h * d] results
     in: the kernels read that layout in place since PR 35, and before
@@ -267,9 +278,10 @@ def test_glm_cell_program_fits_one_chip_and_keeps_its_kernels(
     a lane block of its own; q.k 192 + 64 = v 256), and no head split
     or merge copy anywhere; the grouped expert products went to XLA's own
     Mosaic kernel; no loop over the layers copies a whole weight stack
-    in its body; and the loops are the K loop and the forward and
-    backward scan of the four expert layers (the one-layer segments, the
-    dense layer and the MTP block, need none)."""
+    in its body; and the loops are the K loop, the forward and backward
+    scan of the four expert layers, and a forward and a backward walk
+    of the dispatch's blocks in each of them and in the MTP block; no
+    array of the dispatch's static row bound is left."""
     import jax
     import jax.numpy as jnp
 
@@ -306,11 +318,13 @@ def test_glm_cell_program_fits_one_chip_and_keeps_its_kernels(
     assert pa._lane_plan(32, 256) == 1
     need = _described_bytes(compiled)
     assert need < _CHIP_BYTES, "arguments + temporaries %.3e bytes" % need
-    # a full chip, as a training job's is (PERF.md: 16.0e9 of 16.9e9)
+    # a full chip, as a training job's is (PERF.md section 4)
     assert need > 0.75 * _CHIP_BYTES, need
-    # no more than the parent of PR 33 (16 027 537 920 described bytes):
-    # the kernel's kept output is paid for by `c_q @ wq_b`, rebuilt
-    assert need <= 16027537920 + 0.05 * 2 ** 30, need
+    # recorded at PR 37: 13 817 757 696 described bytes (7 065 million
+    # of arguments), where PR 33 had 16 027 537 920: the expert
+    # dispatch's [32 768, 2048] and [32 768, 1536] buffers went (a block
+    # of its walk is 8 192 rows)
+    assert need <= 13817757696 + 0.05 * 2 ** 30, need
 
     text = compiled.as_text()
     heads, width = config["num_attention_heads"], config["v_head_dim"]
@@ -321,8 +335,15 @@ def test_glm_cell_program_fits_one_chip_and_keeps_its_kernels(
                        "mx_flash_dkv": want}, kernels
     assert "ragged-dot" in text, "the grouped products left Mosaic"
     # one forward call site fewer than before PR 33 (36): the expert
-    # layers' backward scan no longer runs the forward kernel again
-    assert _mosaic_call_sites(text) == 35
+    # layers' backward scan no longer runs the forward kernel again;
+    # PR 37 (35 -> 39): the grouped products sit in the walks' loops,
+    # and a backward walk rebuilds its block's three
+    assert _mosaic_call_sites(text) == 39
+    rows = b * length * config["num_experts_per_tok"]
+    assert tf._dispatch_block(cfg, b * length) == (rows, rows // 4)
+    found = _row_bound_arrays(text, rows, (config["hidden_size"],
+                                           config["moe_intermediate_size"]))
+    assert not found, found
 
     layers = config["num_hidden_layers"] - config["first_k_dense_replace"]
     whiles, copies, splits = _whiles_and_stack_copies(
@@ -336,8 +357,10 @@ def test_glm_cell_program_fits_one_chip_and_keeps_its_kernels(
     found = [c for body, _ in whiles for c in splits.get(body, [])]
     assert not found, ("head split / merge copies once per layer:\n  "
                        + "\n  ".join(found))
-    # recorded: 3 whiles (PR 30)
-    assert len(layer_loops) == 2 and len(whiles) == 3, whiles
+    # recorded: 3 whiles (PR 30); 7 since PR 37: the K loop, the two
+    # layer scans, and the dispatch's walk forward and backward in the
+    # scans' bodies and, for the MTP block, in the K loop's
+    assert len(layer_loops) == 6 and len(whiles) == 7, whiles
 
 
 # ---------------------------------------------------------------------------
@@ -347,8 +370,16 @@ def test_glm_cell_program_fits_one_chip_and_keeps_its_kernels(
 # the config the cell's driver builds
 
 
-LING_DESCRIBED = 18143063040   # arguments + temporaries (PR 34)
+# arguments + temporaries: 18 143 063 040 at PR 34, 18 056 681 472 since
+# PR 37 (the dispatch's [32 768, 2560] buffers went; the KDA layers'
+# temporaries set this program's peak)
+LING_DESCRIBED = 18056681472
 LING_MOSAIC_SITES = 33      # 3 flash kernels + XLA's grouped products
+# the K loop, the five KDA + expert layers' two scans, the chunk scans
+# of the KDA core (forward, rebuilt, backward) and, since PR 37 (9
+# before), the dispatch's walk forward and backward in the scanned
+# segment and in the one-layer MLA segment
+LING_WHILES = 13
 
 
 def _ling_cell():
@@ -448,6 +479,15 @@ def test_ling_cell_program_compiles_for_one_chip_and_keeps_its_kernels(
                        "mx_flash_dkv": want}, kernels
     assert "ragged-dot" in text, "the grouped products left Mosaic"
     assert _mosaic_call_sites(text) == LING_MOSAIC_SITES
+    assert len(_whiles_and_stack_copies(text, 5)[0]) == LING_WHILES
+    tokens = traffic["batch"] * config["input"]["length"]
+    rows = tokens * config["num_experts_per_tok"]
+    from mxtpu.parallel import transformer as tf
+
+    assert tf._dispatch_block(_ling_cell()[0], tokens) == (rows, rows // 32)
+    found = _row_bound_arrays(text, rows, (config["hidden_size"],
+                                           config["moe_intermediate_size"]))
+    assert not found, found
 
 
 # ---------------------------------------------------------------------------
@@ -455,8 +495,13 @@ def test_ling_cell_program_compiles_for_one_chip_and_keeps_its_kernels(
 # widths (benchmark/onchip/configs/trinity_mini_ep16.json, traffic/
 # fused_k4_tokens_2x8k.json), through the config the cell's driver builds
 
-TRI_DESCRIBED = 18322000000     # arguments 5.042e9 + temporaries (PR 36)
-TRI_MOSAIC_SITES = 35       # 3 segments x 3 flash kernels + grouped products
+# arguments 5.042e9 + temporaries: 18.322e9 at PR 36, 14 522 442 752 since
+# PR 37: the dispatch's [131 072, 2048] and [131 072, 1024] buffers went (a
+# block is 16 384 rows), and the walk's result is kept for the post-norm
+TRI_DESCRIBED = 14522442752
+# 3 segments x 3 flash kernels + grouped products (35 at PR 36; since PR
+# 37 they sit in the walks' loops, a backward walk rebuilding its block's)
+TRI_MOSAIC_SITES = 39
 _FLASH_OPERANDS = re.compile(r"%(mx_flash_\w+?)[.\d]* = .*"
                              r"operand_layout_constraints=\{(.*?)frontend")
 
@@ -530,6 +575,11 @@ def test_trinity_cell_program_reads_its_kv_heads_in_place(one_chip_mesh,
     assert 0.75 * _CHIP_BYTES < need <= TRI_DESCRIBED + 0.05 * 2 ** 30, need
 
     text = compiled.as_text()
+    rows = b * length * config["num_experts_per_tok"]
+    assert tf._dispatch_block(cfg, b * length) == (rows, rows // 8)
+    found = _row_bound_arrays(text, rows, (config["hidden_size"],
+                                           config["moe_intermediate_size"]))
+    assert not found, found
     heads, kv, width = (config["num_attention_heads"],
                         config["num_key_value_heads"], config["head_dim"])
     at_q, at_kv = (b, length, heads * width), (b, length, kv * width)
@@ -546,8 +596,10 @@ def test_trinity_cell_program_reads_its_kv_heads_in_place(one_chip_mesh,
     assert "ragged-dot" in text, "the grouped products left Mosaic"
     assert _mosaic_call_sites(text) == TRI_MOSAIC_SITES
 
-    # the loops: the K loop and the forward and backward scan of the
-    # three window expert layers; the one-layer segments need none.  What
+    # the loops: the K loop, the forward and backward scan of the three
+    # window expert layers, and since PR 37 the dispatch's walk forward
+    # and backward in the scans' bodies and, for the one full layer, in
+    # the K loop's (3 whiles at PR 36, 7 now).  What
     # is copied at q's size in them is q's own: XLA lays the 4-D `[2,
     # 8192, 32, 128]` the rotary step works on out head-major, so q is
     # re-laid out after it (forward, and rebuilt in the backward pass),
@@ -556,7 +608,7 @@ def test_trinity_cell_program_reads_its_kv_heads_in_place(one_chip_mesh,
     whiles, copies, splits = _whiles_and_stack_copies(
         text, 3, _split_shapes(b, length, heads, width) | {at_q})
     layer_loops = [body for body, in_entry in whiles if not in_entry]
-    assert len(layer_loops) == 2 and len(whiles) == 3, whiles
+    assert len(layer_loops) == 6 and len(whiles) == 7, whiles
     found = [c for body in layer_loops for c in copies.get(body, [])]
     assert not found, ("whole-stack copies once per layer:\n  "
                        + "\n  ".join(found))

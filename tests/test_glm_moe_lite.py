@@ -291,6 +291,118 @@ def test_no_token_is_dropped_when_every_pair_falls_in_the_held_range(
     assert np.abs(np.asarray(got) - want).max() <= TOL * np.abs(want).max()
 
 
+# pairs the two held experts get, of 1024 tokens at top-2 over 32 experts:
+# the walk's block is C = 512 rows of the 2048 no routing can overflow
+WALKS = {"no_pair_held": (0, 0), "exactly_one_block": (200, 312),
+         "one_pair_past_a_block": (200, 313),
+         "a_group_across_two_block_edges": (300, 900),
+         "every_pair_held": (1024, 1024)}
+# the meshes the walk runs on.  A rank of "dp" has 1024 tokens of its
+# own, so its own pairs and its own number of blocks, and holds the same
+# experts as the other; the ranks of "tp" share the tokens and split the
+# experts' hidden width
+SPLITS = {"one_device": {}, "dp2": {"dp": 2}, "tp2": {"tp": 2}}
+
+
+@pytest.mark.parametrize("split", sorted(SPLITS))
+@pytest.mark.parametrize("case", sorted(WALKS))
+def test_the_walk_takes_the_blocks_the_pairs_need(case, split):
+    """The dispatch walks ceil(pairs / C) blocks of the sorted pairs,
+    none where no pair is held and rows / C where every pair is: result
+    and gradients (tokens, weights, the three expert matrices) are a
+    plain loop's over the experts on one device, under `jax.checkpoint`
+    inside a `lax.scan` over layers as the LM runs it, and
+    `moe_rows_walked` says how many rows each layer walked.  The second
+    rank of "dp" routes as the NEXT case does, so the two walk
+    different numbers of blocks."""
+    n, first, layers = 1024, 2, 2
+    cfg = dataclasses.replace(program_config(HF), n_experts=32,
+                              expert_first=first)
+    rows, C = tf._dispatch_block(cfg, n)
+    assert (rows, C) == (2048, 512)
+    axes = dict({"dp": 1, "pp": 1, "tp": 1, "sp": 1, "ep": 1},
+                **SPLITS[split])
+    dp, tp = axes["dp"], axes["tp"]
+    mesh = create_mesh(axes, devices=jax.devices()[:dp * tp])
+    names = sorted(WALKS)
+    counts = [WALKS[names[(names.index(case) + r) % len(names)]]
+              for r in range(dp)]
+    rng = np.random.RandomState(10)
+    lw = {k: jnp.stack([v, v[::-1]]) for k, v in
+          _expert_layer_weights(cfg, rng, 2).items() if k.startswith("we_")}
+    # slot s of a token names held expert s for the first counts[r][s]
+    # tokens of a shuffle of its own, and an expert held elsewhere for
+    # the rest
+    idx = np.empty((layers, dp, n, 2), np.int32)
+    for layer, r in np.ndindex(layers, dp):
+        for slot, count in enumerate(counts[r]):
+            idx[layer, r, rng.permutation(n), slot] = np.where(
+                np.arange(n) < count, first + slot, 8 + slot)
+    idx = jnp.asarray(idx.reshape(layers, dp * n, 2))
+    w = jnp.asarray(rng.rand(layers, dp * n, 2) + 0.5, jnp.float32)
+    z = jnp.asarray(rng.randn(dp * n, 64), jnp.float32)
+    aim = jnp.asarray(rng.randn(dp * n, 64), jnp.float32)
+
+    def plain(cfg, x, idx, w, lw):
+        out = 0.0
+        for e in range(2):
+            y = (jax.nn.silu(x @ lw["we_g"][e]) * (x @ lw["we_u"][e])) \
+                @ lw["we_d"][e]
+            share = jnp.where(idx == first + e, w, 0.0).sum(1)
+            out = out + share[:, None] * y
+        return out, {"moe_rows_walked": jnp.zeros(())}
+
+    def run(experts, own, whole, z, aim, idx, w, lw):
+        """`own` marks a rank's inputs its own as the LM's activations
+        are (varying over every axis); `whole` is one copy of what the
+        ranks that share tokens all hold, summed over the named axes
+        besides."""
+        def loss(z, w, lw):
+            @jax.checkpoint
+            def layer(x, per_layer):
+                idx, w, lw = per_layer
+                f, stats = experts(cfg, x, idx, w, lw)
+                return x + f, (f, stats["moe_rows_walked"])
+
+            x, (f, walked) = jax.lax.scan(layer, own(z),
+                                          (own(idx), own(w), lw))
+            return whole((x * own(aim)).sum(), "dp"), (
+                whole(f), whole(walked)[None])
+
+        return jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)(
+            z, w, lw)
+
+    def whole(v, *summed):
+        return jax.lax.psum(v, ("pp", "tp", "sp", "ep") + summed) / tp
+
+    rank, pair = P("dp"), P(None, "dp")
+    lw_spec = {"we_g": P(None, None, None, "tp"),
+               "we_u": P(None, None, None, "tp"), "we_d": P(None, None, "tp")}
+    (_, (f, walked)), got = jax.jit(jax.shard_map(
+        lambda *a: run(tf._experts_grouped, tf._pvary_all, whole, *a),
+        mesh=mesh, in_specs=(rank, rank, pair, pair, lw_spec),
+        out_specs=((P(), (pair, rank)), (rank, pair, lw_spec))))(
+            z, aim, idx, w, lw)
+    (_, (f_want, _)), want = jax.jit(
+        lambda *a: run(plain, lambda v: v, lambda v, *_: v, *a))(
+            z, aim, idx, w, lw)
+    np.testing.assert_array_equal(
+        np.asarray(walked),
+        [[-(-sum(c) // C) * C] * layers for c in counts])
+    for a, b in zip(jax.tree_util.tree_leaves((f, got)),
+                    jax.tree_util.tree_leaves((f_want, want))):
+        a, b = np.asarray(a), np.asarray(b)
+        assert np.abs(a - b).max() <= TOL * np.abs(b).max()
+    for r, c in enumerate(counts):
+        if not sum(c):      # nothing but the residual path's gradient
+            own = slice(r * n, (r + 1) * n)
+            assert not np.asarray(f)[:, own].any()
+            assert not np.asarray(got[1])[:, own].any()
+    if not any(map(sum, counts)):
+        assert not any(np.asarray(a).any()
+                       for a in jax.tree_util.tree_leaves(got[2]))
+
+
 @pytest.mark.parametrize("optimizer", ["adam", "sgd"])
 def test_fused_k_steps_equal_k_sequential_steps(mesh, optimizer):
     cfg = program_config(HF)
