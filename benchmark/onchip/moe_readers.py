@@ -9,7 +9,9 @@ The routed experts' counters are the program's: ``mx.profiler`` stats
 fullest held expert's pairs in one layer of one step), which the driver
 publishes from the arrays EVERY program returns beside its losses, read
 after the first program and at the window's open and close: what the
-readers see is the first program and the whole window.
+readers see is the first program and the whole window.  The fourth,
+``moe_rows_walked``, is the rows the dispatch walked (blocks x the rows
+of a block, over the expert layers).
 """
 import harness
 import readers
@@ -28,6 +30,27 @@ def moe_pairs_per_token(run):
     if not s.get("moe_tokens"):
         return None
     return s["moe_pairs"] / float(s["moe_tokens"])
+
+
+def moe_blocks_per_layer_step(run):
+    """Blocks of the dispatch's walk per expert layer and step: the rows
+    walked over the rows of a block (the program's own rule,
+    ``_dispatch_block``), over the layer-steps routed.  A layer whose
+    held experts got no pair in a step walks none, so under 1 is the
+    share of layer-steps that held a pair at all; the cell's rate is a
+    line in this number."""
+    from mxtpu.parallel import transformer as tf
+
+    s = _stats()
+    cell = run["cell"]
+    if not s.get("moe_tokens") or "moe_rows_walked" not in s:
+        return None
+    n_tok = int(cell.traffic["batch"]) * cell.config["input"]["length"]
+    cfg = harness.load_module(
+        "drivers", cell.traffic["driver"]).transformer_config(cell.config)
+    _, block = tf._dispatch_block(cfg, n_tok)
+    return s["moe_rows_walked"] / float(block) \
+        / (s["moe_tokens"] / float(n_tok))
 
 
 def mfu_pct(run):

@@ -62,6 +62,13 @@ def test_run_is_correct_and_well_formed(cell):
     assert result["correct"] is True, result["compared"]
     assert result["device"]["platform"] == "cpu"    # never a chip's number
     assert "setup_s" in result["metrics"] and len(result["metrics"]) >= 2
+    # the rate goes under the one name the cell's traffic gives it
+    c = harness.Cell(harness.load_json(ROOT, "BENCHMARK.json"), cell)
+    rates = {harness.load_json(ONCHIP, "traffic", f)["rate_metric"]
+             for f in os.listdir(os.path.join(ONCHIP, "traffic"))}
+    # (which name that is, test_benchmark_json.py holds: glm's line has
+    # ``tok_per_s_routed`` and no ``tok_per_s``)
+    assert rates & set(result["metrics"]) == {c.traffic["rate_metric"]}
     # (the CPU client reports no memory, so that one metric reads 0 here)
     assert all(v["value"] > 0 for k, v in result["metrics"].items()
                if k != "peak_hbm_gib")
@@ -115,6 +122,31 @@ def test_chip_readings_stand_on_the_right_side_of_the_limits(cell):
             assert not compare.verdict(row["numbers"], doc)[0], (kind, row)
     for row in chip["bf16"]:
         assert compare.verdict(row["numbers"], doc)[0], row
+
+
+@pytest.mark.parametrize("cell,block,blocks", [
+    ("glm47f_ep8_fused_k4", 8192, 0.75),
+    ("ling3fvl_ep64_fused_k4", 1024, 1.0),
+    ("trinitym_ep16_fused_k4", 16384, 1.25)])
+def test_blocks_per_layer_step_from_the_programs_counters(
+        cell, block, blocks, monkeypatch):
+    """``moe_rows_walked`` is blocks x the rows of a block; the reader
+    takes the block from the program's own rule at the cell's real
+    sizes (PERF.md has the three); without the counter it is silent."""
+    import moe_readers
+
+    c = harness.Cell(harness.load_json(ROOT, "BENCHMARK.json"), cell)
+    name, = [m["name"] for m in c.per_layer
+             if m["name"].startswith("moe_blocks_per_layer_step.")]
+    read = harness.load_module("metrics", name).read
+    n_tok = int(c.traffic["batch"]) * c.config["input"]["length"]
+    layer_steps = 5 * 36
+    stats = {"moe_tokens": n_tok * layer_steps,
+             "moe_rows_walked": int(blocks * layer_steps) * block}
+    monkeypatch.setattr(moe_readers, "_stats", lambda: stats)
+    assert read({"cell": c}) == pytest.approx(blocks)
+    del stats["moe_rows_walked"]
+    assert read({"cell": c}) is None
 
 
 def test_traced_run_reports_per_layer_metrics():

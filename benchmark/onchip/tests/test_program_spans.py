@@ -139,7 +139,7 @@ def test_a_program_without_spans_reads_none(monkeypatch):
 def test_the_new_metrics_are_additions_for_the_fused_cell():
     bench = harness.load_json(ROOT, "BENCHMARK.json")
     by_name = {m["name"]: m for m in bench["per_layer"]}
-    assert [m["name"] for m in bench["per_layer"]][-3:] == list(NEW)
+    # present wherever they stand: later PRs append their own entries
     for name in NEW:
         m = by_name[name]
         assert (m["unit"], m["better"], m["source"], m["moves"]) \
