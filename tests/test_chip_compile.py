@@ -372,8 +372,10 @@ def test_glm_cell_program_fits_one_chip_and_keeps_its_kernels(
 
 # arguments + temporaries: 18 143 063 040 at PR 34, 18 056 681 472 since
 # PR 37 (the dispatch's [32 768, 2560] buffers went; the KDA layers'
-# temporaries set this program's peak)
-LING_DESCRIBED = 18056681472
+# temporaries set this program's peak), 17 731 018 240 since the chunk's
+# triangular systems are solved by a blocked inverse that the backward
+# pass applies transposed (`transformer._unit_lower_solve`)
+LING_DESCRIBED = 17731018240
 LING_MOSAIC_SITES = 33      # 3 flash kernels + XLA's grouped products
 # the K loop, the five KDA + expert layers' two scans, the chunk scans
 # of the KDA core (forward, rebuilt, backward) and, since PR 37 (9
@@ -480,6 +482,9 @@ def test_ling_cell_program_compiles_for_one_chip_and_keeps_its_kernels(
     assert "ragged-dot" in text, "the grouped products left Mosaic"
     assert _mosaic_call_sites(text) == LING_MOSAIC_SITES
     assert len(_whiles_and_stack_copies(text, 5)[0]) == LING_WHILES
+    # the KDA chunk's triangular systems are solved by products: no
+    # call of the TPU's sequential triangular inversion is left
+    assert "InvertDiagBlocksLowerTriangular" not in text
     tokens = traffic["batch"] * config["input"]["length"]
     rows = tokens * config["num_experts_per_tok"]
     from mxtpu.parallel import transformer as tf

@@ -104,10 +104,16 @@ def _program_loss_and_grads(cfg, mesh, params, tokens, labels):
 def _core_inputs(t, d, g_kind, seed=7):
     rng = np.random.RandomState(seed)
     q, k, v = (rng.randn(2, t, 3, d).astype(np.float32) for _ in range(3))
+    if g_kind == "aligned_slow":        # 0.9 of each key one direction
+        k = 0.9 * k[:, :1] / np.linalg.norm(k[:, :1], axis=-1,
+                                            keepdims=True) \
+            + 0.1 * k / d ** 0.5
     q /= np.linalg.norm(q, axis=-1, keepdims=True) * d ** 0.5
     k /= np.linalg.norm(k, axis=-1, keepdims=True)
     if g_kind == "at_the_floor":        # every step's log-decay -5
         g = np.full((2, t, 3, d), -5.0, np.float32)
+    elif g_kind == "aligned_slow":      # log-decays in (-0.01, 0)
+        g = rng.uniform(-0.01, 0.0, (2, t, 3, d))
     else:
         g = -5.0 / (1.0 + np.exp(-3.0 * rng.randn(2, t, 3, d)))
     beta = 1.0 / (1.0 + np.exp(-rng.randn(2, t, 3)))
@@ -120,8 +126,9 @@ def _core_inputs(t, d, g_kind, seed=7):
     (70, 64, 16, "at_the_floor", "by_matrices"),    # exp(80) in a span
     (150, 64, 16, "mixed", "by_matrices"),
     (37, 16, 4, "mixed", "benchmark_reference"),
+    (150, 64, 16, "aligned_slow", "by_matrices"),   # a series would fail
 ], ids=["whole_chunks", "ragged_length", "decays_at_the_floor",
-        "cell_chunking", "benchmark_reference"])
+        "cell_chunking", "benchmark_reference", "aligned_slow"])
 def test_chunked_kda_matches_the_recurrence(mesh, t, chunk, rebase, g_kind,
                                             against):
     """Forward and every input's gradient of `_kda_chunked` against the
@@ -163,6 +170,71 @@ def test_chunked_kda_matches_the_recurrence(mesh, t, chunk, rebase, g_kind,
     assert 0 < float(span) <= rebase * 5.0 * (1 + 1e-6)
     if g_kind == "at_the_floor":
         assert float(span) == pytest.approx(rebase * 5.0)
+
+
+def _chunk_system(c, case, seed=3, n=3, d=32):
+    """(A, rhs) as `_kda_chunked` builds them for one chunk of c rows:
+    beta 0.99, unit keys, per-channel decays.  `case`: independent keys
+    under fast decays; keys half or 0.9 along one direction under slow
+    ones; one key repeated with no decay."""
+    rng = np.random.RandomState(seed)
+    k = rng.randn(n, c, d)
+    share, low = {"independent": (0.0, -5.0), "half_aligned": (0.5, -0.05),
+                  "aligned_slow": (0.9, -0.01),
+                  "identical_no_decay": (1.0, 0.0)}[case]
+    u = rng.randn(n, 1, d)
+    k = share * u / np.linalg.norm(u, axis=-1, keepdims=True) \
+        + (1.0 - share) * k / d ** 0.5
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    G = np.cumsum(rng.uniform(low, 0.0, (n, c, d)), 1)
+    A = np.tril(0.99 * np.einsum("nrd,nid->nri", k * np.exp(G),
+                                 k * np.exp(-G)), -1)
+    v = rng.randn(n, c, d)
+    rhs = 0.99 * np.concatenate([k * np.exp(G), v], -1)
+    return A, rhs
+
+
+@pytest.mark.parametrize("case", ["independent", "half_aligned",
+                                  "aligned_slow", "identical_no_decay"])
+@pytest.mark.parametrize("c", [16, 64, 33])    # 33: padded to 4 x 9 rows
+def test_unit_lower_solve_matches_the_triangular_solve(c, case):
+    """`_unit_lower_solve` (the blocked inverse by products) against
+    `solve_triangular` and float64 `numpy.linalg.solve`, and both its
+    gradients against jax's own VJP of `solve_triangular`, on systems
+    whose keys align under slow decay (where a series in A cancels terms
+    of up to C(63, 31) in float32)."""
+    from mxtpu import profiler
+
+    A64, rhs64 = _chunk_system(c, case)
+    want = np.linalg.solve(np.eye(c) + A64, rhs64)
+    A, rhs = (jnp.asarray(a, jnp.float32) for a in (A64, rhs64))
+    cot = jnp.asarray(np.random.RandomState(5).randn(*rhs.shape),
+                      jnp.float32)
+
+    def triangular(A, rhs):
+        return jax.scipy.linalg.solve_triangular(
+            A + jnp.eye(c, dtype=A.dtype), rhs, lower=True,
+            unit_diagonal=True)
+
+    def value_and_grads(solve):
+        return jax.jit(jax.value_and_grad(
+            lambda A, rhs: (solve(A, rhs) * cot).sum(), argnums=(0, 1)))
+
+    before = profiler.get_stat("kda_solves_blocked")
+    got = np.asarray(jax.jit(lambda *a: tf._unit_lower_solve(*a))(A, rhs))
+    assert profiler.get_stat("kda_solves_blocked") == before + 1
+    scale = np.abs(want).max()
+    assert np.abs(got - want).max() <= 1e-5 * scale
+    assert np.abs(got - np.asarray(triangular(A, rhs))).max() <= 1e-5 * scale
+
+    _, (dA, drhs) = value_and_grads(tf._unit_lower_solve)(A, rhs)
+    _, (want_dA, want_drhs) = value_and_grads(triangular)(A, rhs)
+    below = np.tril(np.ones((c, c), bool), -1)
+    assert not np.asarray(dA)[..., ~below].any()
+    for got, w in ((np.asarray(dA)[..., below],
+                    np.asarray(want_dA)[..., below]),
+                   (np.asarray(drhs), np.asarray(want_drhs))):
+        assert np.abs(got - w).max() <= 1e-5 * np.abs(w).max()
 
 
 # ---------------------------------------------------------------------------
