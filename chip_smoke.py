@@ -99,8 +99,11 @@ class _Sizes(object):
             # sequence), against attention in blocks of query rows
             self.attn_grouped = (2, 8192, 32, 4, 128, 2048)
             # (batch, T, heads, d, chunk, re-basing): the chunked KDA
-            # core against the recurrence, at the published head
-            self.kda = (1, 2048, 4, 128, 64, 16)
+            # core against the recurrence, at the published head, with
+            # the bounded gate re-based every 16 steps and with the
+            # unbounded softplus gate (None: scored by halves)
+            self.kda = [(1, 2048, 4, 128, 64, 16),
+                        (1, 2048, 4, 128, 64, None)]
             self.lm = dict(vocab=8192, d_model=1024, n_heads=8,
                            n_layers=8, d_ff=4096, max_len=1024)
             self.lm_batch, self.lm_k = 8, 2
@@ -113,7 +116,7 @@ class _Sizes(object):
             self.attn_bthd = [((4, 128, 64), 2), ((2, 128, 256), 2)]
             self.attn_unequal = (1, 128, 2, 24, 16)
             self.attn_grouped = (1, 256, 4, 2, 128, 100)
-            self.kda = (1, 40, 2, 16, 16, 4)
+            self.kda = [(1, 40, 2, 16, 16, 4), (1, 40, 2, 16, 16, None)]
             self.lm = dict(vocab=64, d_model=32, n_heads=2, n_layers=2,
                            d_ff=64, max_len=64)
             self.lm_batch, self.lm_k = 4, 2
@@ -577,17 +580,27 @@ def _kda_check(shape):
     """The chunked KDA core (`transformer._kda_chunked`, bfloat16
     operands in its products, float32 decays, solve and state) against
     the gated delta rule run one token at a time in float32; forward
-    and every input's gradient.  Returns (worst error, the largest
-    re-based span's nats)."""
+    and every input's gradient.  Re-based (`shape`'s last entry a
+    span): decays over the floor gate's range (-5, 0), some channels at
+    the floor, beta in (0, 1).  Scored by halves (None): the softplus
+    gate's unbounded decays, -exp(A) softplus(z) with A in (0, log 16),
+    some channels at tens of nats a step, and beta in (0, 2).  Returns
+    (worst error, the largest re-based span's or chunk's nats)."""
     b, t, h, d, chunk, rebase = shape
     rng = np.random.RandomState(d + t)
     q, k, v = (rng.normal(0, 1, (b, t, h, d)) for _ in range(3))
     q /= np.linalg.norm(q, axis=-1, keepdims=True) * d ** 0.5
     k /= np.linalg.norm(k, axis=-1, keepdims=True)
-    # decays over the gate's whole range, some channels at the floor
-    g = -5.0 / (1.0 + np.exp(-4.0 * rng.normal(0, 1, (b, t, h, d))))
-    g[..., :2] = -5.0
-    beta = 1.0 / (1.0 + np.exp(-rng.normal(0, 1, (b, t, h))))
+    z = rng.normal(0, 1, (b, t, h, d))
+    if rebase:      # decays over the gate's whole range, some at the floor
+        g = -5.0 / (1.0 + np.exp(-4.0 * z))
+        g[..., :2] = -5.0
+        beta = 1.0 / (1.0 + np.exp(-rng.normal(0, 1, (b, t, h))))
+    else:
+        g = -np.exp(rng.uniform(0, np.log(16), (1, 1, h, 1))) \
+            * np.logaddexp(2.0 * z - 2.0, 0.0)
+        g[..., :2] *= 10.0
+        beta = 2.0 / (1.0 + np.exp(-rng.normal(0, 1, (b, t, h))))
     args = tuple(jnp.asarray(a, jnp.float32) for a in (q, k, v, g, beta))
     cot = jnp.asarray(rng.normal(0, 1, (b, t, h, d)), jnp.float32)
     hi = jax.lax.Precision.HIGHEST
@@ -662,9 +675,12 @@ def phase3_lm(sizes, meter):
           % info["flash_ms_per_call_now_and_parent"], flush=True)
     info["attn_unequal_widths_err"] = round(
         _unequal_width_check(sizes.attn_unequal), 5)
-    err, span = _kda_check(sizes.kda)
+    err, span = _kda_check(sizes.kda[0])
     info["kda_chunked_err"], info["kda_span_nats"] = round(err, 5), \
         round(span, 2)
+    err, span = _kda_check(sizes.kda[1])
+    info["kda_softplus_err"], info["kda_softplus_chunk_nats"] = \
+        round(err, 5), round(span, 2)
     expanded = stats0.get("flash_kv_expanded", 0)
     for window in (sizes.attn_grouped[-1], None):
         err, ms = _grouped_band_check(sizes.attn_grouped, window,

@@ -34,7 +34,8 @@ from .ring_attention import ring_attention, _match_vma, _pallas_enabled
 __all__ = ["TransformerConfig", "init_params", "param_specs",
            "make_train_step", "make_fused_train_steps", "make_forward",
            "dryrun", "init_opt_state", "param_shapes", "MOE_STATS",
-           "GROUP_STATS", "KDA_STATS", "publish_moe_stats"]
+           "GROUP_STATS", "KDA_STATS", "KDA_NEG_STATS",
+           "publish_moe_stats"]
 
 _NEG_INF = -1e30
 # params below this element count keep replicated optimizer state
@@ -133,7 +134,8 @@ class TransformerConfig:
 
     # ---- a stack of TWO mixer kinds (Kimi Linear's hybrid): with
     # kda_period = p > 0 the layer of published index i runs `attention`
-    # where (i + 1) % p == 0 and Kimi Delta Attention (`_kda`) otherwise.
+    # where i % p == kda_attn_at % p and Kimi Delta Attention (`_kda`)
+    # otherwise.
     # Each run of layers of one (mixer, ffn) kind is a segment; layers of
     # one kind share stacked leaves: KDA layers are "kda.<leaf>" (leading
     # dense ones "dense.kda.<leaf>")
@@ -151,6 +153,20 @@ class TransformerConfig:
     kda_rebase: int = 16       # ... and the steps after which the
     # cumulative log-decay is re-based: a span's rebase * -floor nats
     # (80) are split about its middle, so `exp` sees +-40 at most
+    kda_attn_at: int = -1      # the attention layer's place in a period:
+    # -1 its last (Kimi Linear's, Ling's), 0 its first (Solar Open 2's)
+    kda_gate: str = "floor"    # the log-decay's form.  "floor": above,
+    # bounded below by kda_gate_floor, the chunks re-based every
+    # kda_rebase steps.  "softplus": -exp(A_log) * softplus(logit +
+    # dt_bias), with NO lower bound: the chunks' scores are based at the
+    # boundaries of halves (`_scores_by_halves`), exact for any decay;
+    # kda_chunk a power of two, kda_gate_floor and kda_rebase unused
+    kda_gate_rank: int = 0     # 0: the decay's logits from one full
+    # matrix ("wa"), the output gated per head ("w_gate").  r > 0: both
+    # through a rank-r bottleneck ("f_a" / "f_b", "g_a" / "g_b"), the
+    # output gate elementwise over each head's values
+    kda_neg_eigval: bool = False    # the write strength 2 sigmoid(x Wb)
+    # in (0, 2): transitions I - beta k k^T with eigenvalues down to -1
     qk_norm: bool = False      # mla: q_nope and k_nope RMS-normed per
     # head, one [qk_nope_dim] scale each; gqa: q and k, one [head_dim]
     # scale each
@@ -170,7 +186,9 @@ class TransformerConfig:
     # prefix
     full_period: int = 0       # 0 with a window: every layer slides
     rope_full: bool = True     # False: the full layers carry NO
-    # positions (the window layers' rotary ones give the order)
+    # positions (the window layers' rotary ones give the order); with
+    # no window every layer is a full one and the model has no
+    # positions at all (NoPE)
     out_gate: bool = False     # gqa: the merged attention output times
     # sigmoid(x W_gate), elementwise, before the out product
     post_norms: bool = False   # an RMSNorm AFTER each sub-block too
@@ -201,12 +219,13 @@ class TransformerConfig:
                 or self.window < 0 or self.full_period < 0
                 or (self.full_period and not self.window)
                 or self.full_period == 1
-                or (not self.rope_full and not self.full_period)
-                or self.kda_period):
+                or (not self.rope_full and self.window
+                    and not self.full_period)
+                or (self.kda_period and self.window)):
             bad = ("attention='gqa' needs n_kv_heads dividing n_heads, an "
                    "even head_dim, full_period >= 2 only with a window, "
-                   "rope_full=False only with a full_period, and no "
-                   "kda_period")
+                   "rope_full=False beside a window only with a "
+                   "full_period, and no window beside a kda_period")
         elif self.attention == "mla" and (
                 min(self.kv_lora_rank, self.qk_nope_dim,
                     self.v_head_dim) < 1 or self.q_lora_rank < 0
@@ -220,14 +239,26 @@ class TransformerConfig:
                    "attention's and 'gqa's")
         elif self.kda_period and (
                 self.kda_period < 2 or self.kda_chunk < 1
-                or self.kda_rebase < 1 or self.kda_chunk % self.kda_rebase
-                or not 0 < -self.kda_gate_floor * self.kda_rebase <= 80
-                or self.kda_conv < 1
+                or self.kda_gate not in ("floor", "softplus")
+                or (self.kda_gate == "floor" and (
+                    self.kda_rebase < 1 or self.kda_chunk % self.kda_rebase
+                    or not 0 < -self.kda_gate_floor * self.kda_rebase
+                    <= 80))
+                or (self.kda_gate == "softplus"
+                    and self.kda_chunk & (self.kda_chunk - 1))
+                or self.kda_gate_rank < 0 or self.kda_conv < 1
                 or (self.kda_head_dim or self.d_model // self.n_heads) < 1):
-            bad = ("kda_period needs a period >= 2, kda_chunk a multiple "
-                   "of kda_rebase, and -kda_gate_floor * kda_rebase in "
-                   "(0, 80]: exp() of a re-based span must stay finite "
-                   "in float32")
+            bad = ("kda_period needs a period >= 2 and kda_gate 'floor' "
+                   "or 'softplus'; 'floor' needs kda_chunk a multiple of "
+                   "kda_rebase and -kda_gate_floor * kda_rebase in (0, "
+                   "80] (exp() of a re-based span must stay finite in "
+                   "float32), 'softplus' a kda_chunk that is a power of "
+                   "two")
+        elif not self.kda_period and (
+                self.kda_attn_at != -1 or self.kda_gate != "floor"
+                or self.kda_gate_rank or self.kda_neg_eigval):
+            bad = ("kda_attn_at, kda_gate, kda_gate_rank and "
+                   "kda_neg_eigval are a kda_period's")
         elif self.layer_ids and (
                 len(self.layer_ids) != self.n_layers
                 or list(self.layer_ids) != sorted(set(self.layer_ids))
@@ -306,7 +337,8 @@ def _segments(cfg: TransformerConfig):
     segs, rows = [], {}
     for j, i in enumerate(ids):
         lead = j < cfg.n_dense_layers
-        kda = bool(cfg.kda_period) and (i + 1) % cfg.kda_period != 0
+        kda = bool(cfg.kda_period) and \
+            (i - cfg.kda_attn_at) % cfg.kda_period != 0
         full = bool(cfg.full_period) and (i + 1) % cfg.full_period == 0
         tag = "kda" if kda else "full" if full else ""
         prefix = ("dense." if lead else "") + (tag and tag + ".")
@@ -349,14 +381,21 @@ def _layer_leaves(cfg: TransformerConfig, kind: str):
         for n in ("q", "k", "v"):
             out["w" + n] = ((E, H * d), col, E)
             out["conv_" + n] = ((cfg.kda_conv, H * d), col, cfg.kda_conv)
+        r = cfg.kda_gate_rank
+        if r:       # the decay's logits and the output gate, low-rank
+            for n in ("f", "g"):
+                out[n + "_a"] = ((E, r), (None, None), E)
+                out[n + "_b"] = ((r, H * d), col, r)
+        else:
+            out["wa"] = ((E, H * d), col, E)     # the decay gate, full
         out.update({
-            "wa": ((E, H * d), col, E),          # the decay gate, full
             "A_log": ((H,), (AXIS_TP,), "A_log"),
             "dt_bias": ((H * d,), (AXIS_TP,), "dt_bias"),
-            "wb": ((E, H), col, E),              # write strength
-            "w_gate": ((E, H), col, E),          # the output's head gate
-            "o_norm": ((d,), (None,), None),
-            "wo": ((H * d, E), row, H * d)})
+            "wb": ((E, H), col, E)})             # write strength
+        if not r:
+            out["w_gate"] = ((E, H), col, E)     # the output's head gate
+        out.update({"o_norm": ((d,), (None,), None),
+                    "wo": ((H * d, E), row, H * d)})
     elif mixer == "mla":
         ql, kvl = cfg.q_lora_rank, cfg.kv_lora_rank
         dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
@@ -456,8 +495,11 @@ MOE_STATS = ("moe_pairs", "moe_tokens", "moe_load_max", "moe_rows_walked")
 GROUP_STATS = ("moe_groups_kept_here",)
 # per step, over every KDA layer: tokens mixed; chunks scanned; the
 # largest |cumulative log-decay| of any re-based span, in nats (what
-# `exp` is given: 80 is the config's bound, 88.7 float32's cliff)
+# `exp` is given: 80 is the config's bound, 88.7 float32's cliff), or
+# with the unbounded gate of any chunk (how far past 88.7 the chunks went)
 KDA_STATS = ("kda_tokens", "kda_chunks", "kda_decay_span_max")
+# with negative eigenvalues allowed: (token, head) write strengths over 1
+KDA_NEG_STATS = ("kda_neg_beta",)
 _WATERMARKS = ("moe_load_max", "kda_decay_span_max")    # max, not sum
 
 
@@ -472,7 +514,7 @@ def _stat_names(cfg: TransformerConfig) -> Tuple[str, ...]:
         names += MOE_STATS + (GROUP_STATS if cfg.n_group > 1 else ())
     if any(_kind_parts(cfg, kind)[0] == "kda"
            for _, kind, _, _ in _segments(cfg)):
-        names += KDA_STATS
+        names += KDA_STATS + (KDA_NEG_STATS if cfg.kda_neg_eigval else ())
     return names
 
 
@@ -739,6 +781,14 @@ def _gqa(cfg, x, lw, tp_size, rope, window):
     return jax.lax.psum(_kept(o @ lw["wo"]), AXIS_TP)
 
 
+def _rotary(cfg):
+    """Whether any layer rotates: latent attention's rope dims always,
+    "gqa"'s heads on its window layers and, with `rope_full`, its full
+    ones; "mha" has a position table."""
+    return cfg.attention == "mla" or (
+        cfg.attention == "gqa" and (cfg.rope_full or bool(cfg.window)))
+
+
 def _rotary_table(cfg, positions):
     """(cos, sin), float32 [T, width / 2], of the global `positions`:
     angle = position * rope_theta ** (-2i / width), the width being
@@ -944,6 +994,96 @@ def _f32_dot(spec, a, b):
                       preferred_element_type=jnp.float32)
 
 
+def _scores_rebased(k, q, G, rebase, einsum):
+    """The chunk's scores [..., 2, C, C] float32, (k_r . k_i, q_r . k_i)
+    under exp(G_r - G_i) per channel, for a log-decay bounded below
+    (`kda_gate` "floor"), and the largest |log-decay| summed over a
+    re-based span.  k, q, G: [..., C, d], G the cumulative log-decay.
+    exp(G_r - G_i) is a product of a row factor and a column factor, and
+    exp(-G_i) alone passes float32 within a chunk (e^320 at 64 steps of
+    -5), so the cumulative decay is RE-BASED every `rebase` steps: rows
+    of sub-block a take exp(G_r - R_a) and columns exp(R_a - G_i), R_a
+    being G at the middle of the span the sub-block covers: with a span
+    of at most 80 nats both lie within e^-40 .. e^40 inside the
+    sub-block, and a column before it under e^-40.  Entries above the
+    diagonal are left for the caller to mask."""
+    import jax
+    import jax.numpy as jnp
+
+    C, d = G.shape[-2:]
+    R = rebase
+    n = C // R
+    Gs = G.reshape(G.shape[:-2] + (n, R, d))
+    # the base of sub-block a, [..., n, d]: the MIDDLE of the span its
+    # rows' G covers, so that a row factor and a column factor of the
+    # sub-block each lie within e^-40 .. e^40 and no product of one with
+    # a cotangent leaves float32's normal range (based at the span's
+    # start, a row factor of e^-80 times a cotangent flushed to nought in
+    # the backward pass).  No gradient through it: exp(G_r - R_a)
+    # exp(R_a - G_i) does not depend on R_a
+    start = jnp.concatenate(
+        [jnp.zeros_like(Gs[..., :1, 0, :]), Gs[..., :-1, R - 1, :]], -2)
+    span = jax.lax.stop_gradient(start - Gs[..., R - 1, :])
+    base = jax.lax.stop_gradient(start) - 0.5 * span
+    span = span.max()
+    rows = jnp.exp(Gs - base[..., None, :])     # exp(G_r - R_a)
+    # columns against sub-block a's base, up to a's own end; a later
+    # column is never under a row of a: its factor is nought
+    seen = jnp.arange(C)[None] < (jnp.arange(n)[:, None] + 1) * R
+    cols = jnp.exp(jnp.where(
+        seen[..., None], base[..., None, :] - G[..., None, :, :],
+        -jnp.inf))                              # [..., n, C, d]
+    left = jnp.stack([k.reshape(Gs.shape) * rows,
+                      q.reshape(Gs.shape) * rows], -3)
+    scores = einsum("zbhasrd,zbhacd->zbhsarc", left,
+                    k[..., None, :, :] * cols)
+    return scores.reshape(G.shape[:-2] + (2, C, C)), span
+
+
+def _scores_by_halves(k, q, G, einsum):
+    """`_scores_rebased`'s scores for a log-decay with NO lower bound
+    (`kda_gate` "softplus"), exact and finite in float32 for any decay,
+    and the largest |log-decay| summed over a chunk.  C a power of two.
+
+    Every exponent is at or below 0.  The chunk is cut in halves,
+    quarters, ... down to single rows: the block of a pair of halves of
+    m rows each below the diagonal (rows in the lower half, columns in
+    the upper) is based at G of the upper half's LAST row, b: rows take
+    exp(G_r - G_b) and columns exp(G_b - G_i), each at most 1, their
+    product exp(G_r - G_i) (a factor that falls under float32's range
+    makes the product 0, which is what the true value rounds to).  A
+    level's blocks are one batched product, and the levels are merged by
+    halves as `_unit_lower_inverse` merges: [[D1, 0], [off, D2]].  The
+    diagonal is (k_r . k_r, q_r . k_r) itself; above it, nought."""
+    import jax
+    import jax.numpy as jnp
+
+    C, d = G.shape[-2:]
+    lead = G.shape[:-2]
+    M = (jnp.stack([k, q], -3) * k[..., None, :, :]).sum(-1)[
+        ..., None, None]                        # [..., 2, C, 1, 1]
+    m = 1
+    while m < C:
+        def halves(a):      # [..., C, d] -> [..., C / 2m, 2, m, d]
+            return a.reshape(lead + (C // (2 * m), 2, m, d))
+
+        Gp, kp, qp = halves(G), halves(k), halves(q)
+        base = jax.lax.stop_gradient(Gp[..., 0, m - 1:, :])
+        rows = jnp.exp(Gp[..., 1, :, :] - base)
+        cols = jnp.exp(base - Gp[..., 0, :, :])
+        off = einsum("zbhsjrd,zbhjid->zbhsjri",
+                     jnp.stack([kp[..., 1, :, :] * rows,
+                                qp[..., 1, :, :] * rows], -4),
+                     kp[..., 0, :, :] * cols)   # [..., 2, C / 2m, m, m]
+        pairs = M.reshape(M.shape[:-3] + (C // (2 * m), 2, m, m))
+        D1, D2 = pairs[..., 0, :, :], pairs[..., 1, :, :]
+        M = jnp.concatenate([
+            jnp.concatenate([D1, jnp.zeros_like(D1)], -1),
+            jnp.concatenate([off, D2], -1)], -2)
+        m *= 2
+    return M[..., 0, :, :], jax.lax.stop_gradient(-G[..., -1, :]).max()
+
+
 def _kda_chunked(q, k, v, g, beta, chunk, rebase, dtype):
     """The gated delta rule with a per-channel decay (Kimi Delta
     Attention, arXiv:2510.26692), in its CHUNKED form.  Per head, with
@@ -953,8 +1093,10 @@ def _kda_chunked(q, k, v, g, beta, chunk, rebase, dtype):
               + beta_t k_t v_t^T,          o_t = S_t^T q_t.
 
     q, k, v, g: [B, T, h, d] float32 (q and k as the recurrence takes
-    them, g the log-decay, < 0); beta: [B, T, h].  Returns (o [B, T, h,
-    d] float32, the largest |log-decay| summed over a re-based span).
+    them, g the log-decay, <= 0); beta: [B, T, h] (in (0, 2) where the
+    model allows negative eigenvalues).  Returns (o [B, T, h, d]
+    float32, the largest |log-decay| summed over a re-based span, or
+    with `rebase` None over a chunk).
 
     A chunk is C = `chunk` tokens.  With G the cumulative log-decay
     inside the chunk and S_0 the state the chunk is handed, the rows u_r
@@ -972,13 +1114,11 @@ def _kda_chunked(q, k, v, g, beta, chunk, rebase, dtype):
 
     Everything before S_0 is made for all chunks at once (scope
     `chunk`); a `lax.scan` hands the state from chunk to chunk (scope
-    `state`).  exp(G_r - G_i) is a product of a row factor and a column
-    factor, and exp(-G_i) alone passes float32 within a chunk (e^320 at
-    64 steps of -5), so the cumulative decay is RE-BASED every `rebase`
-    steps: rows of sub-block a take exp(G_r - R_a) and columns exp(R_a -
-    G_i), R_a being G at the middle of the span the sub-block covers:
-    with a span of at most 80 nats both lie within e^-40 .. e^40 inside
-    the sub-block, and a column before it under e^-40.
+    `state`).  Of these only the scores exp(G_r - G_i) need a base:
+    every other factor's exponent is at most 0.  With a `rebase` (a
+    decay bounded below) they are re-based every `rebase` steps
+    (`_scores_rebased`); with None (no bound) based at the boundaries
+    of halves (`_scores_by_halves`, C a power of two).
 
     The system is solved by (I + A)^-1 built in matrix products
     (`_unit_lower_solve`): diagonal blocks inverted by forward
@@ -994,8 +1134,7 @@ def _kda_chunked(q, k, v, g, beta, chunk, rebase, dtype):
 
     f32 = jnp.float32
     B, T, h, d = q.shape
-    C, R = chunk, rebase
-    n = C // R
+    C = chunk
     pad = (-T) % C
     N = (T + pad) // C
 
@@ -1010,30 +1149,8 @@ def _kda_chunked(q, k, v, g, beta, chunk, rebase, dtype):
     q, k, v, g, beta = (blocks(a) for a in (q, k, v, g, beta[..., None]))
     with jax.named_scope("chunk"):
         G = jnp.cumsum(g, axis=3)                   # [N, B, h, C, d], <= 0
-        Gs = G.reshape(N, B, h, n, R, d)
-        # the base of sub-block a, [N, B, h, n, d]: the MIDDLE of the
-        # span its rows' G covers, so that a row factor and a column
-        # factor of the sub-block each lie within e^-40 .. e^40 and no
-        # product of one with a cotangent leaves float32's normal range
-        # (based at the span's start, a row factor of e^-80 times a
-        # cotangent flushed to nought in the backward pass).  No gradient
-        # through it: exp(G_r - R_a) exp(R_a - G_i) does not depend on R_a
-        start = jnp.concatenate(
-            [jnp.zeros_like(Gs[..., :1, 0, :]), Gs[..., :-1, R - 1, :]], -2)
-        span = jax.lax.stop_gradient(start - Gs[..., R - 1, :])
-        base = jax.lax.stop_gradient(start) - 0.5 * span
-        span = span.max()
-        rows = jnp.exp(Gs - base[..., None, :])     # exp(G_r - R_a)
-        # columns against sub-block a's base, up to a's own end; a later
-        # column is never under a row of a: its factor is nought
-        seen = jnp.arange(C)[None] < (jnp.arange(n)[:, None] + 1) * R
-        cols = jnp.exp(jnp.where(
-            seen[..., None], base[..., None, :] - G[..., None, :, :],
-            -jnp.inf))                              # [N, B, h, n, C, d]
-        left = jnp.stack([k.reshape(Gs.shape) * rows,
-                          q.reshape(Gs.shape) * rows], -3)
-        scores = einsum("zbhasrd,zbhacd->zbhsarc", left,
-                        k[..., None, :, :] * cols).reshape(N, B, h, 2, C, C)
+        scores, span = _scores_rebased(k, q, G, rebase, einsum) if rebase \
+            else _scores_by_halves(k, q, G, einsum)
         below = jnp.arange(C)[:, None] - jnp.arange(C)[None]
         A = jnp.where(below > 0, scores[..., 0, :, :], 0.0) * beta
         Bm = jnp.where(below >= 0, scores[..., 1, :, :], 0.0)
@@ -1062,18 +1179,35 @@ def _kda_chunked(q, k, v, g, beta, chunk, rebase, dtype):
     return o, span
 
 
+def _low_rank(x, a, b):
+    """(x A) B, float32 [B, T, n]: a gate's logits through a rank-r
+    bottleneck, each product on x's type accumulated in float32.  Under
+    "dots" the bottleneck's r-wide output is kept and the up product
+    rebuilt from it."""
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    mid = _kept(jnp.einsum("bte,er->btr", x, a, preferred_element_type=f32))
+    return jnp.einsum("btr,rn->btn", mid.astype(x.dtype), b,
+                      preferred_element_type=f32)
+
+
 def _kda(cfg, x, lw):
     """Kimi Delta Attention (arXiv:2510.26692) as one mixer.  x: [B, T,
     E] (sp = tp = 1).  q, k, v each through a product, a causal
     depthwise convolution of `kda_conv` taps and SiLU; q and k
     L2-normed per head (q times d ** -0.5 besides); a log-decay per
-    head AND channel from one full matrix, kda_gate_floor * sigmoid(
-    exp(A_log) * (x Wa + dt_bias)), so one step's decay lies in (e^
-    floor, 1); a write strength per head, sigmoid(x Wb); the gated delta
-    rule in its chunked form (`_kda_chunked`); then per head an RMSNorm
-    over its d values and a sigmoid gate from x, and the out product.
-    Device scopes `conv`, `gate`, `chunk`, `state`, `out`.  Returns (y
-    [B, T, E], stats: `KDA_STATS`)."""
+    head AND channel, `kda_gate` "floor": kda_gate_floor * sigmoid(
+    exp(A_log) * (logit + dt_bias)), so one step's decay lies in (e^
+    floor, 1), or "softplus": -exp(A_log) * softplus(logit + dt_bias),
+    unbounded; the logits from one full matrix, or through a rank-
+    `kda_gate_rank` bottleneck; a write strength per head, sigmoid(x
+    Wb), twice that with `kda_neg_eigval`; the gated delta rule in its
+    chunked form (`_kda_chunked`); then per head an RMSNorm over its d
+    values and a sigmoid gate from x, one a head or, through the
+    bottleneck, one a value; and the out product.  Device scopes `conv`,
+    `gate`, `chunk`, `state`, `out`.  Returns (y [B, T, E], stats:
+    `KDA_STATS`, and `KDA_NEG_STATS` with `kda_neg_eigval`)."""
     import jax
     import jax.numpy as jnp
 
@@ -1100,22 +1234,31 @@ def _kda(cfg, x, lw):
                 for a in (q, k))
         q = q * d ** -0.5
     with jax.named_scope("gate"):
-        a = _kept(jnp.einsum("bte,ef->btf", x, lw["wa"],
+        a = _low_rank(x, lw["f_a"], lw["f_b"]) if cfg.kda_gate_rank else \
+            _kept(jnp.einsum("bte,ef->btf", x, lw["wa"],
                              preferred_element_type=f32))
         rate = jnp.exp(lw["A_log"].astype(f32))[:, None]      # [h, 1]
-        g = cfg.kda_gate_floor * jax.nn.sigmoid(
-            rate * heads(a + lw["dt_bias"].astype(f32)))
+        z = heads(a + lw["dt_bias"].astype(f32))
+        g = -rate * jax.nn.softplus(z) if cfg.kda_gate == "softplus" \
+            else cfg.kda_gate_floor * jax.nn.sigmoid(rate * z)
         beta = jax.nn.sigmoid(per_head("wb"))
-    o, span = _kda_chunked(q, k, v, g, beta, cfg.kda_chunk, cfg.kda_rebase,
-                           x.dtype)
+        if cfg.kda_neg_eigval:
+            beta = 2.0 * beta
+    o, span = _kda_chunked(q, k, v, g, beta, cfg.kda_chunk,
+                           cfg.kda_rebase if cfg.kda_gate == "floor"
+                           else None, x.dtype)
     with jax.named_scope("out"):
-        o = _rms_norm(o, lw["o_norm"].astype(f32), cfg.norm_eps) \
-            * jax.nn.sigmoid(per_head("w_gate"))[..., None]
+        o = _rms_norm(o, lw["o_norm"].astype(f32), cfg.norm_eps)
+        o = o * (jax.nn.sigmoid(heads(_low_rank(x, lw["g_a"], lw["g_b"])))
+                 if cfg.kda_gate_rank
+                 else jax.nn.sigmoid(per_head("w_gate"))[..., None])
         y = _kept(o.astype(x.dtype).reshape(B, T, h * d) @ lw["wo"])
     stats = {"kda_tokens": _pvary_all(jnp.asarray(B * T, f32)),
              "kda_chunks": _pvary_all(jnp.asarray(
                  B * -(-T // cfg.kda_chunk), f32)),
              "kda_decay_span_max": span}
+    if cfg.kda_neg_eigval:
+        stats["kda_neg_beta"] = (beta > 1.0).sum().astype(f32)
     return jax.lax.psum(y, AXIS_TP), stats
 
 
@@ -1503,8 +1646,8 @@ def _layer_fn(cfg, kind, tp_size, ep_size, rope=None):
         elif mixer == "gqa":
             # mask and positions are the LAYER's: a full-attention layer
             # among window layers has no window, and no positions where
-            # the model gives it none
-            full = _is_full(kind)
+            # the model gives it none (with no window every layer is full)
+            full = _is_full(kind) or not cfg.window
             with jax.named_scope("attn"):
                 m = _gqa(cfg, _rms_norm(x, lw["ln1"], cfg.norm_eps), lw,
                          tp_size,
@@ -1672,8 +1815,7 @@ def _build_loss_fn(cfg: TransformerConfig, mesh, n_micro: int):
             raise MXNetError("local batch %d %% n_micro %d" % (B, n_micro))
 
         pos_global = sp_idx * T + jnp.arange(T)
-        rope = _rotary_table(cfg, pos_global) \
-            if cfg.attention != "mha" else None
+        rope = _rotary_table(cfg, pos_global) if _rotary(cfg) else None
         with jax.named_scope("embed"):
             x = _embed(cfg, params, tokens, tp_idx, V_loc, pos_global)
 
@@ -2088,7 +2230,7 @@ def publish_moe_stats(stats) -> Dict[str, float]:
 
     host = {k: np.asarray(v) for k, v in jax.device_get(stats).items()}
     out = {}
-    for k in MOE_STATS + GROUP_STATS + KDA_STATS:
+    for k in MOE_STATS + GROUP_STATS + KDA_STATS + KDA_NEG_STATS:
         if k not in host:
             continue
         if k in _WATERMARKS:
@@ -2118,8 +2260,7 @@ def make_forward(cfg: TransformerConfig, mesh):
         tp_idx = jax.lax.axis_index(AXIS_TP)
         B, T = tokens.shape
         pos_global = sp_idx * T + jnp.arange(T)
-        rope = _rotary_table(cfg, pos_global) \
-            if cfg.attention != "mha" else None
+        rope = _rotary_table(cfg, pos_global) if _rotary(cfg) else None
         x = _embed(cfg, params, tokens, tp_idx, V_loc, pos_global)
         pp = mesh.shape[AXIS_PP]
         state = x

@@ -403,15 +403,17 @@ def _ling_cell():
     return transformer_config(config), config, traffic
 
 
-def _ling_program(mesh):
-    """(compiled program, its config file, its traffic file) of the
-    cell's K=4 program at 2 x 2048 tokens a step, for `mesh`."""
+def _cell_program(mesh, cell):
+    """(compiled program, its config file, its traffic file, the
+    parameters it holds) of a cell's fused program at the traffic's K
+    steps and batch, for `mesh`; `cell` is (TransformerConfig, config
+    file, traffic file)."""
     import jax
     import jax.numpy as jnp
 
     from mxtpu.parallel import transformer as tf
 
-    cfg, config, traffic = _ling_cell()
+    cfg, config, traffic = cell
     k, b = traffic["steps_per_program"], traffic["batch"]
     step, sh = tf.make_fused_train_steps(cfg, mesh, k, lr=3e-4,
                                          optimizer="adam")
@@ -450,7 +452,7 @@ def ling_compiled(one_chip_mesh):
 
     steer, pa._on_tpu = pa._on_tpu, lambda: True
     pool = ThreadPoolExecutor(max_workers=1)
-    future = pool.submit(_ling_program, one_chip_mesh)
+    future = pool.submit(_cell_program, one_chip_mesh, _ling_cell())
     yield future
     pool.shutdown(wait=True)
     pa._on_tpu = steer
@@ -619,3 +621,87 @@ def test_trinity_cell_program_reads_its_kv_heads_in_place(one_chip_mesh,
                        + "\n  ".join(found))
     found = [c for body, _ in whiles for c in splits.get(body, [])]
     assert len(found) <= 4, "\n  ".join(found)
+
+
+# ---------------------------------------------------------------------------
+# `solar2_ep40_fused_k4`: one chip's share of Solar Open 2 at published
+# widths (benchmark/onchip/configs/solar_open2_ep40.json, traffic/
+# fused_k4_tokens_2x2k_solar.json), through the config the cell's driver
+# builds
+
+# arguments 10 355 540 992 + temporaries 7 552 296 960
+SOLAR_DESCRIBED = 17907837952
+SOLAR_MOSAIC_SITES = 33     # 3 flash kernels + XLA's grouped products
+# the K loop, the KDA segment's two scans, the chunk scans of the KDA core
+# (forward, rebuilt, backward), the dispatch's walk forward and backward
+# in the scanned segment and in the one-layer GQA segment
+SOLAR_WHILES = 10
+
+
+def _solar_cell():
+    import json
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    onchip = os.path.join(root, "benchmark", "onchip")
+    if onchip not in sys.path:
+        sys.path.insert(0, onchip)
+    from drivers.lm_solar_fused import transformer_config
+
+    with open(os.path.join(onchip, "configs", "solar_open2_ep40.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(onchip, "traffic",
+                           "fused_k4_tokens_2x2k_solar.json")) as f:
+        traffic = json.load(f)
+    return transformer_config(config), config, traffic
+
+
+@pytest.fixture(scope="module", autouse=True)
+def solar_compiled(one_chip_mesh, ling_compiled):
+    """The solar cell's program, traced and compiled in a thread of its
+    own from the file's first test on, as the ling cell's is (and under
+    the steer of `_on_tpu` that `ling_compiled` holds for the file)."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    pool = ThreadPoolExecutor(max_workers=1)
+    future = pool.submit(_cell_program, one_chip_mesh, _solar_cell())
+    yield future
+    pool.shutdown(wait=True)
+
+
+def test_solar_cell_program_compiles_for_one_chip_and_keeps_its_kernels(
+        solar_compiled):
+    """The cell's K=4 program at 2 x 2048 tokens a step, compiled for a
+    described v5e: the three flash kernels are in it by name, the
+    forward and dq on q's `[2, 2048, 32 x 128]` and dk/dv on k's own
+    `[2, 2048, 4 x 128]` (the NoPE GQA layer's 8 q heads a kv head read
+    in place); the grouped expert products went to XLA's own Mosaic
+    kernel; the KDA core's triangular systems are solved by products;
+    the described bytes are bounded (the chip reserves about three
+    quarters of the described temporaries, PERF.md section 7, so the
+    bound is above the chip's 16.9e9)."""
+    from mxtpu.parallel import transformer as tf
+
+    compiled, config, traffic, n_params = solar_compiled.result()
+    assert n_params == 1035536096   # what the configuration holds here
+    need = _described_bytes(compiled)
+    assert 0.75 * _CHIP_BYTES < need <= SOLAR_DESCRIBED + 0.05 * 2 ** 30, need
+
+    text = compiled.as_text()
+    b, length = traffic["batch"], config["input"]["length"]
+    width = config["head_dim"]
+    at_q = (b, length, config["num_attention_heads"] * width)
+    at_kv = (b, length, config["num_key_value_heads"] * width)
+    kernels = _flash_kernels(text)
+    assert kernels == {"mx_flash_fwd": {at_q}, "mx_flash_dq": {at_q},
+                       "mx_flash_dkv": {at_kv}}, kernels
+    assert "ragged-dot" in text, "the grouped products left Mosaic"
+    assert _mosaic_call_sites(text) == SOLAR_MOSAIC_SITES
+    assert len(_whiles_and_stack_copies(text, 3)[0]) == SOLAR_WHILES
+    assert "InvertDiagBlocksLowerTriangular" not in text
+    tokens = b * length
+    rows = tokens * config["num_experts_per_tok"]
+    assert tf._dispatch_block(_solar_cell()[0], tokens) == (rows, rows // 16)
+    found = _row_bound_arrays(text, rows, (config["hidden_size"],
+                                           config["moe_intermediate_size"]))
+    assert not found, found
