@@ -1286,6 +1286,39 @@ def _gated_ffn(x, wg, wu, wd):
     return jax.lax.psum(_kept(h @ wd), AXIS_TP)
 
 
+def _top_k_by_rounds(x, k):
+    """(values [..., k], indices [..., k] int32) of the k largest entries
+    of x (float32) along its last axis, as `jax.lax.top_k` gives them:
+    descending, the lower index first among equal values, in the total
+    order -NaN < -inf < -0 < 0 < inf < NaN, the indices distinct.  It
+    takes k rounds of "the row's largest untaken key, the first untaken
+    index that holds it" over int32 keys that order as the floats do,
+    and no sort: on the TPU `lax.top_k` is a full sort of every row."""
+    import jax
+    import jax.numpy as jnp
+
+    def ordered(bits):
+        # a negative float's bits order backwards: flip all but the
+        # sign (its own inverse)
+        return bits ^ ((bits >> 31) & 0x7FFFFFFF)
+
+    key = ordered(jax.lax.bitcast_convert_type(x, jnp.int32))
+    pos = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    low = jnp.iinfo(jnp.int32).min
+    taken = jnp.zeros(x.shape, bool)
+    best, idx = [], []
+    for _ in range(k):
+        top = jnp.where(taken, low, key).max(-1, keepdims=True)
+        at = jnp.where(~taken & (key == top), pos, x.shape[-1]).min(
+            -1, keepdims=True)
+        taken = taken | (pos == at)
+        best.append(top)
+        idx.append(at)
+    values = jax.lax.bitcast_convert_type(
+        ordered(jnp.concatenate(best, -1)), jnp.float32)
+    return values, jnp.concatenate(idx, -1)
+
+
 def _route(cfg, flat, router, bias=None):
     """Scores, selection and weights of the routed experts: ONE piece of
     code for every mesh and both dispatches.  flat: [n_tok, E].  Returns
@@ -1315,13 +1348,12 @@ def _route(cfg, flat, router, bias=None):
         with jax.named_scope("groups"):
             n, per = select.shape[0], cfg.n_experts // cfg.n_group
             by_group = select.reshape(n, cfg.n_group, per)
-            _, best = jax.lax.top_k(
-                jax.lax.top_k(by_group, 2)[0].sum(-1), cfg.topk_group)
-            kept = jnp.zeros((n, cfg.n_group), bool).at[
-                jnp.arange(n)[:, None], best].set(True)
+            _, best = _top_k_by_rounds(
+                _top_k_by_rounds(by_group, 2)[0].sum(-1), cfg.topk_group)
+            kept = (best[..., None] == jnp.arange(cfg.n_group)).any(-2)
             select = jnp.where(kept[..., None], by_group,
                                -jnp.inf).reshape(select.shape)
-    _, idx = jax.lax.top_k(select, cfg.top_k)
+    _, idx = _top_k_by_rounds(select, cfg.top_k)
     w = jnp.take_along_axis(scores, idx, axis=1)
     if cfg.moe_norm_topk:
         w = w / (w.sum(-1, keepdims=True) + 1e-20)

@@ -82,17 +82,18 @@ def expert_layer_uncut(z, lw, ids, w):
 
 def route_before_groups(cfg, flat, router, bias=None):
     """`transformer._route` as it stood before group-limited selection
-    (commit a09a2fe), for the jaxpr of `n_group = 1` to be held to."""
+    (commit a09a2fe), for the jaxpr of `n_group = 1` to be held to; its
+    `lax.top_k` is `_top_k_by_rounds` now, as in `_route`."""
     logits = jnp.einsum("ne,ex->nx", flat, router,
                         preferred_element_type=jnp.float32)
-    from mxtpu.parallel.transformer import _kept
+    from mxtpu.parallel.transformer import _kept, _top_k_by_rounds
 
     logits = _kept(logits)
     scores = jax.nn.softmax(logits, axis=-1) \
         if cfg.moe_score == "softmax" else jax.nn.sigmoid(logits)
     select = scores if bias is None else \
         scores + jax.lax.stop_gradient(bias.astype(jnp.float32))
-    _, idx = jax.lax.top_k(jax.lax.stop_gradient(select), cfg.top_k)
+    _, idx = _top_k_by_rounds(jax.lax.stop_gradient(select), cfg.top_k)
     w = jnp.take_along_axis(scores, idx, axis=1)
     if cfg.moe_norm_topk:
         w = w / (w.sum(-1, keepdims=True) + 1e-20)

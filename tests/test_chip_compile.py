@@ -164,6 +164,29 @@ def _row_bound_arrays(text, rows, widths):
         text)))
 
 
+_SORT = re.compile(r"^\s*%?[\w.\-]+ = (.*?) sort\(")
+
+
+def _row_sorts(text):
+    """The `sort` instructions of the compiled text that sort rows: by
+    the shape of each result of more than one dimension (a sort of
+    several operands gives each its own), how many results have it.
+    Where the router's selection ran `lax.top_k`, the TPU sorted every
+    token's whole row of scores: [tokens, experts], [tokens, groups,
+    experts a group], [tokens, groups].  Since `_top_k_by_rounds` none
+    is left; the dispatch's argsort of the pair keys and the scatters'
+    index sorts are 1-D."""
+    found = {}
+    for line in text.splitlines():
+        m = _SORT.match(line)
+        if m:
+            for dims in re.findall(r"\w+\[([\d,]*)\]", m.group(1)):
+                shape = tuple(int(d) for d in dims.split(",") if d)
+                if len(shape) > 1:
+                    found[shape] = found.get(shape, 0) + 1
+    return found
+
+
 def _split_shapes(b, t, h, d):
     """The shapes a head split or merge copy of [b, t, h * d] results
     in: the kernels read that layout in place since PR 35, and before
@@ -344,6 +367,8 @@ def test_glm_cell_program_fits_one_chip_and_keeps_its_kernels(
     found = _row_bound_arrays(text, rows, (config["hidden_size"],
                                            config["moe_intermediate_size"]))
     assert not found, found
+    found = _row_sorts(text)
+    assert not found, found
 
     layers = config["num_hidden_layers"] - config["first_k_dense_replace"]
     whiles, copies, splits = _whiles_and_stack_copies(
@@ -495,6 +520,10 @@ def test_ling_cell_program_compiles_for_one_chip_and_keeps_its_kernels(
     found = _row_bound_arrays(text, rows, (config["hidden_size"],
                                            config["moe_intermediate_size"]))
     assert not found, found
+    # the router selects by rounds: no sort of [4096, 512], [4096, 8,
+    # 64] or [4096, 8], four of each before (`lax.top_k` on the TPU)
+    found = _row_sorts(text)
+    assert not found, found
 
 
 # ---------------------------------------------------------------------------
@@ -586,6 +615,8 @@ def test_trinity_cell_program_reads_its_kv_heads_in_place(one_chip_mesh,
     assert tf._dispatch_block(cfg, b * length) == (rows, rows // 8)
     found = _row_bound_arrays(text, rows, (config["hidden_size"],
                                            config["moe_intermediate_size"]))
+    assert not found, found
+    found = _row_sorts(text)
     assert not found, found
     heads, kv, width = (config["num_attention_heads"],
                         config["num_key_value_heads"], config["head_dim"])
@@ -704,4 +735,6 @@ def test_solar_cell_program_compiles_for_one_chip_and_keeps_its_kernels(
     assert tf._dispatch_block(_solar_cell()[0], tokens) == (rows, rows // 16)
     found = _row_bound_arrays(text, rows, (config["hidden_size"],
                                            config["moe_intermediate_size"]))
+    assert not found, found
+    found = _row_sorts(text)
     assert not found, found
