@@ -34,7 +34,7 @@ from .ring_attention import ring_attention, _match_vma, _pallas_enabled
 __all__ = ["TransformerConfig", "init_params", "param_specs",
            "make_train_step", "make_fused_train_steps", "make_forward",
            "dryrun", "init_opt_state", "param_shapes", "MOE_STATS",
-           "GROUP_STATS", "KDA_STATS", "KDA_NEG_STATS",
+           "GROUP_STATS", "KDA_STATS", "KDA_NEG_STATS", "LOOP_STATS",
            "publish_moe_stats"]
 
 _NEG_INF = -1e30
@@ -196,6 +196,17 @@ class TransformerConfig:
     # norms a layer
     embed_scale: float = 1.0   # the embedding's rows times this
 
+    # ---- a looped stack (Ouro's LoopLM): the whole stack runs
+    # loop_passes times on the same weights.  Each pass ends in ln_f, whose
+    # output is fed to the next pass, a head and a cross-entropy, and a
+    # gate ("exit_gate.w" / ".b": lambda_t = sigmoid(x_t . w + b)); the
+    # passes are combined by the exit distribution p_t = lambda_t prod_{j<t}
+    # (1 - lambda_j) (the last pass takes what is left) into the expected
+    # loss less exit_entropy times the distribution's entropy.  1: the
+    # plain stack, one head, no gate
+    loop_passes: int = 1
+    exit_entropy: float = 0.1
+
     def __post_init__(self):
         from ..executor import _REMAT_POLICIES
 
@@ -271,6 +282,10 @@ class TransformerConfig:
             bad = "moe_score must be 'softmax' or 'sigmoid'"
         elif self.mtp_depth not in (0, 1):
             bad = "mtp_depth must be 0 or 1"
+        elif self.loop_passes < 1 or (self.loop_passes > 1
+                                      and self.mtp_depth):
+            bad = ("loop_passes must be >= 1, and a looped stack has a head "
+                   "at every pass and no multi-token-prediction block")
         elif self.n_dense_layers and not (
                 self.n_experts and 0 < self.n_dense_layers < self.n_layers):
             bad = ("n_dense_layers are the layers in front of the expert "
@@ -482,6 +497,9 @@ def _leaves(cfg: TransformerConfig, pp: int):
         out["mtp.eh"] = ((2 * E, E), P(None, None), 2 * E)
         for leaf in ("mtp.ln_e", "mtp.ln_h", "mtp.ln_f"):
             out[leaf] = ((E,), P(None), None)
+    if cfg.loop_passes > 1:         # the exit gate, its bias starting at 0
+        out["exit_gate.w"] = ((E,), P(None), E)
+        out["exit_gate.b"] = ((), P(), "zeros")
     return out
 
 
@@ -500,21 +518,30 @@ GROUP_STATS = ("moe_groups_kept_here",)
 KDA_STATS = ("kda_tokens", "kda_chunks", "kda_decay_span_max")
 # with negative eigenvalues allowed: (token, head) write strengths over 1
 KDA_NEG_STATS = ("kda_neg_beta",)
+# per step of a looped stack (`loop_passes` > 1): the passes run; the
+# tokens; over the tokens, the pass each is expected to exit at under the
+# exit distribution (sum_t t p_t) and the mass left to the last pass (p_U)
+LOOP_STATS = ("loop_passes", "loop_tokens", "loop_exit_expect",
+              "loop_exit_last")
 _WATERMARKS = ("moe_load_max", "kda_decay_span_max")    # max, not sum
+_COPIES = ("loop_passes",)      # the same on every shard: not summed
 
 
 def _stat_names(cfg: TransformerConfig) -> Tuple[str, ...]:
     """The per-step counters the step returns beside its loss: the
     routed experts' where the expert layers run the grouped, dropless
     dispatch (`_experts_grouped`: gated experts), the KDA layers' where
-    the stack has them.  () for every other config, whose program's
-    outputs are as they were."""
+    the stack has them, the loop's where the stack runs more than once.
+    () for every other config, whose program's outputs are as they
+    were."""
     names = ()
     if cfg.n_experts and cfg.ffn == "swiglu":
         names += MOE_STATS + (GROUP_STATS if cfg.n_group > 1 else ())
     if any(_kind_parts(cfg, kind)[0] == "kda"
            for _, kind, _, _ in _segments(cfg)):
         names += KDA_STATS + (KDA_NEG_STATS if cfg.kda_neg_eigval else ())
+    if cfg.loop_passes > 1:
+        names += LOOP_STATS
     return names
 
 
@@ -541,6 +568,8 @@ def init_params(cfg: TransformerConfig, mesh, seed: int = 0):
         k = ks[i] if i < 16 else jax.random.fold_in(key, i)
         if fan_in is None:                # norm scales start at one
             arr = jnp.ones(shape, dt)
+        elif fan_in == "zeros":
+            arr = jnp.zeros(shape, dt)
         elif fan_in == "A_log":           # decay rates in (1, 16)
             arr = jnp.log(jax.random.uniform(k, shape, jnp.float32, 1.0,
                                              16.0)).astype(dt)
@@ -1805,6 +1834,79 @@ def _sharded_xent(logits_loc, labels, vocab_shard_size):
     return lse - label_logit                              # [N]
 
 
+def _exit_log_probs(s):
+    """log p_t [U, N], float32, of the exit distribution from the gate's
+    logits s [U - 1, N] at every pass but the last: log p_t = log
+    lambda_t + sum_{j<t} log(1 - lambda_j) for t < U and log p_U =
+    sum_{j<U} log(1 - lambda_j), lambda = sigmoid(s), each term a
+    log-sigmoid, so that no probability that rounds to 0 is logged."""
+    import jax
+    import jax.numpy as jnp
+
+    none = jnp.zeros_like(s[:1])
+    stayed = jnp.concatenate([none, jnp.cumsum(jax.nn.log_sigmoid(-s), 0)])
+    return stayed + jnp.concatenate([jax.nn.log_sigmoid(s), none])
+
+
+def _looped_loss(cfg, params, x, labels, tp_size, ep_size, rope):
+    """The stage-1 objective of a looped stack (`loop_passes` = U > 1),
+    inside shard_map on one pipeline stage: the stack runs U times on
+    the same weights, pass t + 1 fed x_t = RMS(pass t's output; ln_f);
+    each x_t meets the head (nll_t, a token's cross-entropy) and every
+    x_t but the last the exit gate (s_t = x_t . w + b).  Per token, sum_t
+    p_t (nll_t + exit_entropy log p_t) under the exit distribution
+    (`_exit_log_probs`): the expected loss less exit_entropy times the
+    entropy, with no gradient stopped.  The heads are not rebuilt in the
+    backward pass: `jax.checkpoint` around each cost the Ouro cell 3.2%
+    of its rate on a v5e and saved no memory, the compiled program
+    holding one pass's logits at a time either way (PERF.md).  Device
+    scopes `loop`, with `pass` around each pass's stack and `exit`
+    around the norm, gate, head and combination.  Returns (mean over the
+    local tokens, the step's counters: the stack's, summed over the
+    passes, and `LOOP_STATS`)."""
+    import jax
+    import jax.numpy as jnp
+
+    B, T = labels.shape
+    f32 = jnp.float32
+    V_loc = params["unembed"].shape[-1]
+    flat = labels.reshape(B * T)
+
+    def head(x, unembed):
+        return _sharded_xent((x @ unembed).reshape(B * T, V_loc), flat,
+                             V_loc)
+
+    U, nll, gates, stats = cfg.loop_passes, [], [], {}
+    with jax.named_scope("loop"):
+        for t in range(U):
+            with jax.named_scope("pass"):
+                h, st = _run_stack(cfg, params, x, tp_size, ep_size, rope)
+            stats = _merge_stats(stats, st)
+            with jax.named_scope("exit"):
+                x = _rms_norm(h, params["ln_f"], cfg.norm_eps)
+                nll.append(head(x, params["unembed"]))
+                if t < U - 1:
+                    # tp ranks hold the same x: the mean of their equal
+                    # logits is exact, and the same on every rank
+                    gates.append(jax.lax.psum(
+                        x.reshape(B * T, -1).astype(f32)
+                        @ params["exit_gate.w"].astype(f32), AXIS_TP)
+                        / tp_size + params["exit_gate.b"].astype(f32))
+        with jax.named_scope("exit"):
+            logp = _exit_log_probs(jnp.stack(gates))
+            p = jnp.exp(logp)
+            per_token = (p * (jnp.stack(nll) + cfg.exit_entropy * logp)
+                         ).sum(0)
+            # marked varying everywhere, as the stack's counters are,
+            # for the one reduction over the mesh they all go through
+            stats.update({k: _pvary_all(v) for k, v in dict(
+                loop_passes=f32(U), loop_tokens=f32(B * T),
+                loop_exit_expect=(jnp.arange(1, U + 1, dtype=f32)[:, None]
+                                  * p).sum(),
+                loop_exit_last=p[-1].sum()).items()})
+    return per_token.mean(), stats
+
+
 # ---------------------------------------------------------------------------
 # full per-device train step (inside shard_map)
 
@@ -1812,7 +1914,8 @@ def _sharded_xent(logits_loc, labels, vocab_shard_size):
 def _build_loss_fn(cfg: TransformerConfig, mesh, n_micro: int):
     """The per-device loss (inside shard_map): embed, this rank's stage
     of layers under the pipeline schedule, final norm, unembed, sharded
-    cross-entropy.
+    cross-entropy; a looped stack's (`loop_passes` > 1, one stage, one
+    microbatch) is `_looped_loss`.
 
     The schedule is GPipe's: n_micro + pp - 1 steps of a `fori_loop`,
     each running the stage on one microbatch and handing its output to
@@ -1857,15 +1960,19 @@ def _build_loss_fn(cfg: TransformerConfig, mesh, n_micro: int):
             return _run_stack(cfg, params, state, tp, ep, rope)
 
         n_steps = n_micro + pp - 1
-        if n_steps == 1:
+        if n_steps > 1 and _stat_names(cfg):
+            raise MXNetError("the step's counters (routed experts, KDA "
+                             "layers, a looped stack) need one stage and "
+                             "one microbatch")
+        looped = None
+        if cfg.loop_passes > 1:
+            looped, stats = _looped_loss(cfg, params, x, labels, tp, ep,
+                                         rope)
+        elif n_steps == 1:
             # one stage, one microbatch: one call, and never a loop of
             # one trip (the docstring above says what that costs)
             h, stats = run_stage(x)
         else:
-            if _stat_names(cfg):
-                raise MXNetError("the step's counters (routed experts, "
-                                 "KDA layers) need one stage and one "
-                                 "microbatch")
             mb, E = B // n_micro, cfg.d_model
             x_mb = x.reshape(n_micro, mb, T, E)
             perm_fwd = [(i, (i + 1) % pp) for i in range(pp)]
@@ -1933,11 +2040,14 @@ def _build_loss_fn(cfg: TransformerConfig, mesh, n_micro: int):
         # only the last stage's h is the real model output; psum the
         # masked loss over pp so every rank agrees (others contribute 0)
         with jax.named_scope("loss"):
-            h = _rms_norm(h, params["ln_f"], cfg.norm_eps)
-            logits = h @ params["unembed"]                # [B, T, V/tp]
-            nll = _sharded_xent(logits.reshape(B * T, V_loc),
-                                labels.reshape(B * T), V_loc)
-            local_loss = nll.mean()
+            if looped is not None:
+                local_loss = looped
+            else:
+                h = _rms_norm(h, params["ln_f"], cfg.norm_eps)
+                logits = h @ params["unembed"]            # [B, T, V/tp]
+                nll = _sharded_xent(logits.reshape(B * T, V_loc),
+                                    labels.reshape(B * T), V_loc)
+                local_loss = nll.mean()
             if mtp_loss is not None:
                 local_loss = local_loss + cfg.mtp_weight * mtp_loss
             local_loss = local_loss * jnp.where(is_last, 1.0, 0.0)
@@ -1954,7 +2064,8 @@ def _build_loss_fn(cfg: TransformerConfig, mesh, n_micro: int):
             # shards (dp, sp); the other axes hold copies
             every = (AXIS_DP, AXIS_PP, AXIS_TP, AXIS_SP, AXIS_EP)
             stats = jax.lax.stop_gradient({
-                k: (jax.lax.pmax(v, every) if k in _WATERMARKS else
+                k: (jax.lax.pmax(v, every)
+                    if k in _WATERMARKS + _COPIES else
                     jax.lax.psum(v, every) / float(pp * tp * ep))
                 for k, v in stats.items()})
         return loss, stats
@@ -2083,6 +2194,10 @@ def _check_mesh(cfg, mesh):
         if kv % mesh.shape[AXIS_TP]:
             raise MXNetError("n_kv_heads=%d not divisible by tp=%d"
                              % (kv, mesh.shape[AXIS_TP]))
+    if cfg.loop_passes > 1 and mesh.shape[AXIS_PP] > 1:
+        raise MXNetError(
+            "a looped stack runs on one pipeline stage: a loop that goes "
+            "round a ring of stages is not built: pp = 1")
     if KDA_STATS[0] in _stat_names(cfg) and (mesh.shape[AXIS_SP] > 1
                                              or mesh.shape[AXIS_TP] > 1):
         raise MXNetError(
@@ -2249,8 +2364,9 @@ def make_fused_train_steps(cfg: TransformerConfig, mesh, k_steps: int,
 def publish_moe_stats(stats) -> Dict[str, float]:
     """Add the per-step counters a step (or a fused program: [K] arrays)
     returned to `mx.profiler`'s stats, the routed experts' (`MOE_STATS`,
-    `GROUP_STATS`) and the KDA layers' (`KDA_STATS`) alike: counts add
-    up, `moe_load_max` and `kda_decay_span_max` are watermarks (whole
+    `GROUP_STATS`), the KDA layers' (`KDA_STATS`) and the loop's
+    (`LOOP_STATS`) alike: counts add up, to the nearest whole number,
+    `moe_load_max` and `kda_decay_span_max` are watermarks (whole
     numbers: the span's nats to the nearest) (docs/observability.md).
     One host read of a few small arrays: call it where the host may
     wait for the program (a sampled step, the end of a window), not
@@ -2262,7 +2378,8 @@ def publish_moe_stats(stats) -> Dict[str, float]:
 
     host = {k: np.asarray(v) for k, v in jax.device_get(stats).items()}
     out = {}
-    for k in MOE_STATS + GROUP_STATS + KDA_STATS + KDA_NEG_STATS:
+    for k in MOE_STATS + GROUP_STATS + KDA_STATS + KDA_NEG_STATS \
+            + LOOP_STATS:
         if k not in host:
             continue
         if k in _WATERMARKS:
@@ -2270,7 +2387,7 @@ def publish_moe_stats(stats) -> Dict[str, float]:
             profiler.max_stat(k, int(round(out[k])))
         else:
             out[k] = float(host[k].sum())
-            profiler.inc_stat(k, int(out[k]))
+            profiler.inc_stat(k, int(round(out[k])))
     return out
 
 
@@ -2305,6 +2422,12 @@ def make_forward(cfg: TransformerConfig, mesh):
                     state, AXIS_PP,
                     [(i, (i + 1) % pp) for i in range(pp)])
         h = _rms_norm(state, params["ln_f"], cfg.norm_eps)
+        # a looped stack runs every pass (no early exit; pp = 1): the
+        # last pass's logits
+        for _ in range(cfg.loop_passes - 1):
+            out, _ = _run_stack(cfg, params, h, tp, mesh.shape[AXIS_EP],
+                                rope)
+            h = _rms_norm(out, params["ln_f"], cfg.norm_eps)
         logits = h @ params["unembed"]
         # only the last stage holds the real output: mask + psum to
         # replicate over pp; ep ranks are identical copies so psum/ep

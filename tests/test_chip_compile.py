@@ -738,3 +738,106 @@ def test_solar_cell_program_compiles_for_one_chip_and_keeps_its_kernels(
     assert not found, found
     found = _row_sorts(text)
     assert not found, found
+
+
+# ---------------------------------------------------------------------------
+# `ouro26b_l8_fused_k4`: one pipeline stage of Ouro-2.6B at published
+# widths, its 8 held layers run 4 times a step (benchmark/onchip/configs/
+# ouro_2_6b_l8.json, traffic/fused_k4_tokens_2x4k_loop.json), through the
+# config the cell's driver builds
+
+# arguments 6 124 644 352 + temporaries 10 575 066 624; the heads rebuilt
+# in the backward pass (`jax.checkpoint`) read 11 314 172 928
+OURO_DESCRIBED = 16699710976
+
+
+def _ouro_cell():
+    import json
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    onchip = os.path.join(root, "benchmark", "onchip")
+    if onchip not in sys.path:
+        sys.path.insert(0, onchip)
+    from drivers.lm_ouro_fused import transformer_config
+
+    with open(os.path.join(onchip, "configs", "ouro_2_6b_l8.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(onchip, "traffic",
+                           "fused_k4_tokens_2x4k_loop.json")) as f:
+        traffic = json.load(f)
+    return transformer_config(config), config, traffic
+
+
+@pytest.fixture(scope="module", autouse=True)
+def ouro_compiled(one_chip_mesh, solar_compiled):
+    """The ouro cell's program, traced and compiled in a thread of its
+    own from the file's first test on, as the ling and solar cells' are
+    (and under the steer of `_on_tpu` that `ling_compiled` holds for the
+    file)."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    pool = ThreadPoolExecutor(max_workers=1)
+    future = pool.submit(_cell_program, one_chip_mesh, _ouro_cell())
+    yield future
+    pool.shutdown(wait=True)
+
+
+def _sites_by_computation(text):
+    """{flash kernel: [the computation each call site is in]}."""
+    sites, name = {}, None
+    for line in text.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            name = m.group(2)
+            continue
+        m = _FLASH_CALL.search(line)
+        if m:
+            sites.setdefault(m.group(1), []).append(name)
+    return sites
+
+
+def test_ouro_cell_program_runs_the_stack_four_times_without_restacking(
+        ouro_compiled):
+    """The cell's K=4 program at 2 x 4096 tokens a step, compiled for a
+    described v5e: the three flash kernels by name on the activations'
+    own `[2, 4096, 16 x 128]`, read in place; the loops are the K loop
+    and, for each of the 4 passes, a forward and a backward scan of the
+    8 held layers, with no loop that re-stacks the layer weights (the
+    hoisted loop a one-trip pipeline loop once brought, which a scan
+    over the passes brings back once a step) and no whole-stack copy in
+    a layer loop's body; each backward layer loop holds one dq and one
+    dk/dv site and a rebuilt forward (`remat="full"`): 32 launches of
+    each a step; the described bytes are bounded, under what one more
+    pass's float32 logits alive at once (1.61e9) would add (the chip
+    reserves about three quarters of the described temporaries, PERF.md
+    section 7, so the bound is above the chip's 16.9e9)."""
+    compiled, config, traffic, n_params = ouro_compiled.result()
+    assert n_params == 612438017    # the issue's count of what this chip holds
+    need = _described_bytes(compiled)
+    assert 0.75 * _CHIP_BYTES < need <= OURO_DESCRIBED + 0.05 * 2 ** 30, need
+
+    text = compiled.as_text()
+    b, length = traffic["batch"], config["input"]["length"]
+    heads, width = config["num_attention_heads"], config["head_dim"]
+    want = {(b, length, heads * width)}
+    assert _flash_kernels(text) == {"mx_flash_fwd": want, "mx_flash_dq": want,
+                                    "mx_flash_dkv": want}
+    passes, layers = config["total_ut_steps"], config["num_hidden_layers"]
+    whiles, copies, splits = _whiles_and_stack_copies(
+        text, layers, _split_shapes(b, length, heads, width))
+    layer_loops = [body for body, in_entry in whiles if not in_entry]
+    assert len(whiles) == 1 + 2 * passes and \
+        len(layer_loops) == 2 * passes, whiles
+    found = [c for body in layer_loops for c in copies.get(body, [])]
+    assert not found, ("whole-stack copies once per layer:\n  "
+                       + "\n  ".join(found))
+    found = [c for body, _ in whiles for c in splits.get(body, [])]
+    assert not found, ("head split / merge copies:\n  " + "\n  ".join(found))
+    sites = _sites_by_computation(text)
+    assert {k: len(v) for k, v in sites.items()} == {
+        "mx_flash_fwd": 2 * passes, "mx_flash_dq": passes,
+        "mx_flash_dkv": passes}, sites
+    assert all(len(set(v)) == len(v) and set(v) <= set(layer_loops)
+               for v in sites.values()), sites
+    assert _mosaic_call_sites(text) == 4 * passes
