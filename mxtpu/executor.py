@@ -62,7 +62,7 @@ _REMAT_POLICIES = {
 REMAT_DOT = "dot"
 
 
-def apply_remat(fn, policy_name, prevent_cse=True, named=False):
+def apply_remat(fn, policy_name, prevent_cse=True, named=False, keep=()):
     """Wrap fn in `jax.checkpoint` under the named policy.  The ONE
     remat vocabulary — the symbolic executor's mirror pass and the SPMD
     transformer's per-layer remat both route through here:
@@ -94,6 +94,10 @@ def apply_remat(fn, policy_name, prevent_cse=True, named=False):
       each, all for the same [E, 4096] product or less; q's where the
       block has no gate).
 
+    `keep` names values (`checkpoint_name`) kept besides what the policy
+    keeps: the looped stack keeps `FLASH_OUT` / `FLASH_LSE` under "full"
+    in every pass but the first (`transformer._looped_loss`).
+
     Pass prevent_cse=False when fn is a `lax.scan` body: the CSE
     barriers are unnecessary under scan (per the jax.checkpoint docs)
     and only cost backward throughput."""
@@ -102,15 +106,19 @@ def apply_remat(fn, policy_name, prevent_cse=True, named=False):
     if policy_name not in _REMAT_POLICIES:
         raise MXNetError("remat policy must be one of %s (got %r)"
                          % (sorted(_REMAT_POLICIES), policy_name))
+    cp = jax.checkpoint_policies
     attr = _REMAT_POLICIES[policy_name]
-    policy = getattr(jax.checkpoint_policies, attr) if attr else None
+    policy = getattr(cp, attr) if attr else None
     if policy_name == "dots":
         from .ops.pallas_attention import FLASH_OUT, FLASH_LSE
 
-        cp = jax.checkpoint_policies
         policy = cp.save_only_these_names(REMAT_DOT, FLASH_OUT, FLASH_LSE) \
             if named else cp.save_from_both_policies(
                 policy, cp.save_only_these_names(FLASH_OUT, FLASH_LSE))
+    if keep:
+        names = cp.save_only_these_names(*keep)
+        policy = names if policy is None else \
+            cp.save_from_both_policies(policy, names)
     return jax.checkpoint(fn, policy=policy, prevent_cse=prevent_cse)
 
 

@@ -1681,11 +1681,12 @@ def _merge_stats(a, b):
     return out
 
 
-def _layer_fn(cfg, kind, tp_size, ep_size, rope=None):
+def _layer_fn(cfg, kind, tp_size, ep_size, rope=None, keep_flash=False):
     """One layer of a segment as the scan's body, (x, lw) -> (x, stats),
-    under the config's remat policy.  `kind` is a segment's (see
-    `_segments`): the feed-forward "dense" or "moe", "kda+" in front
-    where the mixer is Kimi Delta Attention."""
+    under the config's remat policy, which with `keep_flash` keeps the
+    flash kernel's output and log-sums besides (`_looped_loss`).
+    `kind` is a segment's (see `_segments`): the feed-forward "dense" or
+    "moe", "kda+" in front where the mixer is Kimi Delta Attention."""
     import jax
 
     mixer, ffn = _kind_parts(cfg, kind)
@@ -1739,20 +1740,24 @@ def _layer_fn(cfg, kind, tp_size, ep_size, rope=None):
     if cfg.remat == "none":
         return layer
     from ..executor import apply_remat
+    from ..ops.pallas_attention import FLASH_OUT, FLASH_LSE
 
     # the blocks say by name what "dots" keeps of them (`_kept`)
-    return apply_remat(layer, cfg.remat, prevent_cse=False, named=True)
+    return apply_remat(layer, cfg.remat, prevent_cse=False, named=True,
+                       keep=(FLASH_OUT, FLASH_LSE) if keep_flash else ())
 
 
-def _stage_fn(cfg, kind, params_stage, x, tp_size, ep_size, rope=None):
+def _stage_fn(cfg, kind, params_stage, x, tp_size, ep_size, rope=None,
+              keep_flash=False):
     """Run one segment's layers (this pipeline stage's share of them)
     over x via lax.scan (weights stacked on the layer axis).  `kind` is
-    the segment's; `rope` the rotary table where positions are rotary.
-    Returns (x, the segment's per-step counters or {})."""
+    the segment's; `rope` the rotary table where positions are rotary;
+    `keep_flash` as `_layer_fn`'s.  Returns (x, the segment's per-step
+    counters or {})."""
     import jax
 
     x = _pvary_all(x)
-    layer = _layer_fn(cfg, kind, tp_size, ep_size, rope)
+    layer = _layer_fn(cfg, kind, tp_size, ep_size, rope, keep_flash)
     out, per_layer = jax.lax.scan(layer, x, params_stage)
     if not per_layer:
         return out, {}
@@ -1772,16 +1777,22 @@ def _segment_params(cfg, params, segment):
             for leaf in _layer_leaves(cfg, kind)}
 
 
-def _run_stack(cfg, params, x, tp_size, ep_size, rope):
+def _run_stack(cfg, params, x, tp_size, ep_size, rope, keep_flash=False):
     """The model's layers in order, segment by segment (the multi-token-
-    prediction block is not one of them).  Returns (x, stats)."""
+    prediction block is not one of them); `keep_flash` as `_layer_fn`'s,
+    the trace-time stat `loop_flash_kept` counting the attention layers
+    it is set for.  Returns (x, stats)."""
+    from .. import profiler
+
     stats = {}
     for segment in _segments(cfg):
         if segment[0] != "mtp.":
             x, st = _stage_fn(cfg, segment[1],
                               _segment_params(cfg, params, segment),
-                              x, tp_size, ep_size, rope)
+                              x, tp_size, ep_size, rope, keep_flash)
             stats = _merge_stats(stats, st)
+            if keep_flash and _kind_parts(cfg, segment[1])[0] != "kda":
+                profiler.inc_stat("loop_flash_kept", segment[3])
     return x, stats
 
 
@@ -1859,7 +1870,14 @@ def _looped_loss(cfg, params, x, labels, tp_size, ep_size, rope):
     entropy, with no gradient stopped.  The heads are not rebuilt in the
     backward pass: `jax.checkpoint` around each cost the Ouro cell 3.2%
     of its rate on a v5e and saved no memory, the compiled program
-    holding one pass's logits at a time either way (PERF.md).  Device
+    holding one pass's logits at a time either way (PERF.md).  Under
+    `remat="full"` every pass but the first keeps the flash kernel's
+    output and log-sums (`_layer_fn`'s `keep_flash`), so that its
+    backward pass does not run the forward kernel again; the first pass
+    keeps nothing beyond its layer inputs, because its backward pass
+    runs last, beside what the later passes' backward passes left
+    behind, where the program's memory peaks (keeping them there cost the Ouro cell's
+    described program +0.25 GiB, the later passes' 33 MB).  Device
     scopes `loop`, with `pass` around each pass's stack and `exit`
     around the norm, gate, head and combination.  Returns (mean over the
     local tokens, the step's counters: the stack's, summed over the
@@ -1880,7 +1898,8 @@ def _looped_loss(cfg, params, x, labels, tp_size, ep_size, rope):
     with jax.named_scope("loop"):
         for t in range(U):
             with jax.named_scope("pass"):
-                h, st = _run_stack(cfg, params, x, tp_size, ep_size, rope)
+                h, st = _run_stack(cfg, params, x, tp_size, ep_size, rope,
+                                   keep_flash=t > 0 and cfg.remat == "full")
             stats = _merge_stats(stats, st)
             with jax.named_scope("exit"):
                 x = _rms_norm(h, params["ln_f"], cfg.norm_eps)

@@ -142,6 +142,63 @@ def test_program_matches_reference_loss_and_every_gradient(
     assert gap <= TOL, (where, gap)
 
 
+def _flash_kernel_sites(cfg, mesh, params, tokens, labels):
+    """{kernel name: `pallas_call`s in the train step's jaxpr} (a call in
+    a scan's body is one)."""
+    step, _ = tf.make_train_step(cfg, mesh, lr=1e-3, optimizer="adam")
+    sites = {}
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                name = eqn.params["name"]
+                sites[name] = sites.get(name, 0) + 1
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(step)(params, tf.init_opt_state(cfg, mesh), tokens,
+                              labels).jaxpr)
+    return sites
+
+
+def test_later_passes_keep_the_flash_results_and_change_no_number(
+        mesh, monkeypatch, plain_run):
+    """Under `remat="full"` every pass but the first keeps the flash
+    kernel's output and log-sums: the backward pass rebuilds the forward
+    kernel in the first pass's layer scan alone, the trace-time stat
+    `loop_flash_kept` reads (U - 1) x N, and the loss and every leaf's
+    gradient are those of the same program with nothing kept, to float32
+    rounding (interpreted Pallas kernels)."""
+    from mxtpu import profiler
+
+    monkeypatch.setenv("MXTPU_PALLAS_INTERPRET", "1")
+    cfg, params = plain_run["cfg"], plain_run["params"]
+    passes, layers = HF["total_ut_steps"], HF["num_hidden_layers"]
+    tokens, labels = _batch()
+    before = profiler.get_stat("loop_flash_kept")
+    loss, grads, _ = _program_loss_and_grads(cfg, mesh, params, tokens,
+                                             labels)
+    assert profiler.get_stat("loop_flash_kept") - before \
+        == (passes - 1) * layers
+    kept = _flash_kernel_sites(cfg, mesh, params, tokens, labels)
+
+    run_stack = tf._run_stack
+    monkeypatch.setattr(
+        tf, "_run_stack",
+        lambda *args, keep_flash=False: run_stack(*args))
+    want_loss, want, _ = _program_loss_and_grads(cfg, mesh, params, tokens,
+                                                 labels)
+    rebuilt = _flash_kernel_sites(cfg, mesh, params, tokens, labels)
+    # a forward and a backward layer scan a pass; the forward kernel
+    # rebuilt in every backward one, or in the first pass's alone
+    assert rebuilt == {"mx_flash_fwd": 2 * passes, "mx_flash_dq": passes,
+                       "mx_flash_dkv": passes}, rebuilt
+    assert kept == dict(rebuilt, mx_flash_fwd=passes + 1), kept
+    assert abs(loss - want_loss) <= 1e-6 * want_loss
+    gap, where = _worst_gap(grads, want)
+    assert gap <= 1e-5, (where, gap)
+
+
 @pytest.mark.parametrize("what", ["no_entropy", "one_pass_fewer",
                                   "bfloat16"])
 def test_the_tolerance_refuses(mesh, plain_run, what):
